@@ -4,12 +4,10 @@ The framework's dominant fixed costs are both *derivable* artifacts:
 
 - **GRR plan ETL** — the compiled gather-route-reduce plan
   (``data.grr``) is a pure function of (cols, vals, dim) × the plan
-  configuration; measured 123 s at the bench shape on a 1-core host
-  (BENCH_r05), ~2 minutes of re-derivation per run for bytes that never
-  change between runs.
-- **XLA compilation** — the scale run pays ~1000 s of one-time
-  compile+transfer and the scoring sweep another 1037 s (PERF.md),
-  again identical across runs for identical program shapes.
+  configuration: minutes of re-derivation per run at the bench shape
+  for bytes that never change between runs.
+- **XLA compilation** — identical across runs for identical program
+  shapes, and a large part of a cold run.
 
 Snap ML's 10×-over-Spark wins come largely from keeping data and
 derived structures resident across iterations (PAPERS.md); this package
@@ -18,11 +16,12 @@ loads its plan from disk (``plan_cache``) and replays compiled XLA
 programs from JAX's persistent compilation cache (``compile_cache``)
 instead of re-deriving either.
 
-Layout on disk (one directory, safe to delete wholesale)::
+The plan cache's layout on disk (one directory, safe to delete
+wholesale; the XLA cache's place is ``compile_cache``'s decision
+alone)::
 
     <cache_dir>/
       plans/grr-<fp16>-<cfg12>-v<F>.<P>.npz   # serialized plans
-      xla/...                                  # jax persistent cache
 
 Keying (see ``plan_cache``): ``fp16`` is a content hash of the exact
 ELL arrays + table width, ``cfg12`` hashes the plan-affecting build
@@ -33,7 +32,10 @@ harmlessly ignored).  Corrupt or truncated files fall back to a fresh
 build (tested).
 """
 
-from photon_ml_tpu.cache.compile_cache import enable_compilation_cache
+from photon_ml_tpu.cache.compile_cache import (
+    cache_entry_count,
+    enable_compilation_cache,
+)
 from photon_ml_tpu.cache.plan_cache import (
     atomic_savez,
     dataset_fingerprint,
@@ -45,6 +47,7 @@ from photon_ml_tpu.cache.plan_cache import (
 
 __all__ = [
     "atomic_savez",
+    "cache_entry_count",
     "dataset_fingerprint",
     "enable_compilation_cache",
     "load_plan",

@@ -1,17 +1,25 @@
 """JAX persistent compilation cache wiring.
 
-The scale run pays ~1000 s of one-time compile+transfer and the
-scoring sweep another 1037 s (PERF.md) — both re-paid on every run for
-identical program shapes.  JAX ships a persistent compilation cache
-(``jax_compilation_cache_dir``) that keys compiled executables by
-(HLO, compile options, backend); enabling it makes those costs
-once-per-program-shape instead of once-per-run.
+Compiling the solver and scoring programs is a large part of a cold
+run, and it is re-paid on every run for identical program shapes.
+JAX's persistent compilation cache keys compiled executables by (HLO,
+compile options, backend, *cache directory path*), so a run only hits
+what an earlier run wrote when both name the same directory.
 
-``enable_compilation_cache`` is the single switch the drivers, the
-estimator, and the bench all call.  It is idempotent, resolves its
-default from ``PHOTON_ML_TPU_COMPILE_CACHE``, and degrades to a no-op
-on JAX builds without the knobs — a cache must never be able to make a
-run fail.
+``enable_compilation_cache`` is the one place that decides where that
+directory is; the drivers, the estimator, the server, ``bench.py`` and
+``chip_smoke.py`` all call it and choose nothing themselves:
+
+- where JAX already has a cache directory — it reads
+  ``JAX_COMPILATION_CACHE_DIR`` into ``jax_compilation_cache_dir`` at
+  import — this module sets none;
+- where it has none, the cache lives at ``DEFAULT_CACHE_DIR``: one
+  fixed, git-ignored directory at the root of the checkout, derived
+  from this package's own location — never from a temp name, a pid, a
+  uid or the time, any of which would move the path and so never hit.
+
+An unwritable cache directory fails the run here, at the call, rather
+than silently compiling cold for ever.
 """
 
 from __future__ import annotations
@@ -21,54 +29,37 @@ import os
 
 logger = logging.getLogger(__name__)
 
-ENV_VAR = "PHOTON_ML_TPU_COMPILE_CACHE"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache")
 
-_enabled_dir: str | None = None
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns the
+    directory in effect (see the module docstring for which).
+
+    The min-compile-time floor is dropped to 0.5 s so the solver and
+    scoring programs (seconds to minutes of XLA time each) all persist
+    without caching the dispatch-layer trivia.  Idempotent."""
+    import jax
+
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cache_dir = jax.config.jax_compilation_cache_dir
+    os.makedirs(cache_dir, exist_ok=True)
+    if not os.access(cache_dir, os.W_OK):
+        raise PermissionError(
+            f"persistent compilation cache {cache_dir!r} is not writable")
+    logger.info("persistent compilation cache at %s", cache_dir)
+    return cache_dir
 
 
-def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
-
-    ``cache_dir`` None falls back to ``$PHOTON_ML_TPU_COMPILE_CACHE``;
-    if that is unset too, this is a no-op (returns None).  Compiled
-    programs land under ``<cache_dir>/xla``.  The min-compile-time
-    floor is dropped to 0.5 s so the solver/scoring programs (seconds
-    to minutes of XLA time each) all persist without caching the
-    dispatch-layer trivia.  Returns the directory in effect."""
-    global _enabled_dir
-    from photon_ml_tpu.config import read_env
-
-    cache_dir = cache_dir or read_env(ENV_VAR)
-    if not cache_dir:
-        return None
-    xla_dir = os.path.join(os.path.abspath(cache_dir), "xla")
-    if _enabled_dir == xla_dir:
-        return xla_dir
-    try:
-        import jax
-
-        os.makedirs(xla_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", xla_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        # Some jax builds latch the cache state at the FIRST compile and
-        # ignore later config changes; dropping the latched state makes
-        # the next compile re-read the directory we just set.  Clears
-        # only the persistent-cache handle, not the in-process jit cache.
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
-
-            _cc.reset_cache()
-        except Exception:  # photon-lint: disable=swallowed-exception (older jax without reset_cache; stale in-process handle is harmless)
-            pass
-    except Exception as e:  # older jax / read-only fs: run uncached
-        logger.warning(
-            "persistent compilation cache unavailable (%r); compiles "
-            "will not persist across runs", e)
-        return None
-    _enabled_dir = xla_dir
-    logger.info("persistent compilation cache at %s", xla_dir)
-    return xla_dir
+def cache_entry_count(cache_dir: str) -> int:
+    """Number of compiled programs in ``cache_dir`` (0 if absent)."""
+    if not os.path.isdir(cache_dir):
+        return 0
+    return sum(1 for name in os.listdir(cache_dir)
+               if name.endswith("-cache"))

@@ -34,14 +34,9 @@ def run(input_path: str, output_dir: str,
         telemetry_mode: str = "off",
         monitor: str = "off",
         status_port: int | None = None) -> dict:
-    # Indexing itself is host-only, but wire the compilation cache
-    # like the other drivers so $PHOTON_ML_TPU_COMPILE_CACHE covers any
-    # jax use behind the I/O layer uniformly.
     from photon_ml_tpu import telemetry
-    from photon_ml_tpu.cache import enable_compilation_cache
     from photon_ml_tpu.telemetry import monitor as _mon
 
-    enable_compilation_cache()
     # Context-managed logger + optional telemetry session (the driver
     # knob discipline of the other two drivers): the scan phase becomes
     # a span and the summary/trace land under the output dir.  The

@@ -123,10 +123,10 @@ def _make_sinks(config: ScoringConfig, n: int, entity_keys) -> list:
 
 def run(config: ScoringConfig, log: RunLogger | None = None) -> dict:
     # Wire the persistent compilation cache before the scoring programs
-    # compile (the 1037 s sweep compile is once per program shape).
+    # compile.
     from photon_ml_tpu.cache import enable_compilation_cache
 
-    enable_compilation_cache(config.compilation_cache_dir)
+    enable_compilation_cache()
     config.validate()
     out_dir = os.path.dirname(os.path.abspath(config.output_path))
     os.makedirs(out_dir, exist_ok=True)

@@ -144,15 +144,7 @@ def distributed_init_from_env() -> None:
     a caller-initialized process doesn't crash."""
     import jax
 
-    # jax >= 0.5 has jax.distributed.is_initialized(); older builds
-    # expose the same fact as global_state.client.
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is None:
-        from jax._src import distributed as _dist
-
-        def is_init():
-            return _dist.global_state.client is not None
-    if is_init():
+    if jax.distributed.is_initialized():
         return
     from photon_ml_tpu.config import read_env
 
@@ -170,11 +162,10 @@ def run(config: TrainingConfig, log: RunLogger | None = None) -> dict:
     """Full training pipeline; returns the written summary dict."""
     config.validate()
     # Warm path first: the persistent compilation cache must be wired
-    # before any jit compiles (photon_ml_tpu.cache; falls back to
-    # $PHOTON_ML_TPU_COMPILE_CACHE, no-op when neither is set).
+    # before any jit compiles.
     from photon_ml_tpu.cache import enable_compilation_cache
 
-    enable_compilation_cache(config.compilation_cache_dir)
+    enable_compilation_cache()
     if config.distributed_init:
         distributed_init_from_env()
     # Multi-host streaming (ISSUE 16): join the fleet if this process

@@ -38,9 +38,6 @@ from photon_ml_tpu.optim.variance import VarianceComputationType
 SANCTIONED_ENV = {
     "PHOTON_ML_TPU_PLAN_CACHE": (
         "default on-disk GRR plan cache dir (data.grr cache_dir=None)"),
-    "PHOTON_ML_TPU_COMPILE_CACHE": (
-        "default persistent XLA compilation cache dir (cache"
-        ".compile_cache)"),
     "PHOTON_ML_TPU_SPILL_DIR": (
         "default chunk-store spill dir (data.chunk_store"
         ".resolve_spill_dir)"),
@@ -326,17 +323,13 @@ class TrainingConfig:
     # fixed-effect coordinate, smooth regularization (NONE/L2) on every
     # coordinate, no locked coordinates, and single-device execution.
     cd_fused: bool = False
-    # Warm-path artifact caches (photon_ml_tpu.cache): plan_cache_dir
+    # Warm-path plan cache (photon_ml_tpu.cache): plan_cache_dir
     # persists compiled GRR plans keyed by dataset fingerprint ×
     # plan-config × planner version, so the second run of a workload
-    # skips the plan ETL (measured 123 s at the bench shape);
-    # compilation_cache_dir points JAX's persistent compilation cache
-    # at disk, so the ~1000 s scale-run compile and the 1037 s scoring
-    # compile are paid once per program shape.  Both may also be set
-    # via PHOTON_ML_TPU_PLAN_CACHE / PHOTON_ML_TPU_COMPILE_CACHE; the
-    # same directory can serve both (plans/ and xla/ subtrees).
+    # skips the plan ETL.  May also be set via PHOTON_ML_TPU_PLAN_CACHE.
+    # (The XLA compilation cache is not a config field: its place is
+    # cache.compile_cache's decision alone.)
     plan_cache_dir: str | None = None
-    compilation_cache_dir: str | None = None
     # When set, the driver's fit phase runs under jax.profiler.trace
     # and a TensorBoard/XProf device trace is written here (SURVEY §5.1).
     profile_dir: str | None = None
@@ -529,9 +522,6 @@ class ScoringConfig:
     index_dir: str | None = None           # default: <model_dir>/../index_maps
     dense_feature_shards: list[str] = dataclasses.field(default_factory=list)
     evaluators: list[EvaluatorType] = dataclasses.field(default_factory=list)
-    # JAX persistent compilation cache (see TrainingConfig): the 1037 s
-    # scoring-program compile (PERF.md) is paid once per program shape.
-    compilation_cache_dir: str | None = None
     # Streaming fused scoring (estimators.streaming_scorer, ISSUE 4):
     # score_chunk_rows activates the one-pass chunked pipeline — every
     # coordinate scored by ONE fused device program per fixed-shape
@@ -625,9 +615,6 @@ class ServingConfig:
     # (zero dropped requests; a corrupt manifest keeps the previous
     # good model).  0 disables the watcher.
     hot_swap_poll_s: float = 2.0
-    # Persistent XLA compilation cache: bucket warm-up compiles are
-    # paid once per program shape across server restarts.
-    compilation_cache_dir: str | None = None
     # Telemetry/monitoring: the request path is instrumented (latency
     # histograms, queue-depth gauge, batch-fill counters) through a
     # telemetry session and the live monitor's alert rules (incl.

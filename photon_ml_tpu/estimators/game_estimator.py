@@ -110,6 +110,17 @@ def _optimizer_config(settings: OptimizerSettings) -> OptimizerConfig:
     )
 
 
+def _resolve_layout(requested: str, off_tpu: str) -> str:
+    """``AUTO`` → the GRR compiled plan on a TPU (the only backend the
+    Mosaic kernel runs on), ``off_tpu`` elsewhere; anything else as
+    requested."""
+    if requested != "AUTO":
+        return requested
+    import jax
+
+    return "GRR" if jax.default_backend() == "tpu" else off_tpu
+
+
 class GameEstimator:
     """Build coordinates once; fit once per λ-grid point."""
 
@@ -213,12 +224,7 @@ class GameEstimator:
                     build_chunked_batch,
                 )
 
-                layout = cfg.chunk_layout
-                if layout == "AUTO":
-                    import jax
-
-                    layout = ("GRR" if jax.default_backend() == "tpu"
-                              else "ELL")
+                layout = _resolve_layout(cfg.chunk_layout, "ELL")
                 from photon_ml_tpu.data.chunk_store import (
                     resolve_spill_dir,
                 )
@@ -249,12 +255,7 @@ class GameEstimator:
                 # path IS the distributed path — and colmajor elsewhere.
                 from photon_ml_tpu.parallel import shard_sparse_batch
 
-                layout = cfg.sparse_layout
-                if layout == "AUTO":
-                    import jax
-
-                    layout = ("GRR" if jax.default_backend() == "tpu"
-                              else "COLMAJOR")
+                layout = _resolve_layout(cfg.sparse_layout, "COLMAJOR")
                 batch = shard_sparse_batch(
                     rows, dim, labels, mesh, weights=weights,
                     layout=layout.lower(),
@@ -264,12 +265,7 @@ class GameEstimator:
                 # Layout: the GRR compiled plan is the fast TPU path
                 # (the intercept column lands on its dense MXU side);
                 # plain ELL elsewhere (see data/grr.py).
-                layout = cfg.sparse_layout
-                if layout == "AUTO":
-                    import jax
-
-                    layout = ("GRR" if jax.default_backend() == "tpu"
-                              else "ELL")
+                layout = _resolve_layout(cfg.sparse_layout, "ELL")
                 # Device ELL is only consumed by normalization stats
                 # and the down-sampled view; a GRR batch that needs
                 # neither skips the 8-bytes/nnz HBM copy.
@@ -1107,12 +1103,12 @@ class GameEstimator:
         trains as ONE batched sweep — every grid point shares each
         objective evaluation's data stream instead of paying its own
         full fit; other shapes fit once per grid point."""
-        # Programmatic callers (no driver) still get the warm compile
-        # path from config; no-op when neither config nor env sets it.
+        # Programmatic callers (no driver) get the warm compile path
+        # too.
         from photon_ml_tpu import telemetry
         from photon_ml_tpu.cache import enable_compilation_cache
 
-        enable_compilation_cache(self.config.compilation_cache_dir)
+        enable_compilation_cache()
         # Telemetry honors the config knob for programmatic callers too
         # (a driver-configured session takes precedence — maybe_session
         # is a no-op when one is already active).  The whole grid fit

@@ -6,17 +6,23 @@ The rebuild's data plane is host-side array construction; the hot parts
 (LIBSVM text parsing, the transposed-ELL counting sort) live in
 ``fast_etl.cpp`` and are bound here.
 
-Build model: ``g++ -O3 -shared -fPIC`` into a per-version cached .so
-next to the source on first use (seconds, once).  Every caller treats
-``lib()`` returning None as "no native library" and falls back to the
-numpy implementation, so the framework works on machines with no
-toolchain.  ``PHOTON_ML_TPU_NATIVE=0`` forces the fallback (bench
-comparisons, debugging).
+Build model: ``g++ -O3 -shared -fPIC`` on first use (seconds, once) into
+a .so next to the source whose name carries a hash of the source and the
+build command, so "is the binary stale" is decided from content, not
+from mtimes a copy does not keep.  The build is portable (no
+``-march=native``): a tree copied to another machine may carry the .so.
+Every caller treats ``lib()`` returning None as "no native library" and
+falls back to the numpy implementation, so the framework works on
+machines with no toolchain; the reason goes to stderr, and
+``chip_smoke.py`` treats None as a failure.  ``PHOTON_ML_TPU_NATIVE=0``
+forces the fallback (bench comparisons, debugging).
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import sys
@@ -26,20 +32,29 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "fast_etl.cpp")
-_SO = os.path.join(_HERE, f"_fast_etl_{sys.implementation.cache_tag}.so")
+_BUILD_CMD = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC"]
+_SO_PREFIX = os.path.join(
+    _HERE, f"_fast_etl_{sys.implementation.cache_tag}")
 
 _lock = threading.Lock()
 _lib: "ctypes.CDLL | None | bool" = False  # False = not yet attempted
 
 
-def _build() -> bool:
-    cmd = [
-        "g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-        _SRC, "-o", _SO,
-    ]
+def _so_path() -> str:
+    h = hashlib.sha256(" ".join(_BUILD_CMD).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return f"{_SO_PREFIX}_{h.hexdigest()[:16]}.so"
+
+
+def _build(so: str) -> bool:
+    # Build beside the target and rename into place: another process
+    # (a test worker, a fleet host) may be loading the same path.
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         proc = subprocess.run(
-            cmd, capture_output=True, text=True, timeout=120
+            _BUILD_CMD + [_SRC, "-o", tmp],
+            capture_output=True, text=True, timeout=120
         )
     except (OSError, subprocess.TimeoutExpired) as e:
         sys.stderr.write(
@@ -53,6 +68,10 @@ def _build() -> bool:
             f"{proc.stderr[:2000]}\n"
         )
         return False
+    os.replace(tmp, so)
+    for stale in glob.glob(f"{_SO_PREFIX}*.so"):
+        if stale != so:
+            os.remove(stale)
     return True
 
 
@@ -69,15 +88,16 @@ def lib() -> "ctypes.CDLL | None":
         if read_env("PHOTON_ML_TPU_NATIVE") == "0":
             _lib = None
             return None
-        if not os.path.exists(_SO) or (
-            os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-        ):
-            if not _build():
-                _lib = None
-                return None
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
+            _lib = None
+            return None
         try:
-            dll = ctypes.CDLL(_SO)
-        except OSError:
+            dll = ctypes.CDLL(so)
+        except OSError as e:
+            sys.stderr.write(
+                f"photon_ml_tpu.native: cannot load {so} ({e!r}); using "
+                "the numpy fallbacks\n")
             _lib = None
             return None
         dll.pml_libsvm_parse.restype = ctypes.c_void_p

@@ -110,7 +110,8 @@ def grr_contract_kernel(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_ow, group, TILE), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_ow, group, TILE), jnp.float32,
+                                       vma=jax.typeof(vals).vma),
         interpret=interpret,
     )(gw_of_st, ow_of_st, first_of_ow, table_t, g1, g2, g3, vals)
 
@@ -171,7 +172,8 @@ def grr_contract_kernel_dense(
     parts = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_st_p, group, TILE), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_st_p, group, TILE), jnp.float32,
+                                       vma=jax.typeof(vals).vma),
         interpret=interpret,
     )(gwg, table_t, g1, g2, g3, vals)
     # ow reduction: position in the full grid IS the (gw, ow) pair, so

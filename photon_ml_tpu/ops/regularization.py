@@ -38,35 +38,17 @@ class RegularizationType(str, enum.Enum):
     ELASTIC_NET = "ELASTIC_NET"
 
 
-_HALF = None
-
-
-def _half():
-    """0.5 for ``l2_value``, device-resident on the EAGER path.
+@jax.jit
+def _half_l2_sq_norm(l2_weight: Array, wm: Array) -> Array:
+    """½·λ₂·‖wm‖² as ONE jitted program.
 
     ``value_and_gradient`` adds the reg term outside the jitted chunk
-    programs, and an eager ``0.5 * array`` implicitly uploads a fresh
-    host scalar every evaluation — a per-pass host→device transfer the
-    runtime transfer guard (``analysis.guards.no_implicit_transfers``)
-    rightly rejects.  ``device_put`` is the explicit, planned spelling;
-    lazy so importing this module never initializes a backend (the
-    multi-host driver must call ``jax.distributed.initialize`` first).
-    The cached constant is safe under any trace (a concrete device
-    array is just a constant there), but CREATING it must not cache a
-    tracer: under an abstract (jit) trace ``device_put`` returns a
-    tracer, and under vmap's CONCRETE batching trace every op executes
-    eagerly — so a plain-literal fallback would still upload
-    implicitly (the swept ``_lane_reg`` path hits exactly this).
-    First use under a trace therefore takes an UNCACHED explicit
-    ``device_put``: allowed by the transfer guard, folded as a
-    constant by abstract traces."""
-    global _HALF
-    if _HALF is not None:
-        return _HALF
-    if jax.core.trace_state_clean():
-        _HALF = jax.device_put(np.float32(0.5))
-        return _HALF
-    return jax.device_put(np.float32(0.5))
+    programs, and an eager ``0.5 * array`` uploads a fresh host scalar
+    every evaluation — a per-pass implicit host→device transfer that
+    ``analysis.guards.no_implicit_transfers`` rejects.  Inside a jit the
+    0.5 is a literal of the compiled program: nothing is uploaded, on
+    the eager path or under any outer trace (jit, vmap, shard_map)."""
+    return 0.5 * l2_weight * jnp.vdot(wm, wm)
 
 
 @struct.dataclass
@@ -123,7 +105,7 @@ class RegularizationContext:
 
     def l2_value(self, w: Array) -> Array:
         wm = self._masked(w)
-        return _half() * self.l2_weight * jnp.vdot(wm, wm)
+        return _half_l2_sq_norm(self.l2_weight, wm)
 
     def l2_gradient(self, w: Array) -> Array:
         return self.l2_weight * self._masked(w)

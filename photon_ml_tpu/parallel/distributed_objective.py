@@ -33,6 +33,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from flax import struct
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from photon_ml_tpu.data.batch import Batch
@@ -41,33 +42,6 @@ from photon_ml_tpu.ops.regularization import RegularizationContext
 from photon_ml_tpu.parallel.mesh import DATA_AXIS, batch_spec
 
 Array = jax.Array
-
-# jax >= 0.6 exposes shard_map at top level with the replication check
-# spelled ``check_vma``; older builds ship it under jax.experimental
-# with the same semantics as ``check_rep``.
-try:
-    from jax import shard_map as _shard_map_impl
-    _CHECK_KW = "check_vma"
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _CHECK_KW = "check_rep"
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KW: check_vma})
-
-
-def _vma(batch) -> bool:
-    """Whether shard_map may validate varying-mesh-axes for this batch.
-
-    Only the GRR layout must disable it: pallas_call (the GRR kernel)
-    cannot annotate vma on its out_shape, which vma checking requires of
-    everything inside a shard_map.  Every other layout (colmajor/ELL/
-    dense) keeps the validation on, so replication bugs on those paths
-    still fail loudly (advisor finding).
-    """
-    return getattr(batch, "grr", None) is None
 
 
 @struct.dataclass
@@ -96,9 +70,9 @@ class DistributedGLMObjective:
         def local(w, batch):
             return jax.lax.psum(self._data_obj.value(w, batch), DATA_AXIS)
 
-        val = _shard_map(
+        val = shard_map(
             local, mesh=self.mesh, in_specs=(P(), batch_spec()),
-            out_specs=P(), check_vma=_vma(batch),
+            out_specs=P(),
         )(w, batch)
         return val + self.objective.reg.l2_value(w)
 
@@ -107,9 +81,9 @@ class DistributedGLMObjective:
             v, g = self._data_obj.value_and_gradient(w, batch)
             return jax.lax.psum((v, g), DATA_AXIS)
 
-        v, g = _shard_map(
+        v, g = shard_map(
             local, mesh=self.mesh, in_specs=(P(), batch_spec()),
-            out_specs=(P(), P()), check_vma=_vma(batch),
+            out_specs=(P(), P()),
         )(w, batch)
         reg = self.objective.reg
         return v + reg.l2_value(w), g + reg.l2_gradient(w)
@@ -123,9 +97,9 @@ class DistributedGLMObjective:
                 self._data_obj.hessian_vector(w, v, batch), DATA_AXIS
             )
 
-        hv = _shard_map(
+        hv = shard_map(
             local, mesh=self.mesh, in_specs=(P(), P(), batch_spec()),
-            out_specs=P(), check_vma=_vma(batch),
+            out_specs=P(),
         )(w, v, batch)
         return hv + self.objective.reg.l2_hessian_vector(v)
 
@@ -135,26 +109,26 @@ class DistributedGLMObjective:
                 self._data_obj.hessian_diagonal(w, batch), DATA_AXIS
             )
 
-        hd = _shard_map(
+        hd = shard_map(
             local, mesh=self.mesh, in_specs=(P(), batch_spec()),
-            out_specs=P(), check_vma=_vma(batch),
+            out_specs=P(),
         )(w, batch)
         return hd + self.objective.reg.l2_hessian_diagonal(w)
 
     # Scoring: no reduction — per-example outputs stay sharded in place.
     def predict_margins(self, w: Array, batch: Batch) -> Array:
-        return _shard_map(
+        return shard_map(
             lambda w, b: self._data_obj.predict_margins(w, b),
             mesh=self.mesh, in_specs=(P(), batch_spec()),
-            out_specs=batch_spec(), check_vma=_vma(batch),
+            out_specs=batch_spec(),
         )(w, batch)
 
     def x_dot(self, v: Array, batch: Batch) -> Array:
         """Raw X·v per example (coordinate scoring).  Must run under
         shard_map: a per-shard layout (GRR plan / colmajor) indexes only
         its device's rows, so the contraction is shard-local."""
-        return _shard_map(
+        return shard_map(
             lambda v, b: b.x_dot(v),
             mesh=self.mesh, in_specs=(P(), batch_spec()),
-            out_specs=batch_spec(), check_vma=_vma(batch),
+            out_specs=batch_spec(),
         )(v, batch)

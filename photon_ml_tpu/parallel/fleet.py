@@ -471,11 +471,8 @@ class FleetReducer:
         if prog is None:
             import jax
             import jax.numpy as jnp
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
-
-            from photon_ml_tpu.parallel.distributed_objective import (
-                _shard_map,
-            )
 
             mesh = self._hosts_mesh()
 
@@ -484,7 +481,7 @@ class FleetReducer:
                              for x in xs)
 
             # photon-lint: disable=jit-in-function (memoized in self._psum_cache keyed on leaf shapes/dtypes; one compile per pytree signature)
-            prog = jax.jit(_shard_map(
+            prog = jax.jit(shard_map(
                 red, mesh=mesh,
                 in_specs=(P(HOSTS_AXIS),) * n_leaves,
                 out_specs=(P(),) * n_leaves))
@@ -605,19 +602,14 @@ jax.distributed.initialize(
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map as shard_map
-    kw = {"check_vma": False}
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-    kw = {"check_rep": False}
+from jax import shard_map
 mesh = Mesh(np.asarray(jax.devices()[:jax.process_count()]), ("hosts",))
 arr = jax.make_array_from_single_device_arrays(
     (jax.process_count(),), NamedSharding(mesh, P("hosts")),
     [jax.device_put(jnp.ones((1,)), jax.local_devices()[0])])
 out = jax.jit(shard_map(lambda x: jax.lax.psum(x[0], "hosts"),
                         mesh=mesh, in_specs=P("hosts"),
-                        out_specs=P(), **kw))(arr)
+                        out_specs=P(), check_vma=False))(arr)
 assert float(np.asarray(out.addressable_data(0))) == jax.process_count()
 print("FLEET_PROBE_OK", flush=True)
 '''

@@ -200,39 +200,6 @@ class SubprocessReplicaLauncher:
             f.write(config_to_json(cfg))
         return path
 
-    @staticmethod
-    def _replica_env() -> dict:
-        """The child environment: inherit, but pin JAX_PLATFORMS to
-        the supervisor's RESOLVED backend when the env does not pin
-        one.  An unset JAX_PLATFORMS makes the replica probe every
-        plugin at its own jax init — on a TPU-less box the libtpu
-        plugin spends MINUTES timing out against the cloud metadata
-        endpoint before falling back to CPU, which reads as a replica
-        that never warms.  Where the env already pins a platform
-        (production images do) this is a no-op."""
-        env = dict(os.environ)  # photon-lint: disable=env-read (whole-environment passthrough for the replica subprocess, not a config-knob read; JAX_PLATFORMS is jax's own variable, not a photon knob for the sanctioned registry)
-        if "JAX_PLATFORMS" not in env:
-            try:
-                import jax
-
-                # Prefer the CONFIGURED platform string (set by e.g.
-                # jax.config.update("jax_platforms", ...) — reading it
-                # initializes nothing); only fall back to
-                # default_backend(), which initializes the supervisor's
-                # backend — a one-time cost here, amortized over every
-                # replica spawn/restart that would otherwise each pay
-                # the full plugin probe.
-                platforms = None
-                try:
-                    platforms = jax.config.jax_platforms
-                except Exception:  # photon-lint: disable=swallowed-exception (older jax without the config attr: fall through to default_backend)
-                    pass
-                env["JAX_PLATFORMS"] = (platforms
-                                        or jax.default_backend())
-            except Exception:  # photon-lint: disable=swallowed-exception (no jax in the supervisor process: the replica resolves its own platform exactly as before)
-                pass
-        return env
-
     def launch(self, idx: int) -> ReplicaHandle:
         cfg_path = self._replica_config_path(idx)
         info_path = os.path.join(self.workdir, f"replica_{idx}.info")
@@ -245,10 +212,14 @@ class SubprocessReplicaLauncher:
         err = open(os.path.join(self.workdir, f"replica_{idx}.err"),
                    "ab")
         try:
+            # The replica inherits the supervisor's environment as it
+            # is.  The supervisor itself never initialises a JAX
+            # backend: a chip belongs to one process, and it is the
+            # replica that needs it.
             proc = subprocess.Popen(
                 [sys.executable, "-m", "photon_ml_tpu.serving",
                  "--config", cfg_path, "--info-file", info_path],
-                stdout=out, stderr=err, env=self._replica_env())
+                stdout=out, stderr=err)
         finally:
             out.close()
             err.close()

@@ -134,7 +134,7 @@ class ModelServer:
         from photon_ml_tpu.telemetry import monitor as _mon
 
         cfg = self.config
-        enable_compilation_cache(cfg.compilation_cache_dir)
+        enable_compilation_cache()
         logger.info("model server bound on http://%s:%d (warming)",
                     cfg.host, self.port)
         if cfg.telemetry != "off" and telemetry.active() is None:

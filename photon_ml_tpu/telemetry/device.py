@@ -14,11 +14,13 @@ into emitted data riding the telemetry session:
   "Compiling" record is emitted, so the compile-budget counters and
   guard tests are untouched (verified: ``jax.compiles`` stays 0 across
   a capture of a warm program).
-- **Roofline estimate**: bytes-accessed over the platform's peak memory
-  bandwidth — the analytic time floor the report compares against the
-  measured per-chunk span.  Peaks are a small static table (v5e HBM is
-  the measured platform of record; CPU gets a labeled nominal figure so
-  the estimate is never silently null on the test backend).
+- **Roofline estimate**: bytes-accessed over the device's published
+  peak memory bandwidth — the analytic time floor the report compares
+  against the measured per-chunk span.  Peaks are a small static table
+  keyed by ``device_kind``, each with its source; an accelerator that
+  is not in it is an error, never a default.  The CPU backend is the
+  host, not a device with a roofline: there the counts (FLOPs, bytes)
+  are captured and no estimate is made.
 - **Device memory**: ``Device.memory_stats()`` where the backend
   provides it (TPU/GPU), a ``jax.live_arrays()`` nbytes census as the
   CPU fallback — sampled at phase boundaries (every cat="phase" span
@@ -37,16 +39,22 @@ import sys
 
 logger = logging.getLogger(__name__)
 
-# Peak memory bandwidth per jax platform, GB/s.  "tpu" is the v5e HBM
-# figure the bench's roofline_fraction already uses (bench.V5E_PEAK_GBPS);
-# "cpu" is a labeled nominal (dual-channel DDR4) so CPU-backend runs and
-# tests still emit a non-null estimate — the CPU number sizes nothing,
-# it keeps the plumbing honest end to end.
-PLATFORM_PEAK_GBPS = {
-    "tpu": (819.0, "v5e HBM peak"),
-    "gpu": (900.0, "nominal A100-class HBM"),
-    "cpu": (25.6, "nominal dual-channel DDR4"),
+# Published peak HBM bandwidth per chip, GB/s, by jax ``device_kind``.
+DEVICE_PEAK_GBPS = {
+    "TPU v5 lite": (819.0, 'Google Cloud documentation, "TPU v5e": '
+                           "16 GB of HBM at 819 GB/s per chip"),
 }
+
+
+def peak_gbps(device_kind: str) -> tuple[float, str]:
+    """(GB/s, source) for ``device_kind``; an unlisted device raises."""
+    try:
+        return DEVICE_PEAK_GBPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for device_kind {device_kind!r}: add it "
+            "to telemetry.device.DEVICE_PEAK_GBPS with its source"
+        ) from None
 
 
 def _jax():
@@ -56,19 +64,10 @@ def _jax():
     return sys.modules.get("jax")
 
 
-def _platform() -> str | None:
-    jax = _jax()
-    if jax is None:
-        return None
-    try:
-        return jax.devices()[0].platform
-    except Exception:  # photon-lint: disable=swallowed-exception (backend probe; cost capture degrades to unlabeled platform)
-        return None
-
-
-def program_cost(fn, args, platform: str | None = None) -> dict | None:
-    """FLOPs / bytes / memory / roofline estimate for jitted ``fn`` at
-    ``args`` via AOT ``lower().compile()``.
+def program_cost(fn, args) -> dict | None:
+    """FLOPs / bytes / memory for jitted ``fn`` at ``args`` via AOT
+    ``lower().compile()``, plus, on an accelerator, the roofline
+    estimate against its ``device_kind``'s published peak.
 
     Call AFTER the program has executed once with congruent arguments:
     the lowering cache then serves the trace, no "Compiling" record is
@@ -98,11 +97,13 @@ def program_cost(fn, args, platform: str | None = None) -> dict | None:
         out["temp_bytes"] = int(mem.temp_size_in_bytes)
     except Exception:  # pragma: no cover - backend-specific  # photon-lint: disable=swallowed-exception (memory_analysis is optional per backend; cost rows just omit it)
         pass
-    platform = platform or _platform()
-    peak = PLATFORM_PEAK_GBPS.get(platform or "")
-    if peak is not None and byts > 0:
-        gbps, source = peak
-        out["platform"] = platform
+    import jax
+
+    device = jax.devices()[0]   # fn just ran: the backend is up
+    out["platform"] = device.platform
+    out["device_kind"] = device.device_kind
+    if device.platform != "cpu" and byts > 0:
+        gbps, source = peak_gbps(device.device_kind)
         out["peak_gbps"] = gbps
         out["peak_source"] = source
         out["roofline_est_ms"] = round(byts / (gbps * 1e9) * 1e3, 6)

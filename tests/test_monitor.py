@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import json
 import math
-import os
 import urllib.request
 
 import numpy as np
@@ -854,13 +853,13 @@ def test_parse_known_bad_requires_reason():
             parse_known_bad([bad])
 
 
-def test_history_known_bad_waives_repo_r05(capsys):
-    """THE satellite acceptance: the real BENCH_r01..r05 trajectory
-    rc-1s on r05's rc-124 — waived with a reason, the gate passes and
-    the markdown echoes the acknowledgment."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    rounds = [os.path.join(root, f"BENCH_r0{i}.json")
-              for i in range(1, 6)]
+def test_history_known_bad_waives_repo_r05(tmp_path, capsys):
+    """THE satellite acceptance: a BENCH_r01..r05 trajectory rc-1s on
+    r05's rc-124 — waived with a reason, the gate passes and the
+    markdown echoes the acknowledgment."""
+    from tests.test_telemetry import _write_five_round_trajectory
+
+    rounds = _write_five_round_trajectory(tmp_path)
     rc = telemetry_main(["history", *rounds])
     capsys.readouterr()
     assert rc == 1                       # unwaived: r05 fails the gate

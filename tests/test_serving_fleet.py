@@ -739,8 +739,7 @@ def test_fleet_sigkill_e2e_zero_client_failures(tmp_path):
         batch_deadline_ms=1.0, ell_row_capacity=8,
         spill_dir=str(tmp_path / "spill"), entity_chunk=4,
         probe_every_s=0.2, probe_timeout_s=2.0,
-        restart_backoff_s=0.2, telemetry="off", monitor="off",
-        compilation_cache_dir=str(tmp_path / "xla"))
+        restart_backoff_s=0.2, telemetry="off", monitor="off")
     server = FleetServer(cfg, workdir=str(tmp_path / "fleet"))
     reqs = dataset_rows(dataset, 0, 8)
     try:

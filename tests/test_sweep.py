@@ -297,7 +297,13 @@ def test_estimator_grid_swept_chunked(rng):
     cfg = _glm_config(reg_weight_grid={"fixed": grid}, intercept=False,
                       chunk_rows=400, chunk_layout="ELL",
                       chunk_max_resident=8)
-    _assert_grid_matches_sequential(cfg, train, None, grid, tol=5e-3)
+    # The swept and the per-point streamed solves sum the chunks' float32
+    # partials in different orders, and each stops where float32 can no
+    # longer resolve a decrease of f: ½·λ·δ² ≲ eps·|f| of slack, so
+    # δ ≲ sqrt(2·2⁻²⁴·|f|/λ) ≈ 2e-2 on the weakest lane (|f| ≈ 700 over
+    # 1200 rows, λ = 0.2; a column has ~30 nonzeros, so the data adds
+    # little curvature).  All but a coefficient or two agree to 5e-3.
+    _assert_grid_matches_sequential(cfg, train, None, grid, tol=3e-2)
 
 
 def test_estimator_grid_swept_owlqn_lane(rng):

@@ -670,7 +670,10 @@ def test_device_cost_captured_on_streamed_fit(tmp_path):
         cost = programs[name]
         assert cost["flops"] > 0
         assert cost["bytes_accessed"] > 0
-        assert cost["roofline_est_ms"] > 0
+        # The counts stand on any backend; a roofline estimate is made
+        # only against a listed accelerator's published peak.
+        assert cost["platform"] == "cpu"
+        assert "roofline_est_ms" not in cost
         assert cost["span"] == "chunk_compute"
     # Phase boundaries sampled the device-memory gauge (CPU → census).
     mem = summary["device"]["memory"]
@@ -726,9 +729,19 @@ def test_report_shows_device_section(tmp_path, capsys):
     tail = json.loads(out.strip().splitlines()[-1])
     dev = tail["device"]["programs"]["chunk_vg"]
     assert dev["bytes_accessed"] > 0
-    # The roofline estimate is joined against the measured span time.
     assert dev["measured_span_ms"] > 0
-    assert dev["roofline_fraction"] is not None
+    # No roofline estimate on the CPU backend, so nothing to join ...
+    assert "roofline_fraction" not in dev
+    # ... and where a capture carries one (an accelerator run), it is
+    # joined against the measured per-dispatch time.
+    from photon_ml_tpu.telemetry.report import _device
+
+    joined = _device({
+        "device": {"programs": {"chunk_vg": {
+            "bytes_accessed": 8.19e8, "roofline_est_ms": 1.0}}},
+        "histograms": {"device.dispatch_s.chunk_vg": {
+            "count": 4, "mean": 0.004}}})
+    assert joined["programs"]["chunk_vg"]["roofline_fraction"] == 0.25
 
 
 # ---------------------------------------------------------------------------
@@ -1090,6 +1103,25 @@ def _write_round(path, record, rc=0, wrapper=False):
                        "record": record}, f)
 
 
+def _write_five_round_trajectory(hist) -> list[str]:
+    """Five synthetic driver captures in the wrapper shape, with the
+    trajectory the gate was built around: a first round with nothing
+    parsed, three clean rounds of rising GRR throughput, and a last
+    round cut at its time limit (rc 124, ``parsed: null``)."""
+    rounds = [
+        (None, 0),
+        ({"value": 2.0e6, "step_ms": 440.0}, 0),
+        ({"value": 1.5e8, "step_ms_grr": 6.8, "etl_grr_s": 52.0}, 0),
+        ({"value": 2.0e8, "step_ms_grr": 4.8, "etl_grr_s": 46.0}, 0),
+        (None, 124),
+    ]
+    paths = []
+    for i, (record, rc) in enumerate(rounds, start=1):
+        paths.append(str(hist / f"BENCH_r0{i}.json"))
+        _write_round(paths[-1], record, rc=rc, wrapper=True)
+    return paths
+
+
 def _stream_record(rows_per_sec, ratio=1.0):
     return {"stream": {"spilled": {"examples_per_sec": rows_per_sec},
                        "pass_time_ratio": ratio}}
@@ -1146,31 +1178,24 @@ def test_history_flags_nonzero_rc_round(tmp_path, capsys):
 
 
 def test_history_over_repo_bench_records(tmp_path, capsys):
-    """THE acceptance check on the real artifacts: the repo's
-    BENCH_r01..r04 trajectory is clean (rc 0); adding one synthetic
-    regressed round — and the real rc-124 r05 — exits rc 1 naming the
+    """THE acceptance check on a driver-capture trajectory (wrapper
+    shape): rounds r01..r04 are clean (rc 0); adding one synthetic
+    regressed round — and the rc-124 r05 — exits rc 1 naming the
     regressed section/metric."""
-    import shutil
-
     from photon_ml_tpu.telemetry.__main__ import main as telemetry_main
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    repo_rounds = [os.path.join(root, f"BENCH_r0{i}.json")
-                   for i in range(1, 6)]
-    assert all(os.path.exists(p) for p in repo_rounds)
+    hist = tmp_path / "hist"
+    hist.mkdir()
+    rounds = _write_five_round_trajectory(hist)
 
-    rc = telemetry_main(["history", *repo_rounds[:4]])
+    rc = telemetry_main(["history", *rounds[:4]])
     tail = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rc == 0 and tail["ok"] is True
 
-    # Repo rounds + a synthetic regressed round: the GRR throughput
+    # All five + a synthetic regressed round: the GRR throughput
     # collapses 40% → rc 1, regression named, r05's rc=124 flagged too.
-    hist = tmp_path / "hist"
-    hist.mkdir()
-    for p in repo_rounds:
-        shutil.copy(p, str(hist / os.path.basename(p)))
     _write_round(str(hist / "BENCH_r99.json"),
-                 {"value": 206592425.1 * 0.6, "step_ms_grr": 4.84})
+                 {"value": 2.0e8 * 0.6, "step_ms_grr": 4.8})
     rc = telemetry_main(["history", str(hist)])
     out = capsys.readouterr().out
     tail = json.loads(out.strip().splitlines()[-1])
