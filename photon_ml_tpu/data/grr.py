@@ -756,9 +756,10 @@ def build_grr_direction(
     VALS = VALS.reshape(n_st, TILE, TILE)
 
     # Route every supertile; fuse route stage 1 into the gather index.
-    # Native batched path (C++ pml_grr_routes) when available; the
-    # Python loop below is the byte-identical-in-semantics fallback
-    # (per-tile colorings may differ — both are proper, sums agree).
+    # Native batched path (C++ pml_grr_routes, on every core from two
+    # blocks of supertiles on) when available; the Python loop below is
+    # the byte-identical-in-semantics fallback (per-tile colorings may
+    # differ — both are proper, sums agree).
     from photon_ml_tpu.native import grr_routes_native
 
     native = grr_routes_native(dst, HI)
@@ -1346,13 +1347,16 @@ def _build_pair_cold(cols, vals, dim, cap, hot_threshold, max_hot,
     # range (or the single row plan) plus the (mid split → tail col)
     # chain — runs through ONE shared thread pool.  The C++ builder and
     # numpy release the GIL, so a multi-core TPU host builds all tasks
-    # concurrently, targeting wall-clock ≈ one scan (this 1-core build
-    # box is measured neutral).  Each task device_puts its OWN finished
-    # plan immediately (PJRT copies asynchronously in the background),
-    # so host→HBM transfers overlap the remaining host builds — the
-    # mid plan's transfer starts before the tail col build finishes,
-    # and early row ranges transfer under late ones.  The final fence
-    # is the caller's (``place_batch``).
+    # concurrently; each task is one thread but for its route
+    # colouring, which ``grr_routes_native`` spreads over every core on
+    # native threads of its own (never this pool: its workers would
+    # wait on tasks queued behind themselves).  The wall is the column
+    # chain's.  Each task device_puts its OWN finished plan immediately
+    # (PJRT copies asynchronously in the background), so host→HBM
+    # transfers overlap the remaining host builds — the mid plan's
+    # transfer starts before the tail col build finishes, and early row
+    # ranges transfer under late ones.  The final fence is the caller's
+    # (``place_batch``).
     from concurrent.futures import ThreadPoolExecutor
 
     # Range planning is a sampled scan (fast) — run it up front so the

@@ -20,10 +20,14 @@
 //   row-ELL      -> transposed-ELL (the colmajor build: counting sort
 //                   by column + virtual-row splitting; O(nnz + dim))
 
+#include <algorithm>
+#include <atomic>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
 #include <new>
+#include <system_error>
+#include <thread>
 #include <vector>
 
 namespace {
@@ -335,7 +339,9 @@ int32_t pml_edge_color(const int32_t* src, const int32_t* dst, int64_t m,
 // kernel executes (ops/grr_kernel.py), with route stage 1 pre-composed
 // with the gather index plane hi.  This is the hot part of compiling a
 // sparse matrix into the GRR plan (data/grr.py) — one Euler-split
-// coloring per supertile, O(slots · log 128) each.
+// coloring per supertile, O(slots · log 128) each.  Supertiles share
+// nothing: this is the serial body over [0, n_st), and
+// pml_grr_routes_blocks below runs it on slices from several threads.
 // Returns 0, or -1 if any tile's dst is not a bijection / coloring
 // arguments are invalid.
 int32_t pml_grr_routes(const int32_t* dst, const int8_t* hi, int64_t n_st,
@@ -374,6 +380,44 @@ int32_t pml_grr_routes(const int32_t* dst, const int8_t* hi, int64_t n_st,
     }
   }
   return 0;
+}
+
+// pml_grr_routes over contiguous blocks of `block` supertiles on
+// `n_threads` threads (the caller's included): each thread takes the
+// next unrouted block off a shared counter and routes it in place, on
+// the pointer offsets of its slice, so the outputs are the bytes one
+// serial call writes.  The threads live for this call only (nothing
+// to carry across a fork) and never touch Python.  Returns 0, or -1 as
+// soon as any block does; the outputs are then unspecified.
+int32_t pml_grr_routes_blocks(const int32_t* dst, const int8_t* hi,
+                              int64_t n_st, int8_t* g1, int8_t* g2,
+                              int8_t* g3, int64_t block,
+                              int32_t n_threads) {
+  constexpr int64_t S = 128 * 128;
+  if (block < 1) return -1;
+  const int64_t n_blocks = (n_st + block - 1) / block;
+  std::atomic<int64_t> next{0};
+  std::atomic<int32_t> rc{0};
+  auto work = [&] {
+    while (rc.load() == 0) {
+      const int64_t t0 = next.fetch_add(1) * block;
+      if (t0 >= n_st) break;
+      if (pml_grr_routes(dst + t0 * S, hi + t0 * S,
+                         std::min(block, n_st - t0), g1 + t0 * S,
+                         g2 + t0 * S, g3 + t0 * S) != 0)
+        rc.store(-1);
+    }
+  };
+  std::vector<std::thread> others;
+  const int64_t n_others = std::min<int64_t>(n_threads, n_blocks) - 1;
+  try {
+    for (int64_t i = 0; i < n_others; ++i) others.emplace_back(work);
+  } catch (const std::system_error&) {
+    // The process may start no more threads: route on those it got.
+  }
+  work();
+  for (auto& th : others) th.join();
+  return rc;
 }
 
 }  // extern "C"

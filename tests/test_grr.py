@@ -595,6 +595,51 @@ def test_col_range_split_matches_global(rng):
         rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("shape", ["uniform_mid", "powerlaw_split"])
+def test_pair_leaves_equal_routed_in_blocks_or_in_one_call(
+        rng, shape, monkeypatch):
+    """Routing the supertiles in blocks on every core (ISSUE 29) moves
+    no byte of a plan: every leaf of a pair whose routed calls cross the
+    block threshold equals the leaf built with every call held to one
+    block, which runs inline as one serial ``pml_grr_routes``."""
+    import jax
+
+    import photon_ml_tpu.native as nat
+
+    if not nat.native_available():
+        pytest.skip("native library unavailable")
+    if shape == "uniform_mid":
+        n, k, dim = 40000, 8, 5000
+        cols = rng.integers(0, dim, size=(n, k)).astype(np.int32)
+        vals = rng.normal(size=(n, k)).astype(np.float32)
+        split = None
+    else:
+        n, k, dim = 12000, 20, 70000
+        cols, vals = _powerlaw_ell(rng, n, k, dim)
+        split = True
+    real, routed = nat.grr_routes_native, []
+
+    def recording(dst, hi):
+        routed.append(dst.shape[0])
+        return real(dst, hi)
+
+    monkeypatch.setattr(nat, "grr_routes_native", recording)
+    blocked = build_grr_pair(cols, vals, dim, col_range_split=split)
+    assert max(routed) > 2 * nat._ROUTE_BLOCK, routed
+    calls = sorted(routed)
+    del routed[:]
+    monkeypatch.setattr(nat, "_ROUTE_BLOCK", 1 << 40)
+    whole = build_grr_pair(cols, vals, dim, col_range_split=split)
+    assert sorted(routed) == calls
+    leaves, structure = jax.tree_util.tree_flatten(blocked)
+    leaves_whole, structure_whole = jax.tree_util.tree_flatten(whole)
+    assert structure == structure_whole and leaves
+    for a, b in zip(leaves, leaves_whole):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+
+
 def test_col_range_split_reduces_spill(rng):
     """On power-law columns the per-range capacities must hold in the
     level-1 kernel what the single global cap pushed to overflow/COO

@@ -12,7 +12,10 @@ import numpy as np
 import pytest
 
 from photon_ml_tpu.native import (
+    _ROUTE_BLOCK,
+    _ptr,
     colmajor_build_native,
+    grr_routes_native,
     lib,
     libsvm_parse_native,
 )
@@ -113,3 +116,70 @@ def test_colmajor_native_pad_vrows_to(rng):
     assert out is not None and out[0].shape == (64, 8)
     with pytest.raises(ValueError, match="pad_vrows_to"):
         colmajor_build_native(cols, vals, 10, 1, pad_vrows_to=2)
+
+
+# -- GRR route colouring on every host core (ISSUE 29) ------------------------
+
+def _route_tiles(rng, n_st):
+    """``n_st`` random slot bijections and gather planes."""
+    dst = np.empty((n_st, 128, 128), np.int32)
+    for t in range(n_st):
+        dst[t] = rng.permutation(128 * 128).reshape(128, 128)
+    hi = rng.integers(0, 128, size=(n_st, 128, 128)).astype(np.int8)
+    return dst, hi
+
+
+def _one_serial_call(dst, hi):
+    """``pml_grr_routes`` itself, once, over every tile."""
+    out = [np.empty_like(hi) for _ in range(3)]
+    rc = lib().pml_grr_routes(_ptr(dst), _ptr(hi), dst.shape[0],
+                              *map(_ptr, out))
+    assert rc == 0
+    return out
+
+
+ROUTE_SIZES = {"none": 0, "one": 1, "block_less_one": _ROUTE_BLOCK - 1,
+               "block": _ROUTE_BLOCK, "block_and_one": _ROUTE_BLOCK + 1,
+               "ragged_tail": 3 * _ROUTE_BLOCK + 5}
+
+
+@pytest.mark.parametrize("size", sorted(ROUTE_SIZES))
+def test_grr_routes_blocked_is_one_serial_call(rng, size):
+    dst, hi = _route_tiles(rng, ROUTE_SIZES[size])
+    routed = grr_routes_native(dst, hi)
+    for got, want in zip(routed, _one_serial_call(dst, hi)):
+        assert got.dtype == np.int8 and got.shape == hi.shape
+        assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+def test_grr_routes_bad_tile_in_any_block_raises(rng, where):
+    n_st = ROUTE_SIZES["ragged_tail"]
+    dst, hi = _route_tiles(rng, n_st)
+    tile = {"first": 2, "middle": _ROUTE_BLOCK + 3, "last": n_st - 1}[where]
+    dst[tile, 5, 7] = dst[tile, 0, 0]       # one slot twice: no bijection
+    with pytest.raises(ValueError, match="not a bijection"):
+        grr_routes_native(dst, hi)
+
+
+def test_grr_routes_callers_at_once_all_return_the_same(rng):
+    """Four threads route above-threshold inputs at once, as the plan
+    build's chains do: none waits on another (joined under a time
+    limit), and each gets the serial call's bytes."""
+    import threading
+
+    dst, hi = _route_tiles(rng, ROUTE_SIZES["ragged_tail"])
+    want = [a.tobytes() for a in _one_serial_call(dst, hi)]
+    got = [None] * 4
+
+    def call(i):
+        got[i] = [a.tobytes() for a in grr_routes_native(dst, hi)]
+
+    threads = [threading.Thread(target=call, args=(i,), daemon=True)
+               for i in range(len(got))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    assert got == [want] * len(got)

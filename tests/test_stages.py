@@ -56,6 +56,10 @@ PARENT = {
 POOL_STAGES = ("grr_row_part", "grr_mid_split", "grr_col_build")
 # Only a fit with a plan cache directory runs these two.
 CACHE_STAGES = ("plan_cache_load", "plan_cache_save")
+# Only a routed call of two blocks or more runs this one, on the thread
+# that called and so inside that thread's chain stage (ISSUE 29); the
+# tiny fit routes no call that large.
+ROUTE_STAGE = "grr_routes"
 # Counts every run of the stage must carry (a stage may carry more).
 COUNTS = {
     "estimator_fit": {"fit", "rows"},
@@ -83,7 +87,8 @@ COUNTS = {
 
 
 def test_stage_table_is_the_whole_of_stages():
-    assert set(PARENT) | set(CACHE_STAGES) == set(telemetry.STAGES)
+    assert set(PARENT) | set(CACHE_STAGES) | {ROUTE_STAGE} \
+        == set(telemetry.STAGES)
     assert len(set(telemetry.STAGES)) == len(telemetry.STAGES)
 
 
@@ -298,6 +303,64 @@ def test_plan_cache_branches_have_their_stage(tmp_path, stage):
     if stage == "plan_cache_load":
         assert grr.last_build_phases["cache_load_s"] == pytest.approx(
             found[-1]["dur"], abs=5e-3)
+
+
+@pytest.fixture(scope="module")
+def routed_build(tmp_path_factory):
+    """(spans, supertiles of every routed call) of one plan build whose
+    larger calls cross the block threshold and whose smaller do not, as
+    telemetry spans."""
+    import photon_ml_tpu.native as nat
+    from photon_ml_tpu.data import grr
+
+    if not nat.native_available():
+        pytest.skip("native library unavailable")
+    rng = np.random.default_rng(3)
+    cols = rng.integers(0, 5000, size=(20000, 8)).astype(np.int32)
+    vals = rng.normal(size=(20000, 8)).astype(np.float32)
+    real, routed = nat.grr_routes_native, []
+
+    def recording(dst, hi):
+        routed.append(dst.shape[0])
+        return real(dst, hi)
+
+    out = tmp_path_factory.mktemp("routed")
+    session = telemetry.start("trace", str(out))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(nat, "grr_routes_native", recording)
+        try:
+            grr.build_grr_pair(cols, vals, 5000)
+        finally:
+            session.close()
+    spans = [e for e in read_run_log(str(out / "run_log.jsonl"))
+             if e["event"] == "span"]
+    return spans, routed
+
+
+def test_grr_routes_stage_only_for_calls_that_went_to_the_threads(
+        routed_build):
+    from photon_ml_tpu.native import _ROUTE_BLOCK, _usable_cores
+
+    spans, routed = routed_build
+    large = sorted(n for n in routed if n > _ROUTE_BLOCK)
+    assert large and len(large) < len(routed)   # both sides of the choice
+    found = [e["args"] for e in spans if e["name"] == ROUTE_STAGE]
+    assert sorted(a["supertiles"] for a in found) == large
+    for args in found:
+        assert set(args) == {"supertiles", "blocks", "workers"}
+        assert args["blocks"] == -(-args["supertiles"] // _ROUTE_BLOCK)
+        assert args["workers"] == min(_usable_cores(), args["blocks"])
+
+
+def test_grr_routes_stage_nests_inside_its_chain_stage(routed_build):
+    spans, _routed = routed_build
+    chains = [e for e in spans if e["name"] in POOL_STAGES]
+    for route in (e for e in spans if e["name"] == ROUTE_STAGE):
+        (chain,) = [c for c in chains if c["tid"] == route["tid"]
+                    and c["ts"] <= route["ts"]
+                    and route["ts"] + route["dur"] <= c["ts"] + c["dur"]]
+        assert route["depth"] == chain["depth"] + 1
+        assert route["cat"] == "stage" and "parent" not in route["args"]
 
 
 # -- the stage object ---------------------------------------------------------
