@@ -37,6 +37,7 @@ from photon_ml_tpu.reliability import checkpoint as _ckpt
 from photon_ml_tpu.telemetry import convergence as _conv
 from photon_ml_tpu.telemetry import monitor as _mon
 from photon_ml_tpu.game.coordinates import Coordinate
+from photon_ml_tpu.optim.base import OptimizationResult
 
 logger = logging.getLogger(__name__)
 
@@ -96,6 +97,27 @@ def _re_diag_reduce(diag):
     conv = sum(jnp.sum(r.converged.astype(jnp.int32)) for r in diag)
     iters = jnp.max(jnp.stack([jnp.max(r.iterations) for r in diag]))
     return conv, iters
+
+
+def _solve_counts(diag) -> dict:
+    """What one solve did, for the ``coord_train`` stage (a scalar
+    ``OptimizationResult`` only; the random effects hand over lists and
+    dicts): its iterations, the line-search trials they paid (the
+    tracker's plane, where states are tracked) and the forward
+    contractions X·v, where the solver counts them.  One device→host
+    copy for all of them."""
+    if not isinstance(diag, OptimizationResult) \
+            or jnp.ndim(diag.iterations) != 0:
+        return {}
+    iterations, passes, tracked, trials = jax.device_get((
+        diag.iterations, diag.forward_passes, diag.tracker.count,
+        diag.tracker.ls_trials))
+    out = {"solver_iterations": int(iterations)}
+    if tracked and trials is not None:   # hand-built trackers have no plane
+        out["ls_trials"] = int(np.nansum(trials))
+    if passes is not None:
+        out["forward_passes"] = int(passes)
+    return out
 
 
 def _diag_fields(diag) -> dict:
@@ -598,10 +620,7 @@ def _run_sweep(coordinates, update_sequence, locked_coordinates, coefs,
                 w, diag = jax.block_until_ready(
                     coord.train(offsets, coefs.get(name),
                                 donate_warm_start=True))
-                if hasattr(diag, "iterations") \
-                        and jnp.ndim(diag.iterations) == 0:
-                    train_stage.set(
-                        solver_iterations=int(diag.iterations))
+                train_stage.set(**_solve_counts(diag))
             with telemetry.stage("coord_score", coordinate=name):
                 new_scores = jax.block_until_ready(coord.score(w))
         # ``offsets`` already holds total − old scores; reusing it

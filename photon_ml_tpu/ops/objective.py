@@ -20,6 +20,17 @@ Everything is a pure function of ``(w, batch)`` so the same objective is
 - vmapped over entity blocks for random-effect solves,
 - shard_mapped over the device mesh for data parallelism.
 
+The algebra is stated once, split at the margins.  A GLM's margins are
+affine in w, ``margins(w) = X·w + o`` with linear part ``margin_step(d) =
+X·d``, and everything else (value, gradient, curvature) is a function of
+given margins and w: ``value_from_margins``,
+``value_and_gradient_from_margins``.  ``value``, ``value_and_gradient``
+and ``hessian_vector`` are compositions of the two sides, and
+``optim.problem.as_margin_split`` hands the sides to a solver as plain
+functions (``optim.base.MarginSplit``): a line search that knows
+``margins(w + a·d) = margins(w) + a·X·d`` contracts X once an iteration
+instead of once a trial (``optim.lbfgs``).
+
 Sign/weight conventions follow the reference: total value =
 Σ_i weight_i·ℓ(margin_i, y_i) + ½·λ₂·‖w‖² (unnormalized by n; L1 handled by
 OWL-QN, not here).
@@ -59,24 +70,38 @@ class GLMObjective:
     # (incremental training, reference PriorDistribution — see ops/prior.py).
     prior: "GaussianPrior | None" = None
 
-    # ---- internals --------------------------------------------------------
+    # ---- the split at the margins ------------------------------------------
+    # The objective is a GLM: f(w) = Σ wl·ℓ(m(w), y) + R(w), with margins
+    # m(w) = X·w + o affine in w.  Everything below is written once, on
+    # the two sides of m: the map into margins (and its linear part),
+    # and the value / gradient / curvature from given margins.
 
-    def _margins(self, w: Array, batch: Batch) -> Array:
-        w_raw = self.norm.model_to_raw(w)
-        m = batch.margins(w_raw)
+    def _x_dot(self, v: Array, batch: Batch, offsets: Array | None) -> Array:
+        m = batch.x_dot(self.norm.model_to_raw(v))
+        if offsets is not None:
+            m = m + offsets
         if not self.norm.is_identity:
-            m = m - self.norm.margin_correction(w)
+            m = m - self.norm.margin_correction(v)
         return m
+
+    def margins(self, w: Array, batch: Batch) -> Array:
+        """w → X·w + o, the affine map into the margins [n] (under
+        normalization the factors fold into w and the shifts become a
+        scalar)."""
+        return self._x_dot(w, batch, batch.offsets)
+
+    def margin_step(self, d: Array, batch: Batch) -> Array:
+        """d → X·d, the linear part of ``margins``:
+        ``margins(w + a·d) = margins(w) + a·margin_step(d)``."""
+        return self._x_dot(d, batch, None)
 
     def _residual_to_grad(self, r: Array, batch: Batch) -> Array:
         """r (already masked+weighted, [n]) → model-space gradient [dim]."""
         g_raw = batch.xt_dot(r)
         return self.norm.grad_to_model(g_raw, jnp.sum(r))
 
-    # ---- TwiceDiffFunction surface ---------------------------------------
-
-    def value(self, w: Array, batch: Batch) -> Array:
-        m = self._margins(w, batch)
+    def value_from_margins(self, m: Array, w: Array, batch: Batch) -> Array:
+        """f(w) given ``m = margins(w)``: no contraction with X."""
         wl = batch.weights * batch.mask
         data_val = jnp.sum(wl * self.loss.loss(m, batch.labels))
         val = data_val + self.reg.l2_value(w)
@@ -84,9 +109,10 @@ class GLMObjective:
             val = val + self.prior.value(w)
         return val
 
-    def value_and_gradient(self, w: Array, batch: Batch) -> tuple[Array, Array]:
-        """The hot path: one fused pass for (value, gradient)."""
-        m = self._margins(w, batch)
+    def value_and_gradient_from_margins(
+        self, m: Array, w: Array, batch: Batch
+    ) -> tuple[Array, Array]:
+        """(f(w), ∇f(w)) given ``m = margins(w)``: one Xᵀr."""
         wl = batch.weights * batch.mask
         val = jnp.sum(wl * self.loss.loss(m, batch.labels)) + self.reg.l2_value(w)
         r = wl * self.loss.d1(m, batch.labels)
@@ -95,6 +121,16 @@ class GLMObjective:
             val = val + self.prior.value(w)
             grad = grad + self.prior.gradient(w)
         return val, grad
+
+    # ---- TwiceDiffFunction surface ---------------------------------------
+
+    def value(self, w: Array, batch: Batch) -> Array:
+        return self.value_from_margins(self.margins(w, batch), w, batch)
+
+    def value_and_gradient(self, w: Array, batch: Batch) -> tuple[Array, Array]:
+        """The hot path: one fused pass for (value, gradient)."""
+        return self.value_and_gradient_from_margins(
+            self.margins(w, batch), w, batch)
 
     def gradient(self, w: Array, batch: Batch) -> Array:
         return self.value_and_gradient(w, batch)[1]
@@ -105,14 +141,10 @@ class GLMObjective:
         Under normalization, (Xv) uses the same margin algebra as the
         forward pass (factors fold into v, shifts become a scalar).
         """
-        m = self._margins(w, batch)
+        m = self.margins(w, batch)
         wl = batch.weights * batch.mask
         d2 = wl * self.loss.d2(m, batch.labels)
-        v_raw = self.norm.model_to_raw(v)
-        xv = batch.x_dot(v_raw)
-        if not self.norm.is_identity:
-            xv = xv - self.norm.margin_correction(v)
-        r = d2 * xv
+        r = d2 * self.margin_step(v, batch)
         out = self._residual_to_grad(r, batch) + self.reg.l2_hessian_vector(v)
         if self.prior is not None:
             out = out + self.prior.hessian_vector(v)
@@ -125,7 +157,7 @@ class GLMObjective:
         factor-only normalization; with shifts the cross-terms are included
         via the expanded square (x_j − s_j)² = x_j² − 2·s_j·x_j + s_j².
         """
-        m = self._margins(w, batch)
+        m = self.margins(w, batch)
         wl = batch.weights * batch.mask
         d2 = wl * self.loss.d2(m, batch.labels)
 
@@ -152,10 +184,10 @@ class GLMObjective:
     # ---- conveniences -----------------------------------------------------
 
     def predict_margins(self, w: Array, batch: Batch) -> Array:
-        return self._margins(w, batch)
+        return self.margins(w, batch)
 
     def predict_means(self, w: Array, batch: Batch) -> Array:
-        return self.loss.mean(self._margins(w, batch))
+        return self.loss.mean(self.margins(w, batch))
 
 
 def _elementwise_square_batch(batch: Batch) -> Batch:

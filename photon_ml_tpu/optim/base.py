@@ -30,7 +30,7 @@ arrays written with ``.at[i].set`` — static shapes, jit/vmap friendly.
 from __future__ import annotations
 
 import enum
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +41,18 @@ Array = jax.Array
 # Objective callables: value_and_grad(w) -> (f, g);  hvp(w, v) -> Hv.
 ValueAndGrad = Callable[[Array], tuple[Array, Array]]
 Hvp = Callable[[Array, Array], Array]
+
+
+class MarginSplit(NamedTuple):
+    """An objective over one batch whose margins are affine in w (a GLM:
+    ``ops.objective.GLMObjective``), split at the margins: what
+    ``lbfgs_solve`` needs to search along ``m + a·X·d`` instead of
+    evaluating ``w + a·d`` from scratch.  ``optim.problem`` builds it."""
+
+    margins: Callable[[Array], Array]             # w → m = X·w + o
+    margin_step: Callable[[Array], Array]         # d → X·d
+    value: Callable[[Array, Array], Array]        # (m, w) → f(w), m = margins(w)
+    value_and_grad: Callable[[Array, Array], tuple[Array, Array]]  # the same → (f, ∇f)
 
 
 class OptimizerType(str, enum.Enum):
@@ -137,6 +149,11 @@ class OptimizationResult:
     iterations: Array   # int32 iterations executed
     converged: Array    # bool: tolerance met (vs iteration-capped)
     tracker: StatesTracker
+    # int32 forward contractions X·v the solve made, where the solver
+    # counts them in its carry (L-BFGS along the margins, which makes
+    # iterations + 1; one that evaluates each trial from w makes
+    # iterations + 1 + Σ ls_trials and counts none).
+    forward_passes: Array | None = None
 
 
 def grad_converged(g_norm: Array, g0_norm: Array, tolerance: float) -> Array:

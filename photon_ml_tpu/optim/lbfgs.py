@@ -23,6 +23,16 @@ TPU-native design notes:
   Curvature pairs use smooth gradients, as in Breeze.
 - Every update is guarded by ``done`` so converged vmap lanes coast (see
   optim.base docstring).
+- **A GLM's margins are affine in w**, ``X·(w + α·d) + o = (X·w + o) +
+  α·(X·d)``.  Handed the objective split at its margins
+  (``optim.base.MarginSplit``) and no L1 term, the solve carries
+  ``m = X·w + o``, contracts ``X·d`` once an iteration, and every
+  line-search trial is [rows]-vector work on ``m + α·X·d``: a solve makes
+  ``iterations + 1`` forward contractions and as many transposed ones,
+  where evaluating each trial from w pays one more forward contraction a
+  trial.  OWL-QN's orthant projection breaks ``w⁺ = w + α·d``, so with L1
+  (and for a bare ``value_and_grad`` callable, which shows no margins)
+  each trial is a whole evaluation.
 """
 
 from __future__ import annotations
@@ -32,6 +42,7 @@ import jax.numpy as jnp
 from flax import struct
 
 from photon_ml_tpu.optim.base import (
+    MarginSplit,
     OptimizationResult,
     OptimizerConfig,
     StatesTracker,
@@ -60,6 +71,9 @@ class _LbfgsCarry:
     converged: Array  # bool — finished due to tolerance
     g0_norm: Array    # scalar — initial gradient norm (for rel. tolerance)
     tracker: StatesTracker
+    # where the search walks the margins (``_along_margins``), else None:
+    margins: Array | None = None         # [n] X·w + o
+    forward_passes: Array | None = None  # int32 — contractions X·v so far
 
 
 def _pseudo_gradient(g: Array, w: Array, l1: Array) -> Array:
@@ -144,13 +158,15 @@ def _line_search(
     For OWL-QN (``xi`` given) trial points are projected onto the starting
     orthant and the slope uses the *actual* displacement x⁺ − x (which may
     differ from α·d where coordinates were clipped to zero).
+    ``value_fn(α, x⁺)`` scores a trial: given α, a caller that knows the
+    objective along the step need not start from x⁺.
     """
 
     def trial(alpha):
         w_try = w + alpha * d
         if xi is not None:
             w_try = jnp.where(jnp.sign(w_try) == xi, w_try, 0.0)
-        return w_try, value_fn(w_try)
+        return w_try, value_fn(alpha, w_try)
 
     def accepts(w_try, f_try):
         return f_try <= f0 + config.ls_c1 * jnp.vdot(pg, w_try - w)
@@ -177,8 +193,62 @@ def _line_search(
     return w_new, f_new, ok, alpha, steps + 1
 
 
+def _by_whole_evaluations(value_and_grad: ValueAndGrad, l1_vec):
+    """How a solve evaluates its points when all it has is ``w → (f, g)``
+    (or an orthant projection that bends the step): every trial, and the
+    accepted point once more, from w.  ``(start, open_search)``:
+
+    - ``start(w0) → (margins, forward_passes, f_smooth, g)``;
+    - ``open_search(c, d) → (trial, accept)``: ``trial(α, w_try) → f``
+      scores a point of the search, ``accept(α, w_new) → (margins,
+      forward_passes, g)`` takes the gradient where it ended.
+
+    This mode carries no margins and counts no contractions."""
+
+    def start(w0):
+        return (None, None, *value_and_grad(w0))
+
+    def open_search(c, d):
+        def trial(alpha, w_try):
+            f, _ = value_and_grad(w_try)
+            return f if l1_vec is None else f + jnp.sum(l1_vec * jnp.abs(w_try))
+
+        def accept(alpha, w_new):
+            return None, None, value_and_grad(w_new)[1]
+
+        return trial, accept
+
+    return start, open_search
+
+
+def _along_margins(split: MarginSplit):
+    """The same two functions for an objective split at its margins and
+    a straight step: one forward contraction at the start and one an
+    iteration (each counted where it is made); a trial is [rows]-vector
+    work, and the accepted point's margins are known when its gradient
+    is taken."""
+
+    def start(w0):
+        m0 = split.margins(w0)
+        return (m0, jnp.asarray(1, jnp.int32), *split.value_and_grad(m0, w0))
+
+    def open_search(c, d):
+        xd, passes = split.margin_step(d), c.forward_passes + 1
+
+        def trial(alpha, w_try):
+            return split.value(c.margins + alpha * xd, w_try)
+
+        def accept(alpha, w_new):
+            m_new = c.margins + alpha * xd
+            return m_new, passes, split.value_and_grad(m_new, w_new)[1]
+
+        return trial, accept
+
+    return start, open_search
+
+
 def lbfgs_solve(
-    value_and_grad: ValueAndGrad,
+    objective: ValueAndGrad | MarginSplit,
     w0: Array,
     config: OptimizerConfig = OptimizerConfig(),
     l1_weight: Array | None = None,
@@ -186,8 +256,12 @@ def lbfgs_solve(
     """Minimize a smooth objective (plus optional L1 term → OWL-QN).
 
     Args:
-      value_and_grad: smooth part — ``w → (f_smooth, ∇f_smooth)``.  The L1
-        term must NOT be folded in; pass it via ``l1_weight``.
+      objective: smooth part — ``w → (f_smooth, ∇f_smooth)``, or a GLM
+        objective split at its margins (``optim.base.MarginSplit``):
+        without an L1 term the line search then walks the margins
+        (module docstring) and the result counts its
+        ``forward_passes``.  The L1 term must NOT be folded in; pass it
+        via ``l1_weight``.
       w0: [dim] initial point.
       l1_weight: None (plain L-BFGS) or per-coordinate L1 weights [dim]
         (scalars broadcast), activating OWL-QN semantics.
@@ -198,14 +272,17 @@ def lbfgs_solve(
     m = config.lbfgs_memory
     d = w0.shape[-1]
     owlqn = l1_weight is not None
-    if owlqn:
-        l1_vec = jnp.broadcast_to(jnp.asarray(l1_weight, w0.dtype), (d,))
+    l1_vec = (jnp.broadcast_to(jnp.asarray(l1_weight, w0.dtype), (d,))
+              if owlqn else None)
+    if isinstance(objective, MarginSplit) and not owlqn:
+        start, open_search = _along_margins(objective)
+    else:
+        if isinstance(objective, MarginSplit):
+            split = objective
+            objective = lambda w: split.value_and_grad(split.margins(w), w)
+        start, open_search = _by_whole_evaluations(objective, l1_vec)
 
-    def full_value(w):
-        f, _ = value_and_grad(w)
-        return f + jnp.sum(l1_vec * jnp.abs(w)) if owlqn else f
-
-    f0_s, g0 = value_and_grad(w0)
+    m0, passes0, f0_s, g0 = start(w0)
     f0 = f0_s + jnp.sum(l1_vec * jnp.abs(w0)) if owlqn else f0_s
     pg0 = _pseudo_gradient(g0, w0, l1_vec) if owlqn else g0
     g0_norm = jnp.linalg.norm(pg0)
@@ -227,6 +304,8 @@ def lbfgs_solve(
         converged=already,
         g0_norm=g0_norm,
         tracker=tracker,
+        margins=m0,
+        forward_passes=passes0,
     )
 
     def cond(c: _LbfgsCarry):
@@ -248,10 +327,11 @@ def lbfgs_solve(
         bad = jnp.vdot(pg, d_dir) >= 0.0
         d_dir = jnp.where(bad, -pg, d_dir)
 
+        trial, accept = open_search(c, d_dir)
         w_new, f_new, ls_ok, alpha, trials = _line_search(
-            full_value, c.w, c.f, pg, d_dir, config, xi
+            trial, c.w, c.f, pg, d_dir, config, xi
         )
-        f_s_new, g_new = value_and_grad(w_new)
+        m_new, passes, g_new = accept(alpha, w_new)
 
         s = w_new - c.w
         y = g_new - c.g
@@ -297,10 +377,14 @@ def lbfgs_solve(
         def keep(new, old):
             return jnp.where(c.done, old, new)
 
+        def moved(new, old):
+            """A rejected search keeps the point it started from."""
+            return keep(jnp.where(ls_ok, new, old), old)
+
         return _LbfgsCarry(
-            w=keep(jnp.where(ls_ok, w_new, c.w), c.w),
-            f=keep(jnp.where(ls_ok, f_new, c.f), c.f),
-            g=keep(jnp.where(ls_ok, g_new, c.g), c.g),
+            w=moved(w_new, c.w),
+            f=moved(f_new, c.f),
+            g=moved(g_new, c.g),
             s_buf=keep(s_buf, c.s_buf),
             y_buf=keep(y_buf, c.y_buf),
             rho_buf=keep(rho_buf, c.rho_buf),
@@ -311,6 +395,10 @@ def lbfgs_solve(
             converged=jnp.logical_or(c.converged, conv),
             g0_norm=c.g0_norm,
             tracker=jax.tree.map(keep, tracker, c.tracker),
+            # None where the mode carries neither (an empty pytree); a
+            # rejected search made its contraction all the same
+            margins=jax.tree.map(moved, m_new, c.margins),
+            forward_passes=jax.tree.map(keep, passes, c.forward_passes),
         )
 
     final = jax.lax.while_loop(cond, body, init)
@@ -322,6 +410,7 @@ def lbfgs_solve(
         iterations=final.iteration,
         converged=final.converged,
         tracker=final.tracker,
+        forward_passes=final.forward_passes,
     )
 
 

@@ -27,6 +27,7 @@ from flax import struct
 from photon_ml_tpu.data.batch import Batch
 from photon_ml_tpu.ops.objective import GLMObjective
 from photon_ml_tpu.optim.base import (
+    MarginSplit,
     OptimizationResult,
     OptimizerConfig,
     OptimizerType,
@@ -35,6 +36,18 @@ from photon_ml_tpu.optim.lbfgs import lbfgs_solve
 from photon_ml_tpu.optim.tron import tron_solve
 
 Array = jax.Array
+
+
+def as_margin_split(obj: GLMObjective, batch: Batch) -> MarginSplit:
+    """The objective over ``batch``, split at its margins for
+    ``lbfgs_solve``."""
+    return MarginSplit(
+        margins=lambda w: obj.margins(w, batch),
+        margin_step=lambda d: obj.margin_step(d, batch),
+        value=lambda m, w: obj.value_from_margins(m, w, batch),
+        value_and_grad=lambda m, w: obj.value_and_gradient_from_margins(
+            m, w, batch),
+    )
 
 
 @struct.dataclass
@@ -82,7 +95,6 @@ class OptimizationProblem:
         """Solve for one batch from one starting point (jittable; when
         called under jit, ``has_l1`` must be supplied — see has_l1)."""
         obj = self.objective
-        vg = lambda w: obj.value_and_gradient(w, batch)
         if has_l1 is None:
             has_l1 = self.has_l1()
         if self.optimizer == OptimizerType.TRON:
@@ -91,10 +103,12 @@ class OptimizationProblem:
                     "TRON requires a smooth objective; use LBFGS (OWL-QN) "
                     "for L1/elastic-net problems"
                 )
+            vg = lambda w: obj.value_and_gradient(w, batch)
             hvp = lambda w, v: obj.hessian_vector(w, v, batch)
             return tron_solve(vg, hvp, w0, self.config)
         l1 = self._l1_vector(w0.shape[-1]) if has_l1 else None
-        return lbfgs_solve(vg, w0, self.config, l1_weight=l1)
+        return lbfgs_solve(as_margin_split(obj, batch), w0, self.config,
+                           l1_weight=l1)
 
 
 def solve_batched(

@@ -116,6 +116,65 @@ def test_batch_contractions_match_float64_numpy(kdd12, sparse_bound):
     _close(grad, xtr)
 
 
+def test_margins_carried_through_a_solve_match_float64_numpy(
+        kdd12, sparse_bound):
+    """The L-BFGS line search walks ``m + a.X.d`` (ISSUE 31): after 30
+    float32 iterations the margins the solver carries (what it hands
+    the gradient of its last accepted point) are still X.w + o of the
+    coefficients it returns, in float64, and every column class is in
+    them."""
+    from photon_ml_tpu.data.normalization import NormalizationContext
+    from photon_ml_tpu.ops import losses
+    from photon_ml_tpu.ops.objective import GLMObjective
+    from photon_ml_tpu.ops.regularization import RegularizationContext
+    from photon_ml_tpu.optim.base import OptimizerConfig
+    from photon_ml_tpu.optim.lbfgs import lbfgs_solve
+    from photon_ml_tpu.optim.problem import as_margin_split
+
+    train, rows, cols, vals, dim = kdd12
+    offsets = np.random.default_rng(8).normal(0, 0.5, cols.shape[0])
+    batch = make_sparse_batch(rows, dim, train.labels, offsets=offsets,
+                              grr=True, keep_ell=False)
+    pair = batch.grr
+    obj = GLMObjective(loss=losses.LOGISTIC,
+                       reg=RegularizationContext.l2(1.0),
+                       norm=NormalizationContext.identity())
+    config = OptimizerConfig(max_iters=30, tolerance=0.0)
+    seen = []   # (margins, w) of every gradient the solve took
+
+    def solve(b, w0):
+        split = as_margin_split(obj, b)
+
+        def value_and_grad(m, w):
+            jax.debug.callback(lambda m, w: seen.append((m, w)), m, w,
+                               ordered=True)
+            return split.value_and_grad(m, w)
+        return lbfgs_solve(split._replace(value_and_grad=value_and_grad),
+                           w0, config)
+
+    result = jax.jit(solve)(batch, jnp.zeros(dim, jnp.float32))
+    jax.effects_barrier()
+    assert int(result.iterations) == 30 and len(seen) == 31
+    # the last accepted point's (a rejected search leaves the carry be)
+    carried = next(m for m, w_at in reversed(seen)
+                   if np.array_equal(w_at, result.w))
+    assert carried.dtype == np.float32
+    w = np.asarray(result.w, np.float64)
+    xw, _xtr = _numpy_products(cols, vals, dim, w, np.zeros(cols.shape[0]))
+    _close(carried, xw + offsets)
+    # by column class: margins that had lost any one class's share
+    # would not pass
+    tail_ids = np.unique(np.asarray(pair.tail.col_seg))
+    for ids in (np.asarray(pair.hot_ids), np.asarray(pair.planned_ids),
+                tail_ids):
+        without = w.copy()
+        without[ids] = 0.0
+        short = _numpy_products(cols, vals, dim, without,
+                                np.zeros(cols.shape[0]))[0] + offsets
+        with pytest.raises(AssertionError):
+            _close(carried, short)
+
+
 # -- (b) every entry in exactly one class -----------------------------------
 
 def test_every_entry_lands_in_exactly_one_class(kdd12, tailed_pair):

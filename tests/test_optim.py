@@ -6,6 +6,8 @@ extra obligation: the same solver must converge per-problem under vmap
 (the random-effect prerequisite, SURVEY.md §7 "masked while_loop").
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,7 @@ from photon_ml_tpu.data.batch import make_dense_batch
 from photon_ml_tpu.data.normalization import NormalizationContext
 from photon_ml_tpu.ops import losses
 from photon_ml_tpu.ops.objective import GLMObjective
+from photon_ml_tpu.ops.prior import GaussianPrior
 from photon_ml_tpu.ops.regularization import RegularizationContext
 from photon_ml_tpu.optim import (
     OptimizationProblem,
@@ -392,3 +395,183 @@ def test_boundary_tau_roots_and_degenerate_direction():
     tau = float(_boundary_tau(jnp.asarray([0.5, 0.0], jnp.float32),
                               jnp.zeros(2, jnp.float32), delta))
     assert np.isfinite(tau) and tau >= 0.0
+
+
+# -- the line search along the margins (ISSUE 31) ---------------------------
+# A GLM's margins are affine in w, so a solve handed the objective split
+# at its margins contracts X.d once an iteration and scores every trial
+# on m + a.X.d.  It is the same algorithm as the solve by whole
+# evaluations: in float64 (rounding out of the way) both make the same
+# decisions.  What float32 rounding does to the carried margins is held
+# to float64 in tests/test_grr_tail.py.
+
+LOSSES = {"logistic": losses.LOGISTIC, "poisson": losses.POISSON,
+          "squared": losses.SQUARED}
+
+
+def _labels(rng, loss, z):
+    if loss == "logistic":
+        return (rng.uniform(size=z.shape) < 1 / (1 + np.exp(-z))).astype(
+            np.float64)
+    if loss == "poisson":
+        return rng.poisson(np.exp(np.clip(z, -3.0, 2.0))).astype(np.float64)
+    return z + rng.normal(size=z.shape)
+
+
+@pytest.fixture(scope="module")
+def kdd12_rows():
+    """The fixed effect of ``game5-kdd12`` at its rehearsal size, with
+    the intercept: (rows, dim, labels)."""
+    from test_grr_tail import _ell, _rehearsal
+
+    train, _valid, _truth = _rehearsal("game5-kdd12")
+    rows, _cols, _vals, dim = _ell(train)
+    return rows, dim, np.asarray(train.labels, np.float64)
+
+
+def _dressed_objective(rng, loss, dim):
+    """Factor-and-shift normalization and a Gaussian prior, float64."""
+    norm = NormalizationContext(
+        factors=jnp.asarray(rng.uniform(0.5, 2.0, dim)),
+        shifts=jnp.asarray(rng.normal(0, 0.1, dim)))
+    prior = GaussianPrior(means=jnp.asarray(rng.normal(0, 0.1, dim)),
+                          precisions=jnp.asarray(rng.uniform(0.5, 2.0, dim)),
+                          weight=jnp.asarray(0.7))
+    return GLMObjective(loss=LOSSES[loss], reg=RegularizationContext.l2(1.0),
+                        norm=norm, prior=prior)
+
+
+def _counting(fn, counter):
+    """``fn`` with every execution (not every trace) counted."""
+    def counted(*args):
+        jax.debug.callback(lambda: counter.append(1))
+        return fn(*args)
+    return counted
+
+
+@pytest.mark.parametrize("layout", ["dense", "ell", "grr_tail"])
+@pytest.mark.parametrize("loss", sorted(LOSSES))
+def test_search_along_margins_is_the_search_by_whole_evaluations(
+        rng, loss, layout, kdd12_rows, monkeypatch):
+    from photon_ml_tpu.data import grr
+    from photon_ml_tpu.data.batch import make_sparse_batch
+    from photon_ml_tpu.optim.problem import as_margin_split
+
+    if layout == "dense":
+        n, dim = 300, 12
+        x = rng.normal(size=(n, dim))
+        y = _labels(rng, loss, x @ rng.normal(0, 0.3, dim))
+        batch = make_dense_batch(x, y, weights=rng.uniform(0.5, 2.0, n),
+                                 offsets=rng.normal(0, 0.5, n),
+                                 dtype=jnp.float64)
+    else:
+        rows, dim, y = kdd12_rows
+        n = len(y)
+        if loss == "squared":
+            y = y + rng.normal(size=n)
+        # one entry does not pay for a column's slots: a tail at 6,000
+        # rows, as at the cell's 3.185e6 (tests/test_grr_tail.py)
+        monkeypatch.setattr(grr, "ECONOMY_SLOTS_PER_ENTRY", 2)
+        batch = make_sparse_batch(
+            rows, dim, y, weights=rng.uniform(0.5, 2.0, n),
+            offsets=rng.normal(0, 0.5, n), dtype=jnp.float64,
+            grr=layout == "grr_tail", keep_ell=layout == "ell")
+        assert (batch.grr is not None and batch.grr.tail.nnz > 0) \
+            == (layout == "grr_tail")
+    obj = _dressed_objective(rng, loss, dim)
+    # Stops well above float64's noise floor, where an Armijo test can
+    # fall either way; and after 10 iterations, because L-BFGS on the
+    # wide input multiplies a difference of one rounding by ten to a
+    # hundred an iteration (squared loss, ELL: 7e-17 of w after 2
+    # iterations, 2e-11 after 5, 6e-10 after 10, 1e-5 after 30).
+    cfg = OptimizerConfig(max_iters=10, tolerance=1e-6)
+    w0 = jnp.zeros(dim, jnp.float64)
+
+    forward_along, forward_whole = [], []
+
+    def along(b, w):
+        split = as_margin_split(obj, b)
+        return lbfgs_solve(split._replace(
+            margins=_counting(split.margins, forward_along),
+            margin_step=_counting(split.margin_step, forward_along)),
+            w, cfg)
+
+    def whole(b, w):
+        return lbfgs_solve(_counting(
+            lambda v: obj.value_and_gradient(v, b), forward_whole), w, cfg)
+
+    got = jax.jit(along)(batch, w0)
+    want = jax.jit(whole)(batch, w0)
+    jax.effects_barrier()
+
+    iterations = int(want.iterations)
+    assert int(got.iterations) == iterations >= 10
+    np.testing.assert_array_equal(got.tracker.ls_trials,
+                                  want.tracker.ls_trials)
+    trials = int(np.nansum(np.asarray(want.tracker.ls_trials)))
+    assert trials > iterations           # some step was backtracked
+    scale = float(jnp.max(jnp.abs(want.w)))
+    assert float(jnp.max(jnp.abs(got.w - want.w))) <= 1e-5 * scale
+    assert float(jnp.abs(got.value - want.value)) \
+        <= 1e-9 * float(jnp.abs(want.value))
+    # what each solve says it made, and what it made
+    assert int(got.forward_passes) == iterations + 1 == len(forward_along)
+    assert want.forward_passes is None
+    assert len(forward_whole) == iterations + 1 + trials
+
+
+def test_search_along_margins_under_vmap_each_lane_is_its_solo_solve(rng):
+    """Lanes whose searches take different numbers of trials: the
+    margins of a lane that has accepted wait, like its w, while the
+    others backtrack."""
+    lanes, n, dim = 6, 60, 5
+    xs = rng.normal(size=(lanes, n, dim)) * rng.uniform(
+        0.5, 6.0, (lanes, 1, 1))
+    ys = np.stack([_labels(rng, "poisson", x @ rng.normal(0, 0.3, dim))
+                   for x in xs])
+    batches = jax.vmap(lambda x, y, o: jax.tree.map(
+        jnp.asarray, make_dense_batch(x, y, offsets=o, dtype=jnp.float64))
+    )(xs, ys, rng.normal(0, 0.5, (lanes, n)))
+    problem = OptimizationProblem(
+        objective=GLMObjective(loss=losses.POISSON,
+                               reg=RegularizationContext.l2(0.5),
+                               norm=NormalizationContext.identity()),
+        config=OptimizerConfig(max_iters=40, tolerance=1e-6))
+    w0s = jnp.zeros((lanes, dim), jnp.float64)
+    run = partial(problem.run, has_l1=False)
+    together = jax.jit(jax.vmap(run))(batches, w0s)
+    trials = np.nansum(np.asarray(together.tracker.ls_trials), axis=1)
+    assert len(set(trials.tolist())) > 2
+    for lane in range(lanes):
+        solo = jax.jit(run)(jax.tree.map(lambda a: a[lane], batches),
+                            w0s[lane])
+        assert int(solo.iterations) == int(together.iterations[lane])
+        assert int(solo.forward_passes) == int(
+            together.forward_passes[lane]) == int(solo.iterations) + 1
+        np.testing.assert_array_equal(solo.tracker.ls_trials,
+                                      together.tracker.ls_trials[lane])
+        np.testing.assert_allclose(together.w[lane], solo.w, rtol=0,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(solo.w))))
+
+
+def test_an_l1_solve_through_the_split_is_the_solve_of_the_bare_callable(rng):
+    """OWL-QN projects each trial onto an orthant, so it evaluates each
+    from w, split or no split: the same program text, and no count."""
+    from photon_ml_tpu.optim.problem import as_margin_split
+
+    x, y, _batch, _obj = _logistic_problem(rng)
+    batch = make_dense_batch(x, y, dtype=jnp.float64)
+    obj = _dressed_objective(rng, "logistic", 8)
+    cfg = OptimizerConfig(max_iters=9)
+    w0 = jnp.zeros(8, jnp.float64)
+    texts = [
+        jax.jit(solve).lower(batch, w0).as_text()
+        for solve in (
+            lambda b, w: lbfgs_solve(as_margin_split(obj, b), w, cfg,
+                                     l1_weight=0.3),
+            lambda b, w: lbfgs_solve(
+                lambda v: obj.value_and_gradient(v, b), w, cfg,
+                l1_weight=0.3))]
+    assert texts[0] == texts[1]
+    res = lbfgs_solve(as_margin_split(obj, batch), w0, cfg, l1_weight=0.3)
+    assert res.forward_passes is None

@@ -85,7 +85,20 @@ def test_streamed_matches_resident(rng, tmp_path, mix, chunk_entities):
     compiles a different f32 summation order, so the two solvers walk
     slightly different trajectories to the same optimum — both below
     the 1e-7 gradient tolerance; coefficients agree to the
-    tolerance/curvature scale, not bitwise."""
+    tolerance/curvature scale, not bitwise.
+
+    The coefficients' bound, 1.2e-3, is what ``F32_SOLVE_ATOL``'s
+    derivation gives, held against readings: the largest difference
+    over the six cases, by dataset seed 42 (this test's), 0, 1, 2, 3,
+    is 6.1e-4, 9.2e-4, 6.1e-4, 9.4e-4, 1.04e-3 with the line search by
+    whole evaluations and 1.05e-3, 9.2e-4, 7.6e-4, 7.4e-4, 5.2e-4 with
+    the search along the margins (ISSUE 31).  One distribution, and
+    the former bound of 1e-3 sat on its edge: the search by whole
+    evaluations is over it at seed 3 as the search along the margins
+    is at 42.  Under either search a lane's rounding depends on its
+    neighbours (1- and 7-lane chunks of a 29-lane bucket differ from
+    it by an ulp after 2 iterations, 4-lane chunks do not), and the
+    solver's slack turns that ulp into 1e-3."""
     ds = _dataset(rng, mix=mix)
     offsets = jnp.asarray(rng.normal(0, 0.3, ds.n).astype(np.float32))
     res = build_random_effect_coordinate("u", ds, "re", _objective(),
@@ -96,7 +109,7 @@ def test_streamed_matches_resident(rng, tmp_path, mix, chunk_entities):
     w_r, _ = res.train(offsets)
     w_s, diag = st.train(offsets)
     assert diag["entities_solved"] == st.grouping.n_total_entities
-    _assert_blocks_close(w_r, w_s, atol=1e-3)
+    _assert_blocks_close(w_r, w_s, atol=1.2e-3)
     np.testing.assert_allclose(np.asarray(res.score(w_r)),
                                np.asarray(st.score(w_s)), atol=2e-3)
     _assert_blocks_close(res.compute_variance_blocks(w_r, offsets),
@@ -180,24 +193,31 @@ def test_warm_store_reuse_across_builds(rng, tmp_path):
 
 def test_corrupt_and_missing_chunks_rebuild_from_lineage(rng, tmp_path):
     """A deleted chunk file and a truncated one both rebuild from the
-    original rows mid-sweep — the store can never fail a run."""
+    original rows mid-sweep — the store can never fail a run — and a
+    rebuilt chunk is the spilled chunk to the last bit: the solve after
+    the damage is the same coordinate's solve before it (one program on
+    one chunk grid, so no other rounding enters)."""
     ds = _dataset(rng)
     offsets = jnp.asarray(rng.normal(0, 0.3, ds.n).astype(np.float32))
-    res = build_random_effect_coordinate("u", ds, "re", _objective(),
-                                         config=CFG)
-    w_r, _ = res.train(offsets)
     st = build_streamed_random_effect_coordinate(
         "u", ds, "re", _objective(), spill_dir=str(tmp_path),
         chunk_entities=4, config=CFG, host_max_resident=1)
     files = sorted(glob.glob(os.path.join(str(tmp_path), "chunks",
                                           f"{st.store.key}-*.npz")))
     assert len(files) == st.store.n_chunks >= 4
-    os.remove(files[-1])
+    w_sound = [np.array(block) for block in st.train(offsets)[0]]  # copies
+    assert st.store.rebuilds == 0
+    # not the chunk the first sweep left resident (the last it loaded)
+    os.remove(files[0])
     with open(files[2], "r+b") as f:
         f.truncate(10)
-    w_s, _ = st.train(offsets)
+    # from the first sweep's start, zeros, not from where it ended
+    w_s, _ = st.train(offsets, warm_start=[
+        np.zeros_like(block) for block in w_sound])
     assert st.store.rebuilds >= 2
-    _assert_blocks_close(w_r, w_s)
+    for sound, rebuilt in zip(w_sound, w_s, strict=True):
+        np.testing.assert_array_equal(np.asarray(sound),
+                                      np.asarray(rebuilt))
 
 
 def _cd_sweeps(coord, offsets_schedule, use_hook=True):

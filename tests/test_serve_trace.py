@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -653,6 +654,12 @@ def test_server_zero_compiles_with_tracing_on(tmp_path):
             for _ in range(4):
                 _post_rows(srv.port, reqs)
         assert compiles.count == 0
+        # a request's trace is finished after its response is written:
+        # the fifth may still be on its way when the client returns
+        deadline = time.monotonic() + 5.0
+        while tracing.active().snapshot()["requests"] < 5 \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert tracing.active().snapshot()["requests"] >= 5
     finally:
         srv.stop()

@@ -200,6 +200,10 @@ def test_traced_fit_counts_say_what_was_done(traced, tiny):
         == ["global", "per_user", "per_item"]
     trains = sorted(_events(traced, "coord_train"))
     assert counts[trains[0]]["solver_iterations"] == 30
+    # the fixed effect's line search walks the margins: one forward
+    # contraction an iteration and one at the start, whatever the trials
+    assert counts[trains[0]]["forward_passes"] == 31
+    assert not any("forward_passes" in counts[e] for e in trains[1:])
     assert sorted(counts[e]["entity_key"]
                   for e in _events(traced, "group_entities")) \
         == ["itemId", "userId"]
@@ -209,6 +213,45 @@ def test_traced_fit_counts_say_what_was_done(traced, tiny):
     (validation,) = _events(traced, "validation")
     assert counts[validation]["rows"] == valid.n
     assert len(_events(traced, "place_batch")) == 2   # the fence, the rest
+
+
+def test_coord_train_counts_of_one_solve():
+    """What ``coord_train`` says of a solve: the trials only where the
+    solver tracked its states, the forward passes only where it counts
+    them (L-BFGS along the margins), nothing for a list of batched
+    results."""
+    import jax.numpy as jnp
+
+    from photon_ml_tpu.data.batch import make_dense_batch
+    from photon_ml_tpu.data.normalization import NormalizationContext
+    from photon_ml_tpu.game.coordinate_descent import _solve_counts
+    from photon_ml_tpu.ops import losses
+    from photon_ml_tpu.ops.objective import GLMObjective
+    from photon_ml_tpu.ops.regularization import RegularizationContext
+    from photon_ml_tpu.optim import OptimizationProblem, OptimizerConfig
+
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(80, 4))
+    batch = make_dense_batch(x, (rng.uniform(size=80) < 0.5).astype(float))
+    w0 = jnp.zeros(4, jnp.float32)
+
+    def solve(reg, **config):
+        return OptimizationProblem(
+            objective=GLMObjective(loss=losses.LOGISTIC, reg=reg,
+                                   norm=NormalizationContext.identity()),
+            config=OptimizerConfig(max_iters=6, tolerance=0.0, **config),
+        ).run(batch, w0)
+
+    tracked = _solve_counts(solve(RegularizationContext.l2(1.0)))
+    assert tracked["solver_iterations"] == 6 \
+        and tracked["forward_passes"] == 7 and tracked["ls_trials"] >= 6
+    assert _solve_counts(solve(RegularizationContext.l2(1.0),
+                               track_states=False)) \
+        == {"solver_iterations": 6, "forward_passes": 7}
+    whole = _solve_counts(solve(RegularizationContext.l1(0.1)))
+    assert set(whole) == {"solver_iterations", "ls_trials"}
+    assert _solve_counts([solve(RegularizationContext.l2(1.0))]) == {}
+    assert _solve_counts({"entities": 3}) == {}
 
 
 def test_traced_fit_is_divided_and_little_is_unnamed(traced):

@@ -238,28 +238,30 @@ def test_fixed_effect_value_and_gradient_compiles(one_chip, on_tpu):
     _assert_fits(compiled)
 
 
-def test_tailed_value_and_gradient_compiles_at_the_public_width(one_chip,
-                                                               on_tpu):
-    """``game5-kdd12``'s pair (ISSUE 30): plans over the 191,182 planned
-    columns, a COO tail of 16,468,262 entries, the full width of
-    54,686,453 in ``w`` and the gradient.  The tail's two contractions
-    carry their named scopes in the compiled program's metadata (the
-    v5e's trace drops it: the benchmark's readers hold on to the tail's
-    length instead)."""
+# ``game5-kdd12``'s fixed effect (ISSUE 30): the full width, its planned
+# columns, training rows and the tail's entries.
+KDD12_WIDTH, KDD12_PLANNED = 54_686_453, 191_182
+KDD12_ROWS, KDD12_TAIL_NNZ = 3_185_000, 16_468_262
+
+
+def _tailed_batch(sharding):
+    """Abstract ``game5-kdd12`` batch: plans over the planned columns,
+    a COO tail, the full width in ``w`` and the gradient."""
     from photon_ml_tpu.data.grr import GrrTail
 
-    leaf = _abstract(one_chip)
-    width, planned, rows, tail_nnz = 54_686_453, 191_182, 3_185_000, 16_468_262
+    leaf = _abstract(sharding)
+    width, planned, rows, tail_nnz = (KDD12_WIDTH, KDD12_PLANNED,
+                                      KDD12_ROWS, KDD12_TAIL_NNZ)
     pair = GrrPair(
         row_dir=_direction([(True, 9360, 4, 12, 778, 0),
                             (False, 778, 4, 12, 778, 125_456)],
-                           planned, rows, one_chip),
+                           planned, rows, sharding),
         col_dir=_direction([(True, 9360, 4, 195, 47, 383_528)],
-                           rows, planned, one_chip),
+                           rows, planned, sharding),
         hot_ids=leaf((30,), jnp.int32), x_hot=leaf((rows, 30)),
         mid_ids=leaf((41,), jnp.int32),
         col_mid=_direction([(False, 195, 64, 195, 1, 8)], rows, 41,
-                           one_chip),
+                           sharding),
         planned_ids=leaf((planned,), jnp.int32),
         tail=GrrTail(
             row_seg=leaf((tail_nnz,), jnp.int32),
@@ -268,18 +270,83 @@ def test_tailed_value_and_gradient_compiles_at_the_public_width(one_chip,
             col_idx=leaf((tail_nnz,), jnp.int32), col_val=leaf((tail_nnz,)),
             n_rows=rows, dim=width),
         width=width)
-    batch = SparseBatch(
+    return SparseBatch(
         values=leaf((rows, 0)), col_ids=leaf((rows, 0), jnp.int32),
         labels=leaf((rows,)), weights=leaf((rows,)), offsets=leaf((rows,)),
         mask=leaf((rows,)), dim=width, grr=pair)
+
+
+def test_tailed_value_and_gradient_compiles_at_the_public_width(one_chip,
+                                                               on_tpu):
+    """``game5-kdd12``'s pair (ISSUE 30): plans over the 191,182 planned
+    columns, a COO tail of 16,468,262 entries, the full width of
+    54,686,453 in ``w`` and the gradient.  The tail's two contractions
+    carry their named scopes in the compiled program's metadata (the
+    v5e's trace drops it: the benchmark's readers hold on to the tail's
+    length instead)."""
+    width = KDD12_WIDTH
     compiled = jax.jit(
         lambda obj, w, b: obj.value_and_gradient(w, b)
-    ).lower(_objective(one_chip, width, width - 1), leaf((width,)),
-            batch).compile()
+    ).lower(_objective(one_chip, width, width - 1),
+            _abstract(one_chip)((width,)), _tailed_batch(one_chip)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "photon/fe_tail_dot/" in text and "photon/fe_tail_tdot/" in text
-    assert f"f32[{tail_nnz}]" in text
+    assert f"f32[{KDD12_TAIL_NNZ}]" in text
+    _assert_fits(compiled)
+
+
+def _segment_sums(jaxpr, loops=0):
+    """(enclosing ``while`` loops, name stack) of every segment-sum
+    (``scatter-add``) in ``jaxpr`` and whatever it calls."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scatter-add":
+            found.append((loops, str(eqn.source_info.name_stack)))
+        inner = loops + (eqn.primitive.name == "while")
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _segment_sums(sub, inner)
+    return found
+
+
+def test_tailed_solve_contracts_the_tail_once_an_iteration(one_chip, on_tpu):
+    """The fixed-effect solve of ``game5-kdd12`` (ISSUE 31): the line
+    search walks the margins, so the iteration's body holds one X.d and
+    one X^T r of the tail (each one segment-sum under its scope) and
+    the line search's loop, nested in it, holds neither; and the whole
+    solve, S and Y and their second copies included, fits the chip."""
+    from photon_ml_tpu.game.coordinates import (
+        _fixed_train_local_donating,
+        _fixed_train_local_impl,
+    )
+    from photon_ml_tpu.optim.base import OptimizerConfig, OptimizerType
+
+    leaf = _abstract(one_chip)
+    static = (OptimizerType.LBFGS, OptimizerConfig(max_iters=30), False)
+    args = (_objective(one_chip, KDD12_WIDTH, KDD12_WIDTH - 1),
+            _tailed_batch(one_chip), leaf((KDD12_ROWS,)), None, None,
+            leaf((KDD12_WIDTH,)))
+    by_scope = {}
+    for loops, stack in _segment_sums(jax.make_jaxpr(
+            lambda *a: _fixed_train_local_impl(*static, *a))(*args).jaxpr):
+        for scope in ("photon/fe_tail_dot", "photon/fe_tail_tdot"):
+            if stack.endswith(scope):
+                by_scope.setdefault(scope, []).append(loops)
+    # before the loop (the start's margins and gradient), then once an
+    # iteration; nothing two loops deep
+    assert by_scope == {"photon/fe_tail_dot": [0, 1],
+                        "photon/fe_tail_tdot": [0, 1]}
+
+    compiled = _fixed_train_local_donating.lower(*static, *args).compile()
+    text = compiled.as_text()
+    assert "/while/body/photon/fe_tail_dot/" in text
+    assert "/while/body/photon/fe_tail_tdot/" in text
+    assert "/while/body/while/body/photon/fe_tail" not in text
+    assert "tpu_custom_call" in text
     _assert_fits(compiled)
 
 
