@@ -22,7 +22,7 @@ contracts twice:
 - ``guards``: runtime context managers (compile counting via
   ``jax.log_compiles``, ``jax.check_tracer_leaks``,
   ``jax.transfer_guard``) with budget assertions wired into the
-  hot-path tests and ``bench.py --guards``.
+  hot-path tests.
 """
 
 from photon_ml_tpu.analysis.checkers import (  # noqa: F401

@@ -879,9 +879,9 @@ def check_naked_clock(ctx: _FileContext):
 # ---------------------------------------------------------------------------
 
 # Dotted lowercase identifier with at least two segments
-# ("namespace.metric"): the report, the bench telemetry block, and the
-# history extractor all address metrics by dotted path, so a flat or
-# mixed-case name silently falls out of every dashboard slice.
+# ("namespace.metric"): the report CLIs address metrics by dotted
+# path, so a flat or mixed-case name silently falls out of every
+# dashboard slice.
 _METRIC_NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)+$")
 _METRIC_FNS = ("count", "gauge", "observe")
 # Receivers that identify the metrics registry at a call site: the
@@ -912,7 +912,7 @@ def check_metric_name(ctx: _FileContext):
                 f"metric name {arg.value!r} is not a dotted lowercase "
                 "identifier (want namespace.metric, e.g. "
                 "'solver.sweeps'); flat or mixed-case names fall out "
-                "of the report/history metric paths")
+                "of the report's metric paths")
 
 
 # ---------------------------------------------------------------------------

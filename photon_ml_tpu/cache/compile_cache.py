@@ -7,8 +7,8 @@ compile options, backend, *cache directory path*), so a run only hits
 what an earlier run wrote when both name the same directory.
 
 ``enable_compilation_cache`` is the one place that decides where that
-directory is; the drivers, the estimator, the server, ``bench.py`` and
-``chip_smoke.py`` all call it and choose nothing themselves:
+directory is; the drivers, the estimator, the server and ``chip_smoke.py``
+all call it and choose nothing themselves:
 
 - where JAX already has a cache directory — it reads
   ``JAX_COMPILATION_CACHE_DIR`` into ``jax_compilation_cache_dir`` at

@@ -45,8 +45,6 @@ SANCTIONED_ENV = {
         "'0' forces the numpy ETL fallbacks (native bindings disabled)"),
     "PHOTON_ML_TPU_GRR": (
         "'0' forces the XLA fallback contraction off the Pallas kernel"),
-    "PHOTON_ML_TPU_BENCH_CACHE": (
-        "bench.py artifact cache dir override"),
     "JAX_COORDINATOR_ADDRESS": (
         "jax.distributed coordinator (multi-host init, training driver)"),
     "JAX_NUM_PROCESSES": "jax.distributed process count",

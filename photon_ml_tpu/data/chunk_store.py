@@ -507,7 +507,7 @@ class ChunkStore:
     main thread; mutation of the window happens under one lock.  The
     instrumentation fields (``loads``/``hits``/``rebuilds``/
     ``peak_resident``/``access_log``) back the LRU-bound and
-    determinism tests and the bench's stream section.
+    determinism tests.
     """
 
     def __init__(self, spill_dir: str, key: str, n_chunks: int,

@@ -221,7 +221,7 @@ def build_chunked_batch(
     (``data.chunk_store``).  Deliberately EXPLICIT at this layer — the
     ``$PHOTON_ML_TPU_SPILL_DIR`` default is applied by the config/
     estimator layer, so library callers building a resident baseline
-    (bench control arms, parity tests) cannot be silently flipped to
+    (parity tests, a comparison's control side) cannot be silently flipped to
     the spill store by ambient environment.  With the disk tier on:
     chunks spill to atomic content-keyed ``.npz`` files and at most
     ``host_max_resident`` decoded chunks stay live.  ELL chunks are
@@ -345,7 +345,7 @@ def build_chunked_batch(
     if spill_dir is None:
         # One aggregation scope around the whole sharded build: every
         # per-shard sub-plan's spill note folds into ONE summary line
-        # (ISSUE 4 satellite — MULTICHIP_r05's tail was 15+ lines).
+        # (a line a sub-plan buries the end of the log an operator reads).
         with collect_spill_warnings():
             built = compile_all(chunk_ids=local_ids)
         chunks = [built.get(i) for i in range(n_chunks)]

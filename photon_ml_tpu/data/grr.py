@@ -114,8 +114,8 @@ class _SpillWarnings:
     reports immediately, then further flagged builds buffer for
     ``_UNSCOPED_WINDOW_S`` and the next note past the window emits ONE
     summary for the whole burst.  Every emission also feeds the
-    ``grr.spill_flagged_builds`` telemetry counter so the report/bench
-    tiers see the signal without parsing log text."""
+    ``grr.spill_flagged_builds`` telemetry counter so the report
+    CLIs see the signal without parsing log text."""
 
     _THRESHOLD = 0.05    # COO fraction below which no one needs to act
     _UNSCOPED_WINDOW_S = 30.0   # unscoped-burst dedupe window
@@ -244,8 +244,8 @@ def collect_spill_warnings():
     healing, ``shard_sparse_batch``'s per-shard set — enters this once
     and every nested ``build_grr_pair``/``build_sharded_grr_pairs``
     scope folds into ONE summary at the outermost exit (the scope is
-    re-entrant), instead of one line per sub-plan (the MULTICHIP_r05
-    tail printed 15+)."""
+    re-entrant), instead of one line per sub-plan (a four-device
+    dry run printed 15+, and an operator reads the last lines of a log)."""
     return _spill_warnings
 
 
@@ -402,7 +402,7 @@ class GrrDirection:
         )
 
     def plan_stats(self) -> dict:
-        """Host-side placement accounting (diagnostics/bench): entries
+        """Host-side placement accounting (diagnostics): entries
         on the level-1 kernel, per-overflow-level entries, and the COO
         residual that stays on the XLA scatter path."""
         lvl1 = int(np.count_nonzero(np.asarray(self.vals)))
@@ -1312,13 +1312,6 @@ def _mid_hot_split(cols, vals_masked, dim, n, mid_threshold, validate,
     return mid.astype(np.int32), col_mid, tail
 
 
-# Phase timings of the most recent ``build_grr_pair`` call (seconds).
-# Written whole (no partial states); read by bench.py so the ETL number
-# of record is self-diagnosing (round-4 verdict: the host-build vs
-# device-transfer split explains captured-vs-claimed ETL discrepancies).
-last_build_phases: dict = {}
-
-
 def _pair_cache_path(cols, vals, dim, cache_dir, config: dict,
                      extra: tuple = ()) -> str:
     """Plan-cache file path for these exact inputs (see
@@ -1344,23 +1337,6 @@ _PLAN_OPTION_NAMES = ("cap", "hot_threshold", "max_hot", "max_hot_bytes",
                       "col_range_split")
 
 
-def pair_cache_path_for(cols, vals, dim, cache_dir: str,
-                        **overrides) -> str:
-    """The cache-file path ``build_grr_pair(cols, vals, dim,
-    **overrides)`` would read/write.  Option defaults are resolved from
-    ``build_grr_pair``'s own signature, so external callers (the bench)
-    never hold a copy that can drift out of sync with it."""
-    import inspect
-
-    sig = inspect.signature(build_grr_pair)
-    config = {n: sig.parameters[n].default for n in _PLAN_OPTION_NAMES}
-    unknown = set(overrides) - set(config)
-    if unknown:
-        raise TypeError(f"unknown plan options: {sorted(unknown)}")
-    config.update(overrides)
-    return _pair_cache_path(cols, vals, dim, cache_dir, config)
-
-
 @_collect_spill_warnings
 def build_grr_pair(
     cols: np.ndarray,
@@ -1375,7 +1351,6 @@ def build_grr_pair(
     overflow_threshold: int | None = None,
     col_range_split: bool | None = None,
     cache_dir: str | None = None,
-    cache_rebuild: bool = False,
 ) -> GrrPair:
     """Compile an ELL batch ([n,k] cols/vals) into the full GRR plan.
 
@@ -1397,19 +1372,12 @@ def build_grr_pair(
     ``cache_dir`` (default ``$PHOTON_ML_TPU_PLAN_CACHE``) enables the
     on-disk plan cache: a hit replaces the whole host build with one
     load + device transfer (the warm path); a miss builds as usual and
-    persists the host plan for the next run.  Phase timings in
-    ``last_build_phases`` record which path ran (``cache_hit``).
-    ``cache_rebuild`` skips the cache READ but still saves — how the
-    bench keeps its cold-ETL number honest while warming the cache.
+    persists the host plan for the next run.  The ``grr_plan_build``
+    stage's ``cache_hit`` count records which path ran.
     """
     cols = np.asarray(cols)
     vals = np.asarray(vals, np.float32)
     n, k = cols.shape
-    # The stages below are the timers; ``phases`` keeps their seconds
-    # under the names bench.py and the plan-cache tests read.
-    phases: dict = {}
-    global last_build_phases
-
     with telemetry.stage("grr_plan_build", rows=n, k=k, dim=dim) as build:
         pair = None
         cache_dir = _resolve_cache_dir(cache_dir)
@@ -1417,26 +1385,19 @@ def build_grr_pair(
         if cache_dir is not None:
             from photon_ml_tpu.cache import plan_cache
 
-            t0 = time.perf_counter()
             _passed = locals()
             cache_path = _pair_cache_path(
                 cols, vals, dim, cache_dir,
                 {name: _passed[name] for name in _PLAN_OPTION_NAMES})
-            phases["cache_lookup_s"] = time.perf_counter() - t0
-            if not cache_rebuild:
-                with telemetry.stage("plan_cache_load") as load:
-                    # place=device_put pipelines the disk read of later
-                    # directions under the async transfer of earlier
-                    # ones.
-                    pair = plan_cache.load_plan(cache_path,
-                                                place=jax.device_put)
-                    load.set(bytes=_nbytes(pair))
+            with telemetry.stage("plan_cache_load") as load:
+                # place=device_put pipelines the disk read of later
+                # directions under the async transfer of earlier ones.
+                pair = plan_cache.load_plan(cache_path,
+                                            place=jax.device_put)
+                load.set(bytes=_nbytes(pair))
             if pair is not None:
-                phases["cache_load_s"] = load.duration_s
                 logger.info("GRR plan cache hit: %s", cache_path)
         cache_hit = pair is not None
-        if cache_dir is not None:
-            phases["cache_hit"] = float(cache_hit)
         if not cache_hit:
             nnz = int(np.count_nonzero(vals))
             _spill_warnings.planned_for(nnz)
@@ -1445,18 +1406,17 @@ def build_grr_pair(
             pair, host_pair, classes = _build_pair_cold(
                 cols, vals, dim, cap, hot_threshold, max_hot,
                 max_hot_bytes, mid_threshold, validate,
-                overflow_threshold, col_range_split, phases)
+                overflow_threshold, col_range_split)
             if cache_path is not None:
                 # Persist the HOST copy (no device pull-back) while the
                 # device transfers drain; failures only cost the next
                 # run its warm path, never this run.
                 with telemetry.stage("plan_cache_save",
-                                     bytes=_nbytes(host_pair)) as save:
+                                     bytes=_nbytes(host_pair)):
                     try:
                         plan_cache.save_plan(cache_path, host_pair)
                     except Exception as e:  # never let the cache fail the run
                         logger.warning("plan cache: save failed (%r)", e)
-                phases["cache_save_s"] = save.duration_s
             build.set(nnz=nnz,
                       directions=_n_directions(host_pair),
                       spill=int(host_pair.row_dir.n_spill
@@ -1473,13 +1433,10 @@ def build_grr_pair(
         build.set(cache_hit=int(cache_hit))
     # The one fence of the fixed effect's placement: every direction's
     # transfer was enqueued where its build ended.
-    with telemetry.stage("place_batch", bytes=_nbytes(pair)) as fence:
+    with telemetry.stage("place_batch", bytes=_nbytes(pair)):
         if cache_hit:
             pair = jax.device_put(pair)   # remaining host leaves
         jax.block_until_ready(pair)
-    phases["transfer_fence_s"] = fence.duration_s
-    phases["total_s"] = build.duration_s + fence.duration_s
-    last_build_phases = phases
     return pair
 
 
@@ -1512,11 +1469,10 @@ def _n_directions(pair: "GrrPair") -> int:
 
 def _build_pair_cold(cols, vals, dim, cap, hot_threshold, max_hot,
                      max_hot_bytes, mid_threshold, validate,
-                     overflow_threshold, col_range_split, phases):
+                     overflow_threshold, col_range_split):
     """``build_grr_pair``'s host build (arguments as there, with
     ``overflow_threshold`` resolved); returns (the pair with every
-    transfer enqueued, its host copy, the column classes) and records
-    the seconds of its parts in ``phases``."""
+    transfer enqueued, its host copy, the column classes)."""
     n = cols.shape[0]
     n_row_windows = max(1, -(-n // WIN))
     with telemetry.stage("grr_hot_split") as hot_split:
@@ -1540,7 +1496,6 @@ def _build_pair_cold(cols, vals, dim, cap, hot_threshold, max_hot,
         x_hot, (cols, vals_masked, dim), tail_entries = _split_classes(
             cols, vals, dim, n, classes)
         hot_split.set(hot_columns=len(hot_ids))
-    phases["hot_split_s"] = hot_split.duration_s
     auto_mid = mid_threshold is None
     if auto_mid:
         mid_threshold = 16 * n_row_windows
@@ -1606,14 +1561,12 @@ def _build_pair_cold(cols, vals, dim, cap, hot_threshold, max_hot,
             mid_split.set(
                 mid_columns=0 if mid_ids_h is None else len(mid_ids_h),
                 spill=0 if col_mid_h is None else int(col_mid_h.n_spill))
-        phases["mid_split_s"] = mid_split.duration_s
         with telemetry.stage("grr_col_build", parent=parent) as col_build:
             col_h = _build_direction_ell(cols, vals_tail, 1, n, dim, cap,
                                          validate, overflow_threshold,
                                          device=False)
             col_build.set(cap=int(col_h.cap), spill=int(col_h.n_spill))
             col_d = jax.device_put(col_h)
-        phases["col_build_s"] = col_build.duration_s
         return ((mid_ids_h, col_mid_h, col_h),
                 (mid_ids_d, col_mid_d, col_d))
 
@@ -1624,7 +1577,6 @@ def _build_pair_cold(cols, vals, dim, cap, hot_threshold, max_hot,
             task.set(bytes=_nbytes(tail_h))
             return tail_h, jax.device_put(tail_h)
 
-    row_t0 = time.perf_counter()
     n_row_tasks = len(ranges) if ranges else 1
     with ThreadPoolExecutor(max_workers=n_row_tasks + 2) as ex:
         f_tail = ex.submit(tail_task) if tail_entries else None
@@ -1637,7 +1589,6 @@ def _build_pair_cold(cols, vals, dim, cap, hot_threshold, max_hot,
         else:
             row_futs = [ex.submit(row_part, None, overflow_threshold)]
         row_results = [f.result() for f in row_futs]
-        phases["row_build_s"] = time.perf_counter() - row_t0
         (mid_ids_h, col_mid_h, col_h), \
             (mid_ids, col_mid, col_dir) = f_col.result()
         tail_h, tail = f_tail.result() if f_tail else (None, None)

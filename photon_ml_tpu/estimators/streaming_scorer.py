@@ -95,7 +95,7 @@ def _run_chunk(specs, mean_fn, tables, chunk):
     dispatch per chunk.  Jitted at module level with the (hashable)
     spec tuple and mean function static, so every scorer instance for
     the same model STRUCTURE shares one compile — repeated scoring
-    passes (bench arms, driver re-runs in-process) never re-trace."""
+    passes (driver re-runs in-process) never re-trace."""
     from photon_ml_tpu.ops.kernels import gather_rowsum
 
     m = chunk["base"]
@@ -258,7 +258,7 @@ class StreamingGameScorer:
         self.host_max_resident = int(host_max_resident)
         self.prefetch_depth = int(prefetch_depth)
         # Plan memo for repeated score() calls over the SAME dataset
-        # object (bench arms, in-process re-scoring): the plan embeds
+        # object (in-process re-scoring): the plan embeds
         # device tables and — with a spill store — a full content hash
         # of every chunk input, which would otherwise be re-derived per
         # pass.  Identity-keyed (strong ref); callers mutating a

@@ -16,7 +16,7 @@ Every caller treats ``lib()`` returning None as "no native library" and
 falls back to the numpy implementation, so the framework works on
 machines with no toolchain; the reason goes to stderr, and
 ``chip_smoke.py`` treats None as a failure.  ``PHOTON_ML_TPU_NATIVE=0``
-forces the fallback (bench comparisons, debugging).
+forces the fallback (comparisons, debugging).
 """
 
 from __future__ import annotations

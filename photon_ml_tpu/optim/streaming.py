@@ -472,8 +472,8 @@ class ChunkedGLMObjective:
     odometer) is exactly the resident path's.
 
     ``sweeps`` counts full chunk sweeps since construction — the
-    data-pass odometer the bench's ``sweep`` section reads to show the
-    L → 1 passes-per-iteration amortization.
+    data-pass odometer that shows the L → 1 passes-per-iteration
+    amortization of a swept solve.
     """
 
     def __init__(self, objective: GLMObjective, batch: ChunkedBatch,
@@ -521,8 +521,8 @@ class ChunkedGLMObjective:
 
     def capture_device_cost(self, w: Array) -> None:
         """Explicit device-cost capture of the per-chunk value+gradient
-        program against chunk 0 (ISSUE 8).  Bench arms call this right
-        after warmup so the capture's AOT relower lands OUTSIDE the
+        program against chunk 0 (ISSUE 8).  A caller that times sweeps calls this
+        right after warmup so the capture's AOT relower lands OUTSIDE the
         timed sweeps; the in-sweep capture then finds the name already
         resolved.  2-D ``w`` captures the swept program.  No-op without
         an active telemetry session or with an empty batch."""

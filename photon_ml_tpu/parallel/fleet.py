@@ -77,7 +77,7 @@ EMPTY_CHUNK = -1
 HOSTS_AXIS = "hosts"
 
 # The jaxlib CPU backend's "no multiprocess collectives" marker: the
-# single capability probe every 2-process CPU test and the bench's
+# single capability probe every 2-process CPU test and a launcher's
 # transport selection key off (ISSUE 16 satellite — previously an
 # ad-hoc string scattered through the mesh tests).
 MULTIPROC_UNSUPPORTED_MARKER = "Multiprocess computations aren't implemented"
@@ -192,7 +192,7 @@ def reducer() -> "FleetReducer | None":
 
 @contextlib.contextmanager
 def session(ctx: FleetContext | None):
-    """Expose ``ctx`` as the active fleet for the block (tests/bench
+    """Expose ``ctx`` as the active fleet for the block (tests'
     workers); None yields a no-op."""
     global _ACTIVE, _REDUCER
     if ctx is None:
@@ -300,7 +300,7 @@ def _recv_msg(fh) -> tuple[dict, bytes]:
 class ReduceCoordinator:
     """Star allreduce for the tcp local-fleet transport.
 
-    Runs in the LAUNCHER (bench parent / test harness / a dedicated
+    Runs in the LAUNCHER (a test harness / a dedicated
     supervisor) — deliberately outside any worker, so killing a worker
     host never takes the reduction plane with it.  Each reduce sequence
     number completes when all ``n_hosts`` contributions arrive; the
@@ -620,7 +620,7 @@ _PROBE_RESULT: bool | None = None
 def probe_cpu_multiprocess_collectives(timeout_s: float = 120.0) -> bool:
     """Whether this environment can run REAL 2-process CPU collectives
     (jax.distributed + cross-process psum).  Spawns two tiny probe
-    workers once per process and caches the verdict — the bench's
+    workers once per process and caches the verdict — a launcher's
     transport selection and the 2-process tests' skip guard share this
     single probe instead of ad-hoc marker scans."""
     global _PROBE_RESULT

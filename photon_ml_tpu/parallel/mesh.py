@@ -197,8 +197,8 @@ def shard_sparse_batch(
     shards = []
     # One spill-warning aggregation scope over the whole sharded build
     # (per-shard batch builds + the sharded plan set below): one
-    # summary line per build, never one per shard sub-plan (ISSUE 4
-    # satellite; MULTICHIP_r05's tail printed 15+).
+    # summary line per build, never one per shard sub-plan (a line a
+    # sub-plan buries the end of the log an operator reads).
     with collect_spill_warnings():
         for i in range(n_dev):
             lo, hi = i * per, min((i + 1) * per, n)

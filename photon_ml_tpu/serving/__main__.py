@@ -14,7 +14,7 @@ with backoff + circuit breaker, and a newly published manifest rolls
 through the replicas one at a time.
 
 ``--info-file`` writes ``{"port", "pid", "url"}`` as soon as the
-socket binds (atomic tmp + replace), so a supervisor or the bench's
+socket binds (atomic tmp + replace), so a supervisor or a
 client harness can discover an ephemeral port and poll ``/healthz``
 for warming → ready.
 """

@@ -430,8 +430,8 @@ class ScoringEngine:
 
 def dataset_rows(dataset, lo: int, hi: int) -> list[dict]:
     """``GameDataset`` rows [lo, hi) → request-row JSON objects (the
-    ``/v1/score`` wire shape).  Test/bench/client helper: the parity
-    suites and the bench's open-loop clients replay real dataset rows
+    ``/v1/score`` wire shape).  Test/client helper: the parity
+    suites and open-loop clients replay real dataset rows
     against the server."""
     offsets = dataset.offset_array()
     sparse = {s: (f if isinstance(f, SparseRows)

@@ -39,7 +39,7 @@ the aggregated ``/status`` fleet view served by the frontend.
 Testability: replica processes hide behind the ``launch()`` seam — the
 tier-1 fault matrix drives the supervisor against in-process stub
 replicas with a fake clock (no subprocess, no sleeps), while the
-slow-marked e2e and the bench fleet arm use the real
+slow-marked e2e uses the real
 ``SubprocessReplicaLauncher``.
 """
 

@@ -495,8 +495,8 @@ def finish(rt: RequestTrace | None, status: int | None = None) -> None:
 def stage_summary(session=None) -> dict | None:
     """Per-stage latency table {stage: {count, p50_ms, p99_ms}} from
     the telemetry registry's ``serve.stage.<stage>_s`` histograms —
-    the ``/status`` stages block, the monitor's dominant-stage input,
-    and the bench's stage-median source.  Uses the registry's
+    the ``/status`` stages block and the monitor's dominant-stage
+    input.  Uses the registry's
     prefix-targeted accessor, NOT the full ``summary()`` snapshot —
     a /status poll must not sort every histogram in the process while
     request threads block on the registry lock."""

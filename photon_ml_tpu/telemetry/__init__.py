@@ -753,7 +753,7 @@ class Telemetry:
 
     def summary(self) -> dict:
         """JSON-ready snapshot of every registry (the
-        ``telemetry_summary`` event body; bench arms embed it)."""
+        ``telemetry_summary`` event body)."""
         with self._lock:
             counters = {k: (round(v, 6) if isinstance(v, float) else v)
                         for k, v in sorted(self._counters.items())}

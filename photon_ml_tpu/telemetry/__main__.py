@@ -1,18 +1,11 @@
 """Telemetry CLI: ``python -m photon_ml_tpu.telemetry
-<report|history|watch|serve-report>``.
+<report|watch|serve-report|fleet-report>``.
 
 ``report <log>`` prints the per-phase / stage-span / overlap /
 convergence / device / reconciliation report for a run's
 ``run_log.jsonl`` (see ``telemetry.report``); exit code 1 when the
 span-vs-wall-clock reconciliation or the convergence sweep-odometer
 check fails.
-
-``history <dir-or-files...>`` ingests bench round records (the repo's
-``BENCH_r*.json`` wrappers, raw bench JSON-last-line records, or
-``bench.py --history-dir`` envelopes) into per-section metric
-trajectories and gates them against a rolling baseline (see
-``telemetry.history``); exit code 1 on any regression or on any round
-with a nonzero rc not waived via ``--known-bad``.
 
 ``watch <log>`` follows a LIVE, still-being-written run log (ISSUE
 10): a refreshing status view — phase, per-stage progress/ETA, loss
@@ -48,12 +41,6 @@ import sys
 from photon_ml_tpu.telemetry import fleet_report as fleet_report_mod
 from photon_ml_tpu.telemetry import serve_report as serve_report_mod
 from photon_ml_tpu.telemetry import watch as watch_mod
-from photon_ml_tpu.telemetry.history import (
-    DEFAULT_TOLERANCE,
-    DEFAULT_WINDOW,
-    parse_known_bad,
-    run_history,
-)
 from photon_ml_tpu.telemetry.report import report
 
 
@@ -69,27 +56,6 @@ def main(argv=None) -> int:
     rp.add_argument("log", help="path to a run_log.jsonl")
     rp.add_argument("--threshold", type=float, default=0.9,
                     help="reconciliation pass threshold (default 0.9)")
-    hp = sub.add_parser(
-        "history", help="bench-record trajectory: aggregate rounds, "
-                        "gate regressions against a rolling baseline")
-    hp.add_argument("paths", nargs="+",
-                    help="history directory or individual bench JSON "
-                         "files (BENCH_r*.json wrappers, raw records, "
-                         "or --history-dir envelopes)")
-    hp.add_argument("--tolerance", type=float, default=DEFAULT_TOLERANCE,
-                    help="relative worsening vs the rolling baseline "
-                         "that counts as a regression (default "
-                         f"{DEFAULT_TOLERANCE})")
-    hp.add_argument("--window", type=int, default=DEFAULT_WINDOW,
-                    help="rolling-baseline width in preceding rounds "
-                         f"(default {DEFAULT_WINDOW})")
-    hp.add_argument("--known-bad", action="append", default=[],
-                    metavar="ROUND=REASON",
-                    help="waive an acknowledged bad round (e.g. "
-                         "BENCH_r05.json=rc-124 budget timeout, see "
-                         "PERF.md): its rc/regressions stop failing "
-                         "the gate; the reason is REQUIRED and echoed "
-                         "in the markdown output. Repeatable.")
     wp = sub.add_parser(
         "watch", help="follow a live run_log.jsonl: phase, per-stage "
                       "progress/ETA, loss trajectory, alerts; exits "
@@ -148,14 +114,6 @@ def main(argv=None) -> int:
                                interval_s=args.interval,
                                max_wait_s=args.max_wait_s)
         return 0 if not snap["thread_exceptions"] else 1
-    if args.cmd == "history":
-        try:
-            waivers = parse_known_bad(args.known_bad)
-        except ValueError as e:
-            p.error(str(e))
-        result = run_history(args.paths, tolerance=args.tolerance,
-                             window=args.window, known_bad=waivers)
-        return 0 if result["ok"] else 1
     result = report(args.log, threshold=args.threshold)
     return 0 if result["ok"] else 1
 

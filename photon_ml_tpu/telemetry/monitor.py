@@ -2,7 +2,7 @@
 status endpoint (ISSUE 10).
 
 Every observability layer so far (spans/metrics/trace, device cost,
-convergence traces, bench history) is post-mortem — nothing tells an
+convergence traces) is post-mortem — nothing tells an
 operator what a RUNNING fit is doing, and the multi-hour streaming
 workloads this repo is built for are exactly where a silent process is
 unacceptable ("Distributed Function Minimization in Apache Spark",
@@ -590,7 +590,7 @@ class Monitor:
                     "residency", first_mb=round(first / 1e6, 1),
                     last_mb=round(mem["last"] / 1e6, 1))
 
-    # -- snapshots for the endpoint / bench ----------------------------------
+    # -- snapshots for the endpoint ------------------------------------------
 
     def status(self) -> dict:
         """JSON-ready live snapshot: the ``/status`` body."""
@@ -628,8 +628,7 @@ class Monitor:
         return out
 
     def summary(self) -> dict:
-        """Run-end summary (the ``monitor_summary`` event body; bench
-        arms embed it as their ``progress`` block)."""
+        """Run-end summary (the ``monitor_summary`` event body)."""
         st = self.status()
         return {
             "snapshots": st["snapshots"],

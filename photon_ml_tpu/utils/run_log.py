@@ -34,7 +34,7 @@ import uuid
 logger = logging.getLogger("photon_ml_tpu")
 
 # run_header schema version (ISSUE 8): bump when header fields change
-# meaning; report/history consumers key their parsing on it and must
+# meaning; the report CLIs key their parsing on it and must
 # tolerate ABSENCE entirely (pre-ISSUE-8 logs have no header).
 RUN_LOG_SCHEMA = 1
 
@@ -102,8 +102,8 @@ class RunLogger:
         driver run appends WITH a header, so the stitched log carries
         one ``run_header`` per process segment and ``telemetry
         report`` can reconcile the segments separately (their clocks
-        restart at each header).  ``report``/``history`` consume it and
-        tolerate its absence in pre-existing logs.
+        restart at each header).  ``report`` consumes it and
+        tolerates its absence in pre-existing logs.
 
         ``flush_every_s`` (ISSUE 10): None (default) flushes after
         EVERY event — maximal freshness for library/test use; a

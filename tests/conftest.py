@@ -6,10 +6,11 @@ tests exercise the real collective code paths without hardware.  Must run
 before jax initializes its backends, hence env mutation at conftest import.
 """
 
+import itertools
 import os
 
-# Tests always run on the CPU backend; child processes (bench arms,
-# serving replicas, fleet hosts) inherit the variable.
+# Tests always run on the CPU backend; child processes (serving
+# replicas, fleet hosts) inherit the variable.
 os.environ["JAX_PLATFORMS"] = "cpu"
 # No persistent compilation cache under test: a run must not depend on
 # what an earlier run left in the checkout's cache directory, six
@@ -36,3 +37,28 @@ import pytest
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture
+def spans_of(tmp_path):
+    """``spans_of(fn, *args, **kwargs)`` calls ``fn`` under a telemetry
+    session of its own in trace mode and returns (its result, the
+    session's ``span`` events): how a test reads the stages of one call
+    (a plan build's ``cache_hit``, its ``plan_cache_load``), which have
+    no other record."""
+    from photon_ml_tpu import telemetry
+    from photon_ml_tpu.utils.run_log import read_run_log
+
+    calls = itertools.count()
+
+    def run(fn, *args, **kwargs):
+        out = tmp_path / f"spans_{next(calls)}"
+        session = telemetry.start("trace", str(out))
+        try:
+            result = fn(*args, **kwargs)
+        finally:
+            session.close()
+        return result, [e for e in read_run_log(str(out / "run_log.jsonl"))
+                        if e["event"] == "span"]
+
+    return run

@@ -6,7 +6,7 @@ a worker that tries dies with the error text pinned as
 ``fleet.MULTIPROC_UNSUPPORTED_MARKER``.  These guards share the fleet
 module's single cached capability probe instead of per-test ad-hoc
 marker scans, so every multi-process test skips (or runs) on the same
-verdict the bench's transport selection uses:
+verdict a launcher's transport selection uses:
 
 - ``require_multiprocess_collectives()`` — probe up front (one cached
   2-worker probe per test process) and ``pytest.skip`` when

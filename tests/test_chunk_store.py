@@ -401,7 +401,7 @@ def test_env_spill_default_applies_at_config_layer_only(
         rng, tmp_path, monkeypatch):
     """$PHOTON_ML_TPU_SPILL_DIR must flow through the config/estimator
     layer and NEVER flip a direct `build_chunked_batch` caller to the
-    spill store — bench control arms and parity baselines build
+    spill store — parity baselines and a comparison's control side build
     resident batches through that API (review finding: an ambient env
     var silently turned the resident arm into spilled-vs-spilled)."""
     from photon_ml_tpu.data.chunk_store import resolve_spill_dir

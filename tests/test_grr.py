@@ -309,7 +309,7 @@ def test_objective_grr_matches_ell(rng):
     np.testing.assert_allclose(
         np.asarray(obj.hessian_diagonal(w, b_ell)),
         np.asarray(obj.hessian_diagonal(w, b_grr)), rtol=2e-4, atol=2e-4)
-    # autodiff through the batch (bench's naive baseline path)
+    # autodiff through the batch (the naive baseline path)
     ga = jax.grad(lambda w: obj.value(w, b_grr))(w)
     np.testing.assert_allclose(np.asarray(ga), np.asarray(g1_),
                                rtol=2e-4, atol=2e-4)
@@ -700,8 +700,8 @@ def test_idx_range_native_matches_numpy(rng):
 def test_spill_warning_rate_limited(caplog):
     """Satellite (round 8): inside a plan build the per-direction "GRR
     spill fraction" warning aggregates into ONE count/min/max/mean
-    summary (MULTICHIP_r05's tail drowned the dryrun in ~20 identical
-    lines); outside any build scope (ISSUE 16 satellite) a flagged
+    summary (a four-device dry run drowned the end of its log in ~20
+    identical lines); outside any build scope (ISSUE 16 satellite) a flagged
     burst dedupes into a time-windowed summary instead of one raw line
     per call."""
     import logging
@@ -773,8 +773,8 @@ def test_spill_warning_aggregates_across_sharded_builds(caplog):
     """Satellite (round 9): a multi-build operation — several plan
     builds inside one ``collect_spill_warnings`` scope, the shape of
     ``build_chunked_batch``/``shard_sparse_batch`` — emits ONE summary
-    for the whole sharded build, not one line per sub-plan (the
-    MULTICHIP_r05 tail printed 15+)."""
+    for the whole sharded build, not one line per sub-plan (a
+    four-device dry run printed 15+)."""
     import logging
 
     from photon_ml_tpu.data.grr import (

@@ -427,12 +427,14 @@ def test_chunked_builder_agrees_with_the_resident_one(kdd12, sparse_bound):
 # -- the plan cache and the spill warning -----------------------------------
 
 def test_a_tailed_pair_round_trips_through_the_plan_cache(
-        kdd12, sparse_bound, tmp_path):
+        kdd12, sparse_bound, tmp_path, spans_of):
     _train, _rows, cols, vals, dim = kdd12
-    built = grr.build_grr_pair(cols, vals, dim, cache_dir=str(tmp_path))
-    assert grr.last_build_phases["cache_hit"] == 0.0
-    loaded = grr.build_grr_pair(cols, vals, dim, cache_dir=str(tmp_path))
-    assert grr.last_build_phases["cache_hit"] == 1.0
+    built, first = spans_of(grr.build_grr_pair, cols, vals, dim,
+                            cache_dir=str(tmp_path))
+    loaded, second = spans_of(grr.build_grr_pair, cols, vals, dim,
+                              cache_dir=str(tmp_path))
+    assert [e["args"]["cache_hit"] for e in first + second
+            if e["name"] == "grr_plan_build"] == [0, 1]
     assert loaded.width == built.width == dim
     assert (loaded.tail.n_rows, loaded.tail.dim) \
         == (built.tail.n_rows, built.tail.dim)

@@ -1,6 +1,6 @@
 """Live run monitoring (ISSUE 10): progress snapshots, online alert
 rules, counter ``rate()``, cadence flushing, the ``telemetry watch``
-CLI, the status endpoint, and the history ``--known-bad`` waiver.
+CLI and the status endpoint.
 
 The alert-rule tests are the acceptance check: synthetic event streams
 pin EXACTLY which rules fire (an injected divergence produces one
@@ -32,7 +32,6 @@ from photon_ml_tpu.optim.streaming import ChunkedGLMObjective
 from photon_ml_tpu.telemetry import monitor
 from photon_ml_tpu.telemetry import watch as watch_mod
 from photon_ml_tpu.telemetry.__main__ import main as telemetry_main
-from photon_ml_tpu.telemetry.history import parse_known_bad
 from photon_ml_tpu.utils.run_log import RunLogger, read_run_log
 
 pytestmark = pytest.mark.fast
@@ -838,61 +837,6 @@ def test_prometheus_text_exposition_format():
     assert "photon_sink_write_s_count 100" in lines
     assert 'photon_monitor_progress_total{stage="score"} 10.0' in lines
     m.close()
-
-
-# ---------------------------------------------------------------------------
-# history --known-bad waiver
-# ---------------------------------------------------------------------------
-
-
-def test_parse_known_bad_requires_reason():
-    assert parse_known_bad(["r05.json=rc-124 budget timeout"]) == {
-        "r05.json": "rc-124 budget timeout"}
-    for bad in ("r05.json", "r05.json=", "=why", "r05.json=  "):
-        with pytest.raises(ValueError, match="reason"):
-            parse_known_bad([bad])
-
-
-def test_history_known_bad_waives_repo_r05(tmp_path, capsys):
-    """THE satellite acceptance: a BENCH_r01..r05 trajectory rc-1s on
-    r05's rc-124 — waived with a reason, the gate passes and the
-    markdown echoes the acknowledgment."""
-    from tests.test_telemetry import _write_five_round_trajectory
-
-    rounds = _write_five_round_trajectory(tmp_path)
-    rc = telemetry_main(["history", *rounds])
-    capsys.readouterr()
-    assert rc == 1                       # unwaived: r05 fails the gate
-
-    rc = telemetry_main([
-        "history", *rounds, "--known-bad",
-        "BENCH_r05.json=rc-124 budget timeout, see PERF.md round 10"])
-    out = capsys.readouterr().out
-    tail = json.loads(out.strip().splitlines()[-1])
-    assert rc == 0 and tail["ok"] is True
-    assert tail["failed_rounds"] == []
-    assert tail["waived"][0]["round"] == "BENCH_r05.json"
-    assert "budget timeout" in tail["waived"][0]["reason"]
-    assert "WAIVED" in out and "budget timeout" in out
-
-
-def test_history_known_bad_unknown_round_is_surfaced(tmp_path, capsys):
-    """A waiver matching no loaded round (typo) is named in the output
-    instead of silently doing nothing."""
-    hist = tmp_path / "hist"
-    hist.mkdir()
-    with open(str(hist / "r01.json"), "w") as f:
-        json.dump({"schema": 1, "kind": "bench_record", "rc": 0,
-                   "argv": [], "record": {"stream": {
-                       "spilled": {"examples_per_sec": 1000.0},
-                       "pass_time_ratio": 1.0}}}, f)
-    rc = telemetry_main(["history", str(hist),
-                         "--known-bad", "r99.json=typo"])
-    out = capsys.readouterr().out
-    tail = json.loads(out.strip().splitlines()[-1])
-    assert rc == 0
-    assert tail["unknown_waivers"] == ["r99.json"]
-    assert "UNKNOWN WAIVER" in out
 
 
 # ---------------------------------------------------------------------------

@@ -32,6 +32,16 @@ def _ell(rng, n=3000, d=1200, k=6):
     return cols, vals, d
 
 
+def _named(spans, name):
+    return [e for e in spans if e["name"] == name]
+
+
+def _cache_hit(spans):
+    """``cache_hit`` of the one ``grr_plan_build`` among ``spans``."""
+    [build] = _named(spans, "grr_plan_build")
+    return build["args"]["cache_hit"]
+
+
 def _contract_both(pair, rng, n, d):
     w = rng.normal(0, 1, d).astype(np.float32)
     r = rng.normal(0, 1, n).astype(np.float32)
@@ -39,18 +49,22 @@ def _contract_both(pair, rng, n, d):
 
 
 @pytest.mark.fast
-def test_cache_round_trip_contraction_equality(rng, tmp_path):
+def test_cache_round_trip_contraction_equality(rng, tmp_path, spans_of):
     """Second build of identical inputs is a hit, and the cached plan's
     contractions equal the fresh build's in both directions."""
     cols, vals, d = _ell(rng)
-    fresh = build_grr_pair(cols, vals, d, cache_dir=str(tmp_path))
-    assert grr_mod.last_build_phases["cache_hit"] == 0.0
+    td = str(tmp_path)
+    fresh, spans = spans_of(build_grr_pair, cols, vals, d, cache_dir=td)
+    assert _cache_hit(spans) == 0
+    assert len(_named(spans, "plan_cache_save")) == 1
     dot_f, tdot_f = _contract_both(fresh, np.random.default_rng(5),
                                    cols.shape[0], d)
 
-    cached = build_grr_pair(cols, vals, d, cache_dir=str(tmp_path))
-    assert grr_mod.last_build_phases["cache_hit"] == 1.0
-    assert "cache_load_s" in grr_mod.last_build_phases
+    cached, spans = spans_of(build_grr_pair, cols, vals, d, cache_dir=td)
+    assert _cache_hit(spans) == 1
+    [load] = _named(spans, "plan_cache_load")
+    assert load["args"]["bytes"] > 0
+    assert not _named(spans, "plan_cache_save")
     dot_c, tdot_c = _contract_both(cached, np.random.default_rng(5),
                                    cols.shape[0], d)
     np.testing.assert_allclose(dot_c, dot_f, rtol=1e-5, atol=1e-5)
@@ -58,7 +72,7 @@ def test_cache_round_trip_contraction_equality(rng, tmp_path):
 
 
 @pytest.mark.fast
-def test_cache_invalidation_on_data_config_version(rng, tmp_path):
+def test_cache_invalidation_on_data_config_version(rng, tmp_path, spans_of):
     """Any of (data bytes, plan options, planner version) changing is a
     clean miss — never a stale hit."""
     td = str(tmp_path)
@@ -68,43 +82,29 @@ def test_cache_invalidation_on_data_config_version(rng, tmp_path):
     # Data change: one value flips -> different fingerprint.
     vals2 = vals.copy()
     vals2[0, 0] += 1.0
-    build_grr_pair(cols, vals2, d, cache_dir=td)
-    assert grr_mod.last_build_phases["cache_hit"] == 0.0
+    _, spans = spans_of(build_grr_pair, cols, vals2, d, cache_dir=td)
+    assert _cache_hit(spans) == 0
 
     # Config change: explicit cap -> different config key.
-    build_grr_pair(cols, vals, d, cache_dir=td, cap=8)
-    assert grr_mod.last_build_phases["cache_hit"] == 0.0
+    _, spans = spans_of(build_grr_pair, cols, vals, d, cache_dir=td, cap=8)
+    assert _cache_hit(spans) == 0
 
     # Version change: a planner bump orphans every old entry.
     old = grr_mod.PLANNER_VERSION
     grr_mod.PLANNER_VERSION = old + 1
     try:
-        build_grr_pair(cols, vals, d, cache_dir=td)
-        assert grr_mod.last_build_phases["cache_hit"] == 0.0
+        _, spans = spans_of(build_grr_pair, cols, vals, d, cache_dir=td)
+        assert _cache_hit(spans) == 0
     finally:
         grr_mod.PLANNER_VERSION = old
 
     # Unchanged inputs still hit.
-    build_grr_pair(cols, vals, d, cache_dir=td)
-    assert grr_mod.last_build_phases["cache_hit"] == 1.0
+    _, spans = spans_of(build_grr_pair, cols, vals, d, cache_dir=td)
+    assert _cache_hit(spans) == 1
 
 
 @pytest.mark.fast
-def test_cache_rebuild_flag_skips_read_but_saves(rng, tmp_path):
-    """cache_rebuild=True never reads (the bench's honest-cold mode)
-    but still warms the cache for the next reader."""
-    td = str(tmp_path)
-    cols, vals, d = _ell(rng, n=1500)
-    build_grr_pair(cols, vals, d, cache_dir=td)
-    build_grr_pair(cols, vals, d, cache_dir=td, cache_rebuild=True)
-    assert grr_mod.last_build_phases["cache_hit"] == 0.0
-    assert "cache_save_s" in grr_mod.last_build_phases
-    build_grr_pair(cols, vals, d, cache_dir=td)
-    assert grr_mod.last_build_phases["cache_hit"] == 1.0
-
-
-@pytest.mark.fast
-def test_corrupt_cache_entry_falls_back_to_rebuild(rng, tmp_path):
+def test_corrupt_cache_entry_falls_back_to_rebuild(rng, tmp_path, spans_of):
     """Truncated or garbage entries are rebuilt (and the rebuild
     overwrites them), never crash."""
     td = str(tmp_path)
@@ -121,8 +121,8 @@ def test_corrupt_cache_entry_falls_back_to_rebuild(rng, tmp_path):
     with open(path, "wb") as f:
         f.write(blob[: len(blob) // 2])
     assert plan_cache.load_plan(path) is None
-    pair = build_grr_pair(cols, vals, d, cache_dir=td)
-    assert grr_mod.last_build_phases["cache_hit"] == 0.0
+    pair, spans = spans_of(build_grr_pair, cols, vals, d, cache_dir=td)
+    assert _cache_hit(spans) == 0
     assert pair.row_dir.n_segments == cols.shape[0]
 
     # Pure garbage (not even a zip).
@@ -131,8 +131,8 @@ def test_corrupt_cache_entry_falls_back_to_rebuild(rng, tmp_path):
     assert plan_cache.load_plan(path) is None
     build_grr_pair(cols, vals, d, cache_dir=td)
     # The rebuild re-saved a good entry; next read hits.
-    build_grr_pair(cols, vals, d, cache_dir=td)
-    assert grr_mod.last_build_phases["cache_hit"] == 1.0
+    _, spans = spans_of(build_grr_pair, cols, vals, d, cache_dir=td)
+    assert _cache_hit(spans) == 1
 
 
 @pytest.mark.fast

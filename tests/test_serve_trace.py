@@ -3,7 +3,7 @@ contract, the tail-sampled ring buffer under concurrency, stage
 attribution through the real serving path, Chrome flow-event export,
 and the ``serve-report`` cross-process join.
 
-The acceptance checks live here and in the bench: every request above
+The acceptance checks live here: every request above
 the tail threshold is retained (tail sampling is COMPLETE, not
 probabilistic), the ring buffer stays bounded under sustained
 concurrent load, the exported flow events are valid Chrome JSON whose

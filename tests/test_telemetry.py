@@ -1085,136 +1085,3 @@ def test_streamed_re_emits_convergence_dynamics(tmp_path):
     # Device cost of the per-bucket chunk-train program was captured.
     programs = summary.get("device", {}).get("programs", {})
     assert any(k.startswith("re_chunk_train.b") for k in programs)
-
-
-# ---------------------------------------------------------------------------
-# ISSUE 8: bench-history trajectory gating
-# ---------------------------------------------------------------------------
-
-
-def _write_round(path, record, rc=0, wrapper=False):
-    with open(path, "w") as f:
-        if wrapper:
-            json.dump({"n": 1, "cmd": "bench", "rc": rc,
-                       "tail": "", "parsed": record}, f)
-        else:
-            json.dump({"schema": 1, "kind": "bench_record",
-                       "argv": ["--section", "stream"], "rc": rc,
-                       "record": record}, f)
-
-
-def _write_five_round_trajectory(hist) -> list[str]:
-    """Five synthetic driver captures in the wrapper shape, with the
-    trajectory the gate was built around: a first round with nothing
-    parsed, three clean rounds of rising GRR throughput, and a last
-    round cut at its time limit (rc 124, ``parsed: null``)."""
-    rounds = [
-        (None, 0),
-        ({"value": 2.0e6, "step_ms": 440.0}, 0),
-        ({"value": 1.5e8, "step_ms_grr": 6.8, "etl_grr_s": 52.0}, 0),
-        ({"value": 2.0e8, "step_ms_grr": 4.8, "etl_grr_s": 46.0}, 0),
-        (None, 124),
-    ]
-    paths = []
-    for i, (record, rc) in enumerate(rounds, start=1):
-        paths.append(str(hist / f"BENCH_r0{i}.json"))
-        _write_round(paths[-1], record, rc=rc, wrapper=True)
-    return paths
-
-
-def _stream_record(rows_per_sec, ratio=1.0):
-    return {"stream": {"spilled": {"examples_per_sec": rows_per_sec},
-                       "pass_time_ratio": ratio}}
-
-
-def test_history_clean_then_regressed(tmp_path, capsys):
-    from photon_ml_tpu.telemetry.__main__ import main as telemetry_main
-
-    hist = tmp_path / "hist"
-    hist.mkdir()
-    _write_round(str(hist / "r01.json"), _stream_record(1000.0))
-    _write_round(str(hist / "r02.json"), _stream_record(1040.0))
-    rc = telemetry_main(["history", str(hist)])
-    out = capsys.readouterr().out
-    tail = json.loads(out.strip().splitlines()[-1])
-    assert rc == 0 and tail["ok"] is True
-    assert tail["regressions"] == [] and tail["failed_rounds"] == []
-    traj = tail["trajectory"]["stream:stream.spilled.examples_per_sec"]
-    assert traj["values"] == [1000.0, 1040.0]
-
-    # Injected 20% rows/s regression in a third round → rc 1 naming
-    # the section/metric (the acceptance bar).
-    _write_round(str(hist / "r03.json"), _stream_record(816.0))
-    rc = telemetry_main(["history", str(hist)])
-    out = capsys.readouterr().out
-    tail = json.loads(out.strip().splitlines()[-1])
-    assert rc == 1 and tail["ok"] is False
-    regs = tail["regressions"]
-    assert len(regs) == 1
-    assert regs[0]["round"] == "r03.json"
-    assert regs[0]["metric"] == "stream:stream.spilled.examples_per_sec"
-    assert "REGRESSION" in out
-
-
-def test_history_flags_nonzero_rc_round(tmp_path, capsys):
-    """A round whose wrapper recorded a nonzero rc (the repo's own
-    BENCH_r05 shape: rc=124, parsed null) fails the gate by itself."""
-    from photon_ml_tpu.telemetry.__main__ import main as telemetry_main
-
-    hist = tmp_path / "hist"
-    hist.mkdir()
-    _write_round(str(hist / "r01.json"), _stream_record(1000.0),
-                 wrapper=True)
-    _write_round(str(hist / "r02.json"), None, rc=124, wrapper=True)
-    # A torn wrapper that recorded "rc": null must flag, not crash.
-    _write_round(str(hist / "r03.json"), None, rc=None, wrapper=True)
-    rc = telemetry_main(["history", str(hist)])
-    out = capsys.readouterr().out
-    tail = json.loads(out.strip().splitlines()[-1])
-    assert rc == 1 and tail["ok"] is False
-    assert {(f["round"], f["rc"]) for f in tail["failed_rounds"]} == {
-        ("r02.json", 124), ("r03.json", None)}
-    assert "FAILED ROUND" in out
-
-
-def test_history_over_repo_bench_records(tmp_path, capsys):
-    """THE acceptance check on a driver-capture trajectory (wrapper
-    shape): rounds r01..r04 are clean (rc 0); adding one synthetic
-    regressed round — and the rc-124 r05 — exits rc 1 naming the
-    regressed section/metric."""
-    from photon_ml_tpu.telemetry.__main__ import main as telemetry_main
-
-    hist = tmp_path / "hist"
-    hist.mkdir()
-    rounds = _write_five_round_trajectory(hist)
-
-    rc = telemetry_main(["history", *rounds[:4]])
-    tail = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0 and tail["ok"] is True
-
-    # All five + a synthetic regressed round: the GRR throughput
-    # collapses 40% → rc 1, regression named, r05's rc=124 flagged too.
-    _write_round(str(hist / "BENCH_r99.json"),
-                 {"value": 2.0e8 * 0.6, "step_ms_grr": 4.8})
-    rc = telemetry_main(["history", str(hist)])
-    out = capsys.readouterr().out
-    tail = json.loads(out.strip().splitlines()[-1])
-    assert rc == 1 and tail["ok"] is False
-    assert any(r["metric"] == "overall:value"
-               and r["round"] == "BENCH_r99.json"
-               for r in tail["regressions"])
-    assert any(fr["rc"] == 124 for fr in tail["failed_rounds"])
-
-
-def test_history_tolerates_garbage_files(tmp_path, capsys):
-    from photon_ml_tpu.telemetry.__main__ import main as telemetry_main
-
-    hist = tmp_path / "hist"
-    hist.mkdir()
-    (hist / "bad.json").write_text("{not json")
-    _write_round(str(hist / "ok.json"), _stream_record(1000.0))
-    rc = telemetry_main(["history", str(hist)])
-    tail = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 1                       # unreadable round = failed round
-    assert tail["failed_rounds"][0]["round"] == "bad.json"
-    assert "error" in tail["failed_rounds"][0]
