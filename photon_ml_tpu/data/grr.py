@@ -51,6 +51,7 @@ the XLA scatter, a ~100× speedup of the framework's hot loop.
 from __future__ import annotations
 
 import logging
+import math
 import time
 import typing
 
@@ -254,8 +255,9 @@ def _next_pow2(x: int) -> int:
 
 
 def _group_ranks(keys: np.ndarray) -> np.ndarray:
-    """Rank of each entry within its key group (0-based; assignment of
-    ranks within a group is arbitrary — callers only need distinctness).
+    """Rank of each entry within its key group, 0-based, in the order
+    the entries come (the sort below is stable): the rank the C++
+    builder's scan gives, which is why the two write the same plan.
 
     Build-time hot path at 10⁸ entries, so two scale fast paths:
     already-sorted keys (the row direction's (seg, window) keys arrive
@@ -497,22 +499,37 @@ class GrrRangeSplit:
 DENSE_GRID_MIN_FILL = 0.7
 
 
-def _maybe_dense_grid(G1, G2, G3, VALS, gw_of_st, ow_of_st, n_gw, n_ow,
-                      force=None):
-    """Reorder a built plan's tiles into the gw-major full (gw × ow_p)
-    grid (see ``GrrDirection.dense_grid``) when the block grid is dense
-    enough that the dummy tiles cost less than the revisiting kernel's
-    per-tile overhead.  Returns (G1, G2, G3, VALS, gwg) or None (keep
-    the legacy order)."""
+def _dense_grid_shape(n_gw: int, n_ow: int) -> tuple[int, int]:
+    """(padded ow count, supertiles) of the full (gw × ow_p) grid."""
     from photon_ml_tpu.ops.grr_kernel import DENSE_B
 
     n_ow_p = -(-n_ow // DENSE_B) * DENSE_B
-    n_st_p = n_gw * n_ow_p
-    n_st = VALS.shape[0]
+    return n_ow_p, n_gw * n_ow_p
+
+
+def _laid_out_supertiles(n_st: int, n_gw: int, n_ow: int,
+                         force=None) -> tuple[bool, int]:
+    """(dense grid?, supertiles as laid out) of a plan that built
+    ``n_st``: the whole grid when it is dense enough that the dummy
+    tiles cost less than the revisiting kernel's per-tile overhead (or
+    ``force`` says so), else the built tiles."""
+    n_st_p = _dense_grid_shape(n_gw, n_ow)[1]
     dense = (force if force is not None
              else n_st >= DENSE_GRID_MIN_FILL * n_st_p)
-    if not dense:
+    return bool(dense), n_st_p if dense else n_st
+
+
+def _maybe_dense_grid(G1, G2, G3, VALS, gw_of_st, ow_of_st, n_gw, n_ow,
+                      force=None):
+    """Reorder a built plan's tiles into the gw-major full (gw × ow_p)
+    grid (see ``GrrDirection.dense_grid``) where
+    ``_laid_out_supertiles`` says so.  Returns (G1, G2, G3, VALS, gwg)
+    or None (keep the legacy order)."""
+    from photon_ml_tpu.ops.grr_kernel import DENSE_B
+
+    if not _laid_out_supertiles(VALS.shape[0], n_gw, n_ow, force)[0]:
         return None
+    n_ow_p, n_st_p = _dense_grid_shape(n_gw, n_ow)
     pos = (np.asarray(gw_of_st, np.int64) * n_ow_p
            + np.asarray(ow_of_st, np.int64))
 
@@ -526,11 +543,30 @@ def _maybe_dense_grid(G1, G2, G3, VALS, gw_of_st, ow_of_st, n_gw, n_ow,
             scatter(np.asarray(G3)), scatter(np.asarray(VALS)), gwg)
 
 
+def _economy_max_supertiles(entries: int, n_gw: int,
+                            n_ow: int) -> "int | None":
+    """The most supertiles an overflow level over this block grid may
+    build and still lay out at most ``ECONOMY_SLOTS_PER_ENTRY`` slots
+    per entry (``_spill_overflow``'s bound, on the count
+    ``_laid_out_supertiles`` gives); None when every plan does.  The
+    laid-out count never falls as the built one rises, so the plans
+    that pass are those up to one count, and a builder can stop as soon
+    as it has counted its supertiles."""
+    limit = ECONOMY_SLOTS_PER_ENTRY * entries // SLOTS
+    n_st_p = _dense_grid_shape(n_gw, n_ow)[1]
+    if n_st_p <= limit:          # n_st <= n_gw * n_ow <= n_st_p
+        return None
+    # The whole grid is too many: a plan passes if it stays in legacy
+    # order and under the limit there.
+    return min(limit, math.ceil(DENSE_GRID_MIN_FILL * n_st_p) - 1)
+
+
 def _spill_overflow(s_idx, s_seg, s_val, m_real, table_len, n_segments,
-                    validate, threshold, device=True, depth=4):
+                    validate, threshold, device=True, depth=4, level=2):
     """Compile the COO spill into an overflow plan when it is big
     enough to matter; the chain recurses up to ``depth`` levels (the
-    final level's residual stays COO).
+    final level's residual stays COO); ``level`` is this one's number
+    in its chain, for the ``grr_overflow_level`` stage.
     Operates on HOST arrays, before any device placement — pulling
     device arrays back would serialize the whole plan transfer into the
     build timeline.
@@ -541,7 +577,10 @@ def _spill_overflow(s_idx, s_seg, s_val, m_real, table_len, n_segments,
     kept while its streamed slots stay under ~96 per absorbed entry
     (~1.2 KB ≈ 15 ns of HBM time at the measured kernel bandwidth, vs
     ~26 ns measured for the XLA scatter it replaces); beyond that the
-    tail is too scattered to block and the COO fallback stays.
+    tail is too scattered to block and the COO fallback stays.  The
+    builder makes that test as soon as it has counted the level's
+    supertiles (``_economy_max_supertiles``): a level that fails it is
+    never filled, routed or laid out.
 
     Returns (overflow, s_idx, s_seg, s_val) — spill arrays emptied when
     absorbed."""
@@ -551,8 +590,7 @@ def _spill_overflow(s_idx, s_seg, s_val, m_real, table_len, n_segments,
     # carries at least ceil(n_segments/segwin) dummy supertiles, and the
     # widest segwin (smallest cap=4) bounds that floor from below.  A
     # tail that can't clear the 96-slots-per-entry bar even at the floor
-    # would be built (multi-GB arrays, full routing) only to be thrown
-    # away.
+    # needs no pass over its entries to be refused.
     st_floor = -(-n_segments // (WIN // MIN_CAP))
     if st_floor * SLOTS > ECONOMY_SLOTS_PER_ENTRY * m_real:
         return None, s_idx, s_seg, s_val
@@ -564,17 +602,21 @@ def _spill_overflow(s_idx, s_seg, s_val, m_real, table_len, n_segments,
     # shape: 16.3M -> 5.5M at one level), so the default 4 levels leave
     # only a trivial COO tail.  Each level passes the same pre-build
     # and 96-slots-per-entry economy checks.
-    lvl2 = build_grr_direction(
-        idx=np.asarray(s_idx[:m_real], np.int64),
-        seg=np.asarray(s_seg[:m_real], np.int64),
-        val=np.asarray(s_val[:m_real]),
-        table_len=table_len, n_segments=n_segments,
-        cap=None, validate=validate,
-        overflow_threshold=(threshold if depth > 1 else None),
-        device=device, overflow_depth=depth - 1,
-    )
-    if lvl2.n_supertiles * SLOTS > ECONOMY_SLOTS_PER_ENTRY * m_real:
-        return None, s_idx, s_seg, s_val
+    with telemetry.stage("grr_overflow_level", depth=level,
+                         entries=m_real) as stage:
+        plan = _plan_coo(s_idx[:m_real], s_seg[:m_real], s_val[:m_real],
+                         table_len, n_segments, cap=None,
+                         economy_entries=m_real)
+        kept = "vals" in plan
+        stage.set(supertiles=_laid_out_supertiles(
+            plan["n_st"], plan["n_gw"], plan["n_ow"])[1],
+            native=int(plan["native"]), kept=int(kept))
+        if not kept:
+            return None, s_idx, s_seg, s_val
+        lvl2 = _finish_direction(
+            plan, table_len, n_segments, validate,
+            threshold if depth > 1 else None, device=device,
+            overflow_depth=depth - 1, level=level)
     z = np.zeros(0, np.int32)
     return lvl2, z, z, np.zeros(0, np.float32)
 
@@ -586,8 +628,10 @@ def _native_direction(cols, vals_masked, direction, table_len, n_segments,
                       idx_range=None) -> "GrrDirection | None":
     """One direction's plan via the C++ builder (``pml_grr_plan``), or
     None when the native library is unavailable / declines the shape.
-    Rank assignment differs from the numpy path (scan order vs sort
-    order) — both are valid plans; contractions agree (tested).
+    At one cap these are the bytes ``build_grr_direction`` compiles the
+    same entries in row-major order to (both rank in scan order;
+    tested); an absent cap the C++ resolves from the exact mean
+    occupancy where ``_sampled_cap`` takes a sample.
 
     ``device=False`` keeps the plan's leaves as host numpy arrays —
     the mesh-sharded build pads shard plans to a common shape on the
@@ -596,33 +640,69 @@ def _native_direction(cols, vals_masked, direction, table_len, n_segments,
     sub-plan: the C++ builder skips out-of-range entries in-stream (no
     extra numpy masking passes) and the returned plan contracts the
     table SLICE [lo, hi)."""
-    from photon_ml_tpu.native import grr_plan_native, grr_routes_native
+    from photon_ml_tpu.native import grr_plan_native
 
-    conv = jnp.asarray if device else np.asarray
     plan = grr_plan_native(cols, vals_masked, direction, table_len,
                            n_segments, cap, idx_range=idx_range)
     if plan is None:
         return None
     if idx_range is not None:
         table_len = int(idx_range[1] - idx_range[0])
-    routes = grr_routes_native(plan["dst"], plan["hi"])
-    if routes is None:
-        return None
-    G1, G2, G3 = routes
-    if validate and plan["vals"].shape[0]:
+    return _finish_direction(plan, table_len, n_segments, validate,
+                             overflow_threshold, device=device,
+                             dense_grid=dense_grid)
+
+
+def _finish_direction(plan, table_len, n_segments, validate,
+                      overflow_threshold, device=True, dense_grid=None,
+                      overflow_depth=4, level=1) -> GrrDirection:
+    """A builder's ``plan`` (``native._read_grr_plan``'s dict, from the
+    ELL arrays, from COO or from the numpy body) made a
+    ``GrrDirection``: routes coloured, the spill compiled into the
+    overflow chain, tiles laid out, leaves placed."""
+    from photon_ml_tpu.native import grr_routes_native
+    from photon_ml_tpu.ops.crossbar import route_tile
+
+    dst, HI, VALS = plan["dst"], plan["hi"], plan["vals"]
+    n_st = VALS.shape[0]
+    # Route every supertile; fuse route stage 1 into the gather index.
+    # Native batched path (C++ pml_grr_routes, on every core from two
+    # blocks of supertiles on) when available; the Python loop below is
+    # the fallback (per-tile colorings may differ — both are proper,
+    # sums agree).
+    routes = grr_routes_native(dst, HI)
+    if routes is not None:
+        G1, G2, G3 = routes
+    else:
+        if n_st > 64:
+            logger.warning(
+                "GRR: routing %d supertiles with the pure-Python colorer "
+                "(native library unavailable) — this is orders of "
+                "magnitude slower than the C++ path", n_st,
+            )
+        G1 = np.empty((n_st, TILE, TILE), np.int8)
+        G2 = np.empty((n_st, TILE, TILE), np.int8)
+        G3 = np.empty((n_st, TILE, TILE), np.int8)
+        for t in range(n_st):
+            rg1, rg2, rg3 = route_tile(dst[t])
+            G1[t] = np.take_along_axis(HI[t], rg1, axis=1).astype(np.int8)
+            G2[t] = rg2.astype(np.int8)
+            G3[t] = rg3.astype(np.int8)
+    if validate and n_st:
         _validate_routes(G2, G3)
     m = int(np.count_nonzero(plan["spill_val"]))
-    total = m + int(np.count_nonzero(plan["vals"]))
+    total = m + int(np.count_nonzero(VALS))
     overflow, s_idx, s_seg, s_val = _spill_overflow(
         plan["spill_idx"], plan["spill_seg"], plan["spill_val"], m,
         table_len, n_segments, validate, overflow_threshold, device=device,
+        depth=overflow_depth, level=level + 1,
     )
     # Warn only about spill that STAYS on the XLA scatter path — spill
     # absorbed into the overflow plan runs at kernel speed and needs no
     # operator tuning.  Rate-limited: one summary per plan build.
     m_coo = int(np.count_nonzero(s_val))
     _spill_warnings.note(m_coo, total)
-    VALS, gw_arr = plan["vals"], plan["gw_of_st"]
+    gw_arr = plan["gw_of_st"]
     ow_arr, first_arr = plan["ow_of_st"], plan["first_of_ow"]
     dg = _maybe_dense_grid(G1, G2, G3, VALS, gw_arr, ow_arr,
                            plan["n_gw"], plan["n_ow"], force=dense_grid)
@@ -630,6 +710,7 @@ def _native_direction(cols, vals_masked, direction, table_len, n_segments,
     if is_dense:
         G1, G2, G3, VALS, gw_arr = dg
         ow_arr = first_arr = np.zeros(0, np.int32)
+    conv = jnp.asarray if device else np.asarray
     return GrrDirection(
         g1=conv(G1), g2=conv(G2), g3=conv(G3),
         vals=conv(VALS),
@@ -665,10 +746,52 @@ def build_grr_direction(
     distribution; overflow spills to the COO fallback.
     ``device=False`` keeps leaves as host numpy (see _native_direction).
     """
-    from photon_ml_tpu.ops.crossbar import route_tile
+    plan = _plan_coo(idx, seg, val, table_len, n_segments, cap)
+    return _finish_direction(plan, table_len, n_segments, validate,
+                             overflow_threshold, device=device,
+                             dense_grid=dense_grid,
+                             overflow_depth=overflow_depth)
 
-    idx = np.asarray(idx, np.int64)
-    seg = np.asarray(seg, np.int64)
+
+def _sampled_cap(idx, seg, n_gw: int, n_segments: int) -> int:
+    """The capacity heuristic: cover ~1.5× the mean nonempty (seg,
+    window) occupancy; power of two in [4, 64].  The mean is estimated
+    from a random sample of whole *segments* (sampling entries would
+    undercount every group and bias cap low); exact unique over 10⁷+
+    keys would cost a full sort."""
+    if not idx.size:
+        return 4
+    if n_segments > 8192:
+        segs = np.random.default_rng(0).choice(
+            n_segments, 4096, replace=False)
+        # Membership via a boolean LUT — one O(nnz) gather,
+        # vs. a binary search per entry.
+        lut = np.zeros(n_segments, bool)
+        lut[segs] = True
+        sampled = lut[seg]
+        idx, seg = idx[sampled], seg[sampled]
+    _, counts = np.unique(seg.astype(np.int64) * n_gw + idx // WIN,
+                          return_counts=True)
+    mean = counts.mean() if counts.size else 1.0
+    return int(np.clip(_next_pow2(int(np.ceil(1.5 * mean))), 4, 64))
+
+
+def _plan_coo(idx, seg, val, table_len, n_segments, cap,
+              economy_entries=None) -> dict:
+    """One direction's plan from COO, before its routes
+    (``native._read_grr_plan``'s dict, and ``native``: which builder
+    made it): entries validated and ``cap`` resolved here, then the C++
+    passes (``pml_grr_plan_coo``) where there is a library, else the
+    numpy body.  With ``economy_entries`` (an overflow level's) the plan
+    is held to ``_economy_max_supertiles`` and past it comes back as
+    its sizes alone."""
+    from photon_ml_tpu.native import grr_plan_native_coo
+
+    # An integer array keeps its width: a level's spill is int32, which
+    # is what the C++ takes, and at 10⁷ entries a copy to int64 and back
+    # is tenths of a second.
+    idx, seg = (a if a.dtype.kind in "iu" else a.astype(np.int64)
+                for a in (np.asarray(idx), np.asarray(seg)))
     val = np.asarray(val, np.float32)
     keep0 = val != 0
     if not bool(keep0.all()):  # skip three 10⁸-entry gathers when dense
@@ -677,42 +800,34 @@ def build_grr_direction(
         raise ValueError("idx out of range")
     if seg.size and (seg.min() < 0 or seg.max() >= n_segments):
         raise ValueError("seg out of range")
-
     n_gw = max(1, -(-table_len // WIN))
-    gw = idx // WIN
-
-    # Capacity heuristic: cover ~1.5× the mean nonempty (seg, window)
-    # occupancy; power of two in [4, 64].
-    group_key = seg * n_gw + gw
     if cap is None:
-        if idx.size:
-            # Mean nonempty-(seg, window) occupancy.  Estimated from a
-            # random sample of whole *segments* (sampling entries would
-            # undercount every group and bias cap low); exact unique
-            # over 10⁷+ keys would cost a full sort.
-            if n_segments > 8192:
-                segs = np.random.default_rng(0).choice(
-                    n_segments, 4096, replace=False)
-                # Membership via a boolean LUT — one O(nnz) gather,
-                # vs. a binary search per entry.
-                lut = np.zeros(n_segments, bool)
-                lut[segs] = True
-                samp = group_key[lut[seg]]
-            else:
-                samp = group_key
-            _, counts = np.unique(samp, return_counts=True)
-            mean = counts.mean() if counts.size else 1.0
-            cap = int(np.clip(_next_pow2(int(np.ceil(1.5 * mean))), 4, 64))
-        else:
-            cap = 4
+        cap = _sampled_cap(idx, seg, n_gw, n_segments)
     if cap not in (1, 2, 4, 8, 16, 32, 64, 128):
         raise ValueError(f"cap must be a power of two ≤ 128, got {cap}")
+    n_ow = max(1, -(-n_segments // (WIN // cap)))
+    max_st = (None if economy_entries is None else
+              _economy_max_supertiles(economy_entries, n_gw, n_ow))
+    plan = grr_plan_native_coo(idx, seg, val, table_len, n_segments, cap,
+                               max_supertiles=max_st)
+    native = plan is not None
+    if not native:
+        plan = _plan_coo_numpy(idx, seg, val, n_gw, n_ow, cap, max_st)
+    return dict(plan, native=native)
+
+
+def _plan_coo_numpy(idx, seg, val, n_gw, n_ow, cap, max_st=None) -> dict:
+    """``pml_grr_plan_coo`` in numpy: the builder of a machine without
+    a compiler, and the reference the C++ passes are held to, byte for
+    byte (tests/test_grr.py).  No fit with the native library runs it.
+    Entries validated, zeros dropped (``_plan_coo``)."""
+    idx, seg = idx.astype(np.int64), seg.astype(np.int64)
     segwin = WIN // cap
     group = TILE // cap
-    n_ow = max(1, -(-n_segments // segwin))
+    gw = idx // WIN
 
     # Slot rank within (seg, window); beyond cap → spill.
-    q = _group_ranks(group_key)
+    q = _group_ranks(seg * n_gw + gw)
     spill1 = q >= cap
 
     ow = seg // segwin
@@ -746,6 +861,9 @@ def build_grr_direction(
     missing_ow = np.setdiff1d(np.arange(n_ow, dtype=np.int64), present_ow)
     blocks = np.sort(np.r_[blocks, missing_ow * n_gw])
     n_st = blocks.size
+    sizes = {"cap": cap, "n_gw": n_gw, "n_ow": n_ow, "n_st": n_st}
+    if max_st is not None and n_st > max_st:
+        return sizes
     st_of = np.searchsorted(blocks, bkk)
 
     gw_of_st = (blocks % n_gw).astype(np.int32)
@@ -782,78 +900,19 @@ def build_grr_direction(
     free_s = np.flatnonzero(~occ_s)
     free_f = np.flatnonzero(~occ_f)
     dst[free_s] = (free_f % SLOTS).astype(np.int32)
-    dst = dst.reshape(n_st, TILE, TILE)
-    HI = HI.reshape(n_st, TILE, TILE)
-    VALS = VALS.reshape(n_st, TILE, TILE)
-
-    # Route every supertile; fuse route stage 1 into the gather index.
-    # Native batched path (C++ pml_grr_routes, on every core from two
-    # blocks of supertiles on) when available; the Python loop below is
-    # the byte-identical-in-semantics fallback (per-tile colorings may
-    # differ — both are proper, sums agree).
-    from photon_ml_tpu.native import grr_routes_native
-
-    native = grr_routes_native(dst, HI)
-    if native is not None:
-        G1, G2, G3 = native
-    else:
-        if n_st > 64:
-            logger.warning(
-                "GRR: routing %d supertiles with the pure-Python colorer "
-                "(native library unavailable) — this is orders of "
-                "magnitude slower than the C++ path", n_st,
-            )
-        G1 = np.empty((n_st, TILE, TILE), np.int8)
-        G2 = np.empty((n_st, TILE, TILE), np.int8)
-        G3 = np.empty((n_st, TILE, TILE), np.int8)
-        for t in range(n_st):
-            rg1, rg2, rg3 = route_tile(dst[t])
-            G1[t] = np.take_along_axis(HI[t], rg1, axis=1).astype(np.int8)
-            G2[t] = rg2.astype(np.int8)
-            G3[t] = rg3.astype(np.int8)
-
-    if validate and n_st:
-        _validate_routes(G2, G3)
 
     # Spill COO, padded to a multiple of 8.
     s_idx = idx[spilled].astype(np.int32)
     s_seg = seg[spilled].astype(np.int32)
     s_val = val[spilled]
-    m = s_idx.size
-    if m:
-        m_pad = -(-m // 8) * 8
-        s_idx = np.pad(s_idx, (0, m_pad - m))
-        s_seg = np.pad(s_seg, (0, m_pad - m))
-        s_val = np.pad(s_val, (0, m_pad - m))
-
-    overflow, s_idx, s_seg, s_val = _spill_overflow(
-        s_idx, s_seg, s_val, m, table_len, n_segments, validate,
-        overflow_threshold, device=device, depth=overflow_depth,
-    )
-    # Warn only about spill that stays on the XLA scatter path (spill
-    # absorbed by the overflow plan runs at kernel speed).
-    # Rate-limited: one summary per plan build.
-    m_coo = int(np.count_nonzero(s_val))
-    _spill_warnings.note(m_coo, max(idx.size, 1))
-    conv = jnp.asarray if device else np.asarray
-    dg = _maybe_dense_grid(G1, G2, G3, VALS, gw_of_st, ow_of_st,
-                           n_gw, n_ow, force=dense_grid)
-    is_dense = dg is not None
-    if is_dense:
-        G1, G2, G3, VALS, gw_of_st = dg
-        ow_of_st = first_of_ow = np.zeros(0, np.int32)
-    return GrrDirection(
-        g1=conv(G1), g2=conv(G2), g3=conv(G3),
-        vals=conv(VALS),
-        gw_of_st=conv(gw_of_st),
-        ow_of_st=conv(ow_of_st),
-        first_of_ow=conv(first_of_ow),
-        spill_idx=conv(s_idx), spill_seg=conv(s_seg),
-        spill_val=conv(s_val),
-        table_len=table_len, n_segments=n_segments, cap=cap,
-        n_gw=n_gw, n_ow=n_ow, overflow=overflow,
-        dense_grid=is_dense,
-    )
+    pad = (0, -s_idx.size % 8)
+    return dict(
+        sizes, hi=HI.reshape(n_st, TILE, TILE),
+        vals=VALS.reshape(n_st, TILE, TILE),
+        dst=dst.reshape(n_st, TILE, TILE), gw_of_st=gw_of_st,
+        ow_of_st=ow_of_st, first_of_ow=first_of_ow,
+        spill_idx=np.pad(s_idx, pad), spill_seg=np.pad(s_seg, pad),
+        spill_val=np.pad(s_val, pad))
 
 
 def _validate_routes(G2, G3) -> None:

@@ -147,6 +147,12 @@ def lib() -> "ctypes.CDLL | None":
             ctypes.c_int64, ctypes.c_int32, ctypes.c_int64,
             ctypes.c_int64,
         ]
+        dll.pml_grr_plan_coo.restype = ctypes.c_void_p
+        dll.pml_grr_plan_coo.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int32, ctypes.c_int64,
+        ]
         dll.pml_grr_plan_sizes.argtypes = [
             ctypes.c_void_p,
             ctypes.POINTER(ctypes.c_int64),
@@ -316,6 +322,78 @@ def colmajor_build_native(
     return tvals, trows, vcol
 
 
+def _int32_ids(ids, what: str) -> np.ndarray:
+    """``ids`` as contiguous int32.  The narrowing must not wrap
+    (advisor finding: a wrapped 64-bit id landing back inside the range
+    would pass the C++ range check and yield a silently wrong plan)."""
+    ids = np.asarray(ids)
+    if ids.dtype != np.int32 and ids.size and (
+        int(ids.max()) > np.iinfo(np.int32).max
+        or int(ids.min()) < np.iinfo(np.int32).min
+    ):
+        raise ValueError(f"{what} id exceeds int32 range in GRR plan build")
+    return np.ascontiguousarray(ids, np.int32)
+
+
+def _check_cap(cap: int) -> None:
+    """A cap that is no power of two makes distinct (rank, segment)
+    pairs collide on one final slot (same contract as the numpy path)."""
+    if cap not in (1, 2, 4, 8, 16, 32, 64, 128):
+        raise ValueError(f"cap must be a power of two ≤ 128, got {cap}")
+
+
+def _read_grr_plan(dll, handle):
+    """The plan behind ``handle`` as a dict, and the handle freed: the
+    plan arrays (hi/vals/dst per supertile, block maps, spill COO) with
+    ``cap``, ``n_gw``, ``n_ow`` and ``n_st``; the four sizes alone where
+    the plan was over its caller's bound; None where the C++ declined
+    (size overflow, a shape it does not take)."""
+    if not handle:
+        raise MemoryError("pml_grr_plan allocation failed")
+    try:
+        n_st = ctypes.c_int64()
+        n_spill = ctypes.c_int64()
+        cap_out = ctypes.c_int32()
+        n_gw = ctypes.c_int32()
+        n_ow = ctypes.c_int32()
+        error = ctypes.c_int32()
+        dll.pml_grr_plan_sizes(
+            handle, ctypes.byref(n_st), ctypes.byref(n_spill),
+            ctypes.byref(cap_out), ctypes.byref(n_gw), ctypes.byref(n_ow),
+            ctypes.byref(error),
+        )
+        if error.value == 1:
+            raise ValueError("idx or seg out of range in GRR plan build")
+        st = int(n_st.value)
+        sizes = {"cap": int(cap_out.value), "n_gw": int(n_gw.value),
+                 "n_ow": int(n_ow.value), "n_st": st}
+        if error.value == 4:
+            return sizes
+        if error.value:
+            return None  # the numpy path decides
+        m = int(n_spill.value)
+        hi = np.empty((st, 128, 128), np.int8)
+        v_out = np.empty((st, 128, 128), np.float32)
+        dst = np.empty((st, 128, 128), np.int32)
+        gw_of_st = np.empty(st, np.int32)
+        ow_of_st = np.empty(st, np.int32)
+        first_of_ow = np.empty(st, np.int32)
+        spill_idx = np.zeros(m, np.int32)
+        spill_seg = np.zeros(m, np.int32)
+        spill_val = np.zeros(m, np.float32)
+        dll.pml_grr_plan_fill(
+            handle, _ptr(hi), _ptr(v_out), _ptr(dst), _ptr(gw_of_st),
+            _ptr(ow_of_st), _ptr(first_of_ow), _ptr(spill_idx),
+            _ptr(spill_seg), _ptr(spill_val),
+        )
+    finally:
+        dll.pml_grr_plan_free(handle)
+    return dict(sizes, hi=hi, vals=v_out, dst=dst, gw_of_st=gw_of_st,
+                ow_of_st=ow_of_st, first_of_ow=first_of_ow,
+                spill_idx=spill_idx, spill_seg=spill_seg,
+                spill_val=spill_val)
+
+
 def grr_plan_native(
     cols: np.ndarray,
     vals: np.ndarray,
@@ -338,74 +416,53 @@ def grr_plan_native(
     returned plan's table axis is [0, hi-lo); ``lo`` must be
     window-aligned (a multiple of 16384).  Indices outside
     [0, table_len) are still an error.
-    Returns a dict with the plan arrays (hi/vals/dst per supertile,
-    block maps, spill COO) and the chosen cap; route coloring is the
-    caller's next step (``grr_routes_native``).
+    Returns ``_read_grr_plan``'s dict with the chosen cap; route
+    coloring is the caller's next step (``grr_routes_native``).
     """
     dll = lib()
     if dll is None:
         return None
-    # int32 narrowing must not wrap (advisor finding: a wrapped 64-bit
-    # column id landing back inside [0, table_len) would pass the C++
-    # range check and yield a silently wrong plan).
-    cols = np.asarray(cols)
-    if cols.dtype.itemsize > 4 and cols.size and (
-        int(cols.max()) > np.iinfo(np.int32).max
-        or int(cols.min()) < np.iinfo(np.int32).min
-    ):
-        raise ValueError("column id exceeds int32 range in GRR plan build")
-    cols = np.ascontiguousarray(cols, np.int32)
+    cols = _int32_ids(cols, "column")
     vals = np.ascontiguousarray(vals, np.float32)
     n, k = cols.shape
-    # cap=0 is rejected (same contract as the numpy path); only None
-    # means "choose from occupancy".
-    if cap is not None and cap not in (1, 2, 4, 8, 16, 32, 64, 128):
-        raise ValueError(f"cap must be a power of two ≤ 128, got {cap}")
+    # cap=0 is rejected; only None means "choose from occupancy".
+    if cap is not None:
+        _check_cap(cap)
     lo, hi = idx_range if idx_range is not None else (0, int(table_len))
-    handle = dll.pml_grr_plan(
+    return _read_grr_plan(dll, dll.pml_grr_plan(
         _ptr(cols), _ptr(vals), n, k, int(direction), int(table_len),
         int(n_segments), 0 if cap is None else int(cap), int(lo), int(hi),
-    )
-    if not handle:
-        raise MemoryError("pml_grr_plan allocation failed")
-    try:
-        n_st = ctypes.c_int64()
-        n_spill = ctypes.c_int64()
-        cap_out = ctypes.c_int32()
-        n_gw = ctypes.c_int32()
-        n_ow = ctypes.c_int32()
-        error = ctypes.c_int32()
-        dll.pml_grr_plan_sizes(
-            handle, ctypes.byref(n_st), ctypes.byref(n_spill),
-            ctypes.byref(cap_out), ctypes.byref(n_gw), ctypes.byref(n_ow),
-            ctypes.byref(error),
-        )
-        if error.value == 1:
-            raise ValueError("idx or seg out of range in GRR plan build")
-        if error.value:
-            return None  # size overflow: numpy path decides
-        st = int(n_st.value)
-        m = int(n_spill.value)
-        hi = np.empty((st, 128, 128), np.int8)
-        v_out = np.empty((st, 128, 128), np.float32)
-        dst = np.empty((st, 128, 128), np.int32)
-        gw_of_st = np.empty(st, np.int32)
-        ow_of_st = np.empty(st, np.int32)
-        first_of_ow = np.empty(st, np.int32)
-        spill_idx = np.zeros(m, np.int32)
-        spill_seg = np.zeros(m, np.int32)
-        spill_val = np.zeros(m, np.float32)
-        dll.pml_grr_plan_fill(
-            handle, _ptr(hi), _ptr(v_out), _ptr(dst), _ptr(gw_of_st),
-            _ptr(ow_of_st), _ptr(first_of_ow), _ptr(spill_idx),
-            _ptr(spill_seg), _ptr(spill_val),
-        )
-    finally:
-        dll.pml_grr_plan_free(handle)
-    return {
-        "hi": hi, "vals": v_out, "dst": dst, "gw_of_st": gw_of_st,
-        "ow_of_st": ow_of_st, "first_of_ow": first_of_ow,
-        "spill_idx": spill_idx, "spill_seg": spill_seg,
-        "spill_val": spill_val, "cap": int(cap_out.value),
-        "n_gw": int(n_gw.value), "n_ow": int(n_ow.value),
-    }
+    ))
+
+
+def grr_plan_native_coo(
+    idx: np.ndarray,
+    seg: np.ndarray,
+    val: np.ndarray,
+    table_len: int,
+    n_segments: int,
+    cap: int,
+    max_supertiles: "int | None" = None,
+):
+    """The same plan from COO entries in the order given (the spill of
+    the level above, a mid split), or None when the native library is
+    unavailable or declines.  ``cap`` is the caller's to resolve
+    (``data.grr``'s sampled heuristic): at one cap these are the bytes
+    the numpy body writes.  Entries with value 0 are dropped; an idx or
+    seg out of range raises.  Past ``max_supertiles`` (None: no bound)
+    the C++ stops once the supertiles are counted and the dict holds
+    the sizes alone."""
+    dll = lib()
+    if dll is None:
+        return None
+    _check_cap(cap)
+    idx = _int32_ids(idx, "idx")
+    seg = _int32_ids(seg, "seg")
+    val = np.ascontiguousarray(val, np.float32)
+    if not idx.shape == seg.shape == val.shape or idx.ndim != 1:
+        raise ValueError("idx, seg and val must be 1-D and of one length")
+    return _read_grr_plan(dll, dll.pml_grr_plan_coo(
+        _ptr(idx), _ptr(seg), _ptr(val), idx.size, int(table_len),
+        int(n_segments), int(cap),
+        -1 if max_supertiles is None else int(max_supertiles),
+    ))

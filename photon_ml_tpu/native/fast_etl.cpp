@@ -426,19 +426,25 @@ int32_t pml_grr_routes_blocks(const int32_t* dst, const int8_t* hi,
 // GRR plan construction (the layout half of the sparse engine)
 // ---------------------------------------------------------------------------
 //
-// Builds one direction's gather-route-reduce plan straight from the
-// row-ELL arrays: the same pipeline as photon_ml_tpu.data.grr
-// .build_grr_direction (group-capacity ranks, supertile blocking,
-// start/final slot placement, padding bijection, spill COO), but as a
-// handful of streaming passes over the entries with small cache-local
-// counter tables — no 10^8-element comparison sorts, no full-size
-// temporaries.  Rank assignment within a group follows entry scan
-// order; the Python path's sort-based ranks may differ, but rank choice
-// is explicitly arbitrary (both produce valid plans whose contractions
-// agree — tested in tests/test_grr.py).
+// Builds one direction's gather-route-reduce plan from its entries, in
+// the order the caller gives them: the cells of a row-ELL array
+// (pml_grr_plan) or a COO triple (pml_grr_plan_coo, the spill of the
+// level above or a mid split).  The same pipeline as
+// photon_ml_tpu.data.grr's numpy body (group-capacity ranks, supertile
+// blocking, start/final slot placement, padding bijection, spill COO),
+// but as a handful of streaming passes over the entries with small
+// cache-local counter tables — no 10^8-element comparison sorts, no
+// full-size temporaries.  A rank within a group follows entry scan
+// order, and so does the numpy body's (its argsort is stable): at one
+// cap the two builders write the same bytes, leaf for leaf (tested in
+// tests/test_grr.py).  They differ only in how an absent cap is
+// resolved: pml_grr_plan takes the exact mean occupancy, the numpy
+// heuristic a sample of segments; pml_grr_plan_coo is always given the
+// cap.
 //
-// Protocol: pml_grr_plan(...) -> handle; pml_grr_plan_sizes(handle,..);
-// pml_grr_plan_fill(handle, ...); pml_grr_plan_free(handle).
+// Protocol: pml_grr_plan(...) or pml_grr_plan_coo(...) -> handle;
+// pml_grr_plan_sizes(handle,..); pml_grr_plan_fill(handle, ...);
+// pml_grr_plan_free(handle).
 // Route coloring stays in pml_grr_routes (shared with the Python path).
 
 namespace {
@@ -448,7 +454,9 @@ constexpr int32_t GRR_TILE = 128;
 constexpr int64_t GRR_SLOTS = GRR_WIN;  // 128*128 slots per supertile
 
 struct GrrPlan {
-  int32_t error = 0;  // 1 = idx/seg out of range, 2 = size overflow
+  // 1 = idx/seg out of range, 2 = size overflow, 3 = bad argument,
+  // 4 = more supertiles than the caller's bound (sizes only, no arrays)
+  int32_t error = 0;
   int32_t cap = 0, n_gw = 0, n_ow = 0;
   int64_t n_st = 0, n_spill = 0;  // n_spill already padded to 8
   std::vector<int8_t> hi;
@@ -468,6 +476,34 @@ inline int32_t grr_next_pow2(int64_t x) {
   return p;
 }
 
+// The two entry sources of grr_plan_body: `size` cells, some of value
+// zero, cell e holding the entry (idx, seg) = at(e).
+struct EllEntries {  // cell (r, j) of [n, k] cols/vals: row r, column c
+  const int32_t* cols;
+  const float* vals;
+  int64_t k, size;
+  int32_t direction;  // 0: idx = column, seg = row; 1: the transpose
+  float val(int64_t e) const { return vals[e]; }
+  void at(int64_t e, int64_t* idx, int64_t* seg) const {
+    const int64_t r = e / k;
+    const int64_t c = cols[e];
+    *idx = direction ? r : c;
+    *seg = direction ? c : r;
+  }
+};
+
+struct CooEntries {
+  const int32_t* idx_of;
+  const int32_t* seg_of;
+  const float* vals;
+  int64_t size;
+  float val(int64_t e) const { return vals[e]; }
+  void at(int64_t e, int64_t* idx, int64_t* seg) const {
+    *idx = idx_of[e];
+    *seg = seg_of[e];
+  }
+};
+
 // Body behind an exception firewall: std::bad_alloc must not unwind
 // through the extern "C"/ctypes boundary (that would terminate the
 // process instead of letting the caller fall back to numpy).
@@ -480,10 +516,15 @@ inline int32_t grr_next_pow2(int64_t x) {
 // [0, table_len) are still a hard error — every entry belongs to
 // exactly one range of a full partition, so a genuinely out-of-range
 // id must not be silently dropped by all parts.
-void grr_plan_body(GrrPlan* plan, const int32_t* cols, const float* vals,
-                   int64_t n, int64_t k, int32_t direction,
-                   int64_t table_len, int64_t n_segments, int32_t cap_in,
-                   int64_t idx_lo, int64_t idx_hi) {
+//
+// max_st >= 0 bounds the supertiles the caller will pay for (the
+// overflow chain's economy test, data/grr.py _spill_overflow): a plan
+// with more stops once they are counted, before any array is made,
+// with error 4 and its sizes.
+template <class Entries>
+void grr_plan_body(GrrPlan* plan, const Entries& in, int64_t table_len,
+                   int64_t n_segments, int32_t cap_in, int64_t idx_lo,
+                   int64_t idx_hi, int64_t max_st) {
   // Same cap validation as the numpy path (data/grr.py): a non-power-
   // of-two cap makes distinct (q, b) pairs collide on one final slot.
   if (cap_in != 0 && cap_in != 1 && cap_in != 2 && cap_in != 4 &&
@@ -502,19 +543,16 @@ void grr_plan_body(GrrPlan* plan, const int32_t* cols, const float* vals,
   const int64_t range_len = idx_hi - idx_lo;
   const int64_t n_gw = (range_len + GRR_WIN - 1) / GRR_WIN;
   plan->n_gw = static_cast<int32_t>(n_gw);
-  const int64_t m_ell = n * k;
+  const int64_t m_ell = in.size;
 
   // Pass A: count nonzeros, validate ranges, check (seg, gw) sortedness.
   int64_t m_nz = 0;
   bool sorted = true;
   int64_t prev_key = -1;
   for (int64_t e = 0; e < m_ell; ++e) {
-    const float v = vals[e];
-    if (v == 0.0f) continue;
-    const int64_t r = e / k;
-    const int64_t c = cols[e];
-    int64_t idx = direction ? r : c;
-    const int64_t seg = direction ? c : r;
+    if (in.val(e) == 0.0f) continue;
+    int64_t idx, seg;
+    in.at(e, &idx, &seg);
     if (idx < 0 || idx >= table_len || seg < 0 || seg >= n_segments) {
       plan->error = 1;
       return;
@@ -536,13 +574,11 @@ void grr_plan_body(GrrPlan* plan, const int32_t* cols, const float* vals,
     if (sorted) {
       prev_key = -1;
       for (int64_t e = 0; e < m_ell; ++e) {
-        if (vals[e] == 0.0f) continue;
-        const int64_t r = e / k;
-        const int64_t c = cols[e];
-        const int64_t idx = direction ? r : c;
+        if (in.val(e) == 0.0f) continue;
+        int64_t idx, seg;
+        in.at(e, &idx, &seg);
         if (idx < idx_lo || idx >= idx_hi) continue;
-        const int64_t key = (direction ? c : r) * n_gw +
-                            (idx - idx_lo) / GRR_WIN;
+        const int64_t key = seg * n_gw + (idx - idx_lo) / GRR_WIN;
         if (key != prev_key) ++n_groups;
         prev_key = key;
       }
@@ -554,13 +590,11 @@ void grr_plan_body(GrrPlan* plan, const int32_t* cols, const float* vals,
       }
       std::vector<uint8_t> visited(static_cast<size_t>(n_keys), 0);
       for (int64_t e = 0; e < m_ell; ++e) {
-        if (vals[e] == 0.0f) continue;
-        const int64_t r = e / k;
-        const int64_t c = cols[e];
-        const int64_t idx = direction ? r : c;
+        if (in.val(e) == 0.0f) continue;
+        int64_t idx, seg;
+        in.at(e, &idx, &seg);
         if (idx < idx_lo || idx >= idx_hi) continue;
-        const int64_t key = (direction ? c : r) * n_gw +
-                            (idx - idx_lo) / GRR_WIN;
+        const int64_t key = seg * n_gw + (idx - idx_lo) / GRR_WIN;
         if (!visited[key]) { visited[key] = 1; ++n_groups; }
       }
     }
@@ -598,12 +632,10 @@ void grr_plan_body(GrrPlan* plan, const int32_t* cols, const float* vals,
   {
     int64_t run_key = -1, run_q = 0;
     for (int64_t e = 0; e < m_ell; ++e) {
-      const float v = vals[e];
+      const float v = in.val(e);
       if (v == 0.0f) continue;
-      const int64_t r = e / k;
-      const int64_t c = cols[e];
-      int64_t idx = direction ? r : c;
-      const int64_t seg = direction ? c : r;
+      int64_t idx, seg;
+      in.at(e, &idx, &seg);
       if (idx < idx_lo || idx >= idx_hi) continue;
       idx -= idx_lo;
       const int64_t gw = idx / GRR_WIN;
@@ -619,6 +651,12 @@ void grr_plan_body(GrrPlan* plan, const int32_t* cols, const float* vals,
       }
       if (q >= cap) continue;  // spill1
       const int64_t bk = (seg / segwin) * n_gw + gw;
+      // This pass keys the start-lane counter by the lane residue
+      // idx % 128 where pass C keys it by the sub-tile row
+      // (idx % WIN) / 128, so cnt_bk is not pass C's kept count.  It is
+      // only read as "the block has an entry", and a block's first
+      // cap-kept entry is counted under either key (a block holds at
+      // most segwin * cap = 16384 of them, so no counter wraps).
       uint16_t& r2 = r2cnt[bk * GRR_TILE + (idx % GRR_TILE)];
       if (r2 >= GRR_TILE) { ++r2; continue; }  // spill2 (sat. anyway)
       ++r2;
@@ -642,6 +680,10 @@ void grr_plan_body(GrrPlan* plan, const int32_t* cols, const float* vals,
       }
     }
     plan->n_st = n_st;
+    if (max_st >= 0 && n_st > max_st) {
+      plan->error = 4;
+      return;
+    }
     plan->hi.assign(static_cast<size_t>(n_st) * GRR_SLOTS, 0);
     plan->vals.assign(static_cast<size_t>(n_st) * GRR_SLOTS, 0.0f);
     plan->dst.assign(static_cast<size_t>(n_st) * GRR_SLOTS, 0);
@@ -681,12 +723,10 @@ void grr_plan_body(GrrPlan* plan, const int32_t* cols, const float* vals,
     if (!sorted) std::fill(qcnt.begin(), qcnt.end(), 0);
     int64_t run_key = -1, run_q = 0;
     for (int64_t e = 0; e < m_ell; ++e) {
-      const float v = vals[e];
+      const float v = in.val(e);
       if (v == 0.0f) continue;
-      const int64_t r = e / k;
-      const int64_t c = cols[e];
-      int64_t idx = direction ? r : c;
-      const int64_t seg = direction ? c : r;
+      int64_t idx, seg;
+      in.at(e, &idx, &seg);
       if (idx < idx_lo || idx >= idx_hi) continue;
       idx -= idx_lo;
       const int64_t gw = idx / GRR_WIN;
@@ -774,8 +814,31 @@ void* pml_grr_plan(const int32_t* cols, const float* vals, int64_t n,
   auto* plan = new (std::nothrow) GrrPlan();
   if (!plan) return nullptr;
   try {
-    grr_plan_body(plan, cols, vals, n, k, direction, table_len,
-                  n_segments, cap_in, idx_lo, idx_hi);
+    grr_plan_body(plan, EllEntries{cols, vals, k, n * k, direction},
+                  table_len, n_segments, cap_in, idx_lo, idx_hi, -1);
+  } catch (const std::bad_alloc&) {
+    plan->error = 2;  // caller falls back to the numpy path
+  }
+  return plan;
+}
+
+// The same plan from m COO entries (idx[e], seg[e], val[e]) in the
+// order given, over the whole table axis.  `cap` is required (the
+// caller resolves it, data/grr.py); max_st < 0: no bound (see
+// grr_plan_body).  Touches nothing shared: any number of threads may
+// call it at once.
+void* pml_grr_plan_coo(const int32_t* idx, const int32_t* seg,
+                       const float* val, int64_t m, int64_t table_len,
+                       int64_t n_segments, int32_t cap, int64_t max_st) {
+  auto* plan = new (std::nothrow) GrrPlan();
+  if (!plan) return nullptr;
+  if (cap <= 0) {
+    plan->error = 3;
+    return plan;
+  }
+  try {
+    grr_plan_body(plan, CooEntries{idx, seg, val, m}, table_len,
+                  n_segments, cap, 0, 0, max_st);
   } catch (const std::bad_alloc&) {
     plan->error = 2;  // caller falls back to the numpy path
   }

@@ -98,7 +98,8 @@ STAGES = (
     "estimator_fit", "prepare_fixed", "add_intercept_col", "to_ell",
     "grr_plan_build", "plan_cache_load", "grr_hot_split",
     "grr_plan_ranges", "grr_tail_build", "grr_row_part", "grr_mid_split",
-    "grr_col_build", "grr_routes", "plan_cache_save", "place_batch", "build_coordinates",
+    "grr_col_build", "grr_overflow_level", "grr_routes", "plan_cache_save",
+    "place_batch", "build_coordinates",
     "group_entities", "place_re", "cd_initial_scores", "cd_coordinate",
     "coord_train", "coord_score", "cd_validation", "export_model",
     "validation", "transform", "score_coordinate",

@@ -315,17 +315,70 @@ def test_objective_grr_matches_ell(rng):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_native_plan_matches_python_plan(rng):
-    """The C++ plan builder (pml_grr_plan) and the numpy path choose
-    ranks differently (scan vs sort order) but must produce plans whose
-    contractions agree — and match the dense reference."""
-    import jax.numpy as jnp
+def _assert_leaves_equal(a, b):
+    """Two plans (directions, pairs, lists of pairs) hold the same
+    bytes: one tree structure, and every leaf one dtype, shape and
+    content."""
+    import jax
+
+    leaves_a, structure_a = jax.tree_util.tree_flatten(a)
+    leaves_b, structure_b = jax.tree_util.tree_flatten(b)
+    assert structure_a == structure_b and leaves_a
+    for x, y in zip(leaves_a, leaves_b):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert x.tobytes() == y.tobytes()
+
+
+@pytest.fixture
+def builders(monkeypatch):
+    """``builders.numpy()`` takes both C++ plan entries away, so the
+    numpy body builds every plan, ``builders.numpy(ell=False)`` the COO
+    entry alone (the route colouring stays native: the Python
+    colourer's routes are proper but not the same bytes);
+    ``builders.coo`` and ``builders.routed`` count the calls that
+    reached the C++ COO entry and the router."""
+    import types
 
     import photon_ml_tpu.native as nat
-    from photon_ml_tpu.data.grr import build_grr_pair
 
     if not nat.native_available():
         pytest.skip("native library unavailable")
+    real_coo, real_routes = nat.grr_plan_native_coo, nat.grr_routes_native
+    calls = types.SimpleNamespace(coo=[], routed=[])
+
+    def coo(idx, *args, **kwargs):
+        calls.coo.append(len(idx))
+        return real_coo(idx, *args, **kwargs)
+
+    def routes(dst, hi):
+        calls.routed.append(dst.shape[0])
+        return real_routes(dst, hi)
+
+    def numpy(ell=True):
+        monkeypatch.setattr(nat, "grr_plan_native_coo",
+                            lambda *args, **kwargs: None)
+        if ell:
+            monkeypatch.setattr(nat, "grr_plan_native",
+                                lambda *args, **kwargs: None)
+
+    monkeypatch.setattr(nat, "grr_plan_native_coo", coo)
+    monkeypatch.setattr(nat, "grr_routes_native", routes)
+    calls.numpy = numpy
+    return calls
+
+
+def test_native_plan_matches_python_plan(rng, builders):
+    """The C++ plan builder (pml_grr_plan, pml_grr_plan_coo) and the
+    numpy body rank alike (scan order: numpy's argsort is stable), so
+    at one cap they compile one input to the same bytes, leaf for leaf.
+    They differ only in how an absent cap is resolved (the exact mean
+    occupancy against a sample of segments), so the cap is pinned here.
+    And the plan matches the dense reference."""
+    import jax.numpy as jnp
+
+    from photon_ml_tpu.data.grr import build_grr_pair
+
     n, d, k = 700, 17000, 6
     block = d // k
     cols = np.minimum(
@@ -334,22 +387,13 @@ def test_native_plan_matches_python_plan(rng):
     vals = rng.normal(size=(n, k)).astype(np.float32)
     vals[rng.random((n, k)) < 0.15] = 0.0   # real zero entries drop
 
-    pair_native = build_grr_pair(cols, vals, d)
-    saved = nat._lib
-    nat._lib = None   # force the numpy path
-    try:
-        pair_python = build_grr_pair(cols, vals, d)
-    finally:
-        nat._lib = saved
+    pair_native = build_grr_pair(cols, vals, d, cap=8)
+    builders.numpy()
+    pair_python = build_grr_pair(cols, vals, d, cap=8)
+    _assert_leaves_equal(pair_native, pair_python)
 
     w = jnp.asarray(rng.normal(size=d), jnp.float32)
     r = jnp.asarray(rng.normal(size=n), jnp.float32)
-    np.testing.assert_allclose(np.asarray(pair_native.dot(w)),
-                               np.asarray(pair_python.dot(w)),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(pair_native.t_dot(r)),
-                               np.asarray(pair_python.t_dot(r)),
-                               rtol=2e-4, atol=2e-4)
     x = np.zeros((n, d), np.float32)
     np.add.at(x, (np.repeat(np.arange(n), k), cols.reshape(-1)),
               vals.reshape(-1))
@@ -357,6 +401,239 @@ def test_native_plan_matches_python_plan(rng):
                                x @ np.asarray(w), rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(pair_native.t_dot(r)),
                                x.T @ np.asarray(r), rtol=2e-3, atol=2e-3)
+
+
+def _zipf_coo(rng, nnz, L, S, power=3.0):
+    """Heavy repeat groups: segments drawn with a cubic skew."""
+    seg = (S * rng.random(nnz) ** power).astype(np.int64)
+    return rng.integers(0, L, nnz), seg, \
+        rng.normal(0, 1, nnz).astype(np.float32)
+
+
+def _scattered_spill_coo(rng):
+    """Few entries over many blocks, as the column spill of the public
+    KDD width: groups of six in 2,000 (segment, window) pairs spread
+    over 40 table windows and five segment windows, so cap 4 spills
+    two of each: 4,000 entries over some 200 blocks."""
+    L, S = 40 * 16384, 20000
+    seg = np.repeat(rng.choice(S, 2000, replace=False), 6)
+    idx = (np.repeat(rng.integers(0, 40, 2000), 6) * 16384
+           + rng.integers(0, 16384, 12000))
+    return idx, seg, np.ones(12000, np.float32), L, S
+
+
+COO_CASES = {
+    # name: (entries, table_len, n_segments, build_grr_direction options)
+    "sorted_keys_cap4": lambda rng: (
+        tuple(a[np.argsort(_coo(rng, 30000, 70000, 9000)[1],
+                           kind="stable")]
+              for a in _coo(np.random.default_rng(0), 30000, 70000, 9000)),
+        70000, 9000, dict(cap=4)),
+    "unsorted_keys_cap8": lambda rng: (
+        _coo(rng, 30000, 70000, 70000), 70000, 70000, dict(cap=8)),
+    "cap64_one_window": lambda rng: (
+        _zipf_coo(rng, 40000, 300, 150), 300, 150, dict(cap=64)),
+    "zeros_among_val": lambda rng: (
+        (lambda idx, seg, val: (idx, seg, np.where(
+            rng.random(val.size) < 0.2, np.float32(0), val)))(
+                *_coo(rng, 20000, 40000, 5000)),
+        40000, 5000, dict(cap=4)),
+    "sampled_cap_few_segments": lambda rng: (
+        _zipf_coo(rng, 60000, 20000, 8000), 20000, 8000, dict()),
+    "sampled_cap_many_segments": lambda rng: (
+        _zipf_coo(rng, 60000, 20000, 30000), 20000, 30000, dict()),
+    "empty": lambda rng: (
+        (np.zeros(0, np.int64), np.zeros(0, np.int64),
+         np.zeros(0, np.float32)), 17000, 17, dict()),
+    "chain_three_levels": lambda rng: (
+        _zipf_coo(rng, 120000, 3000, 3000), 3000, 3000,
+        dict(cap=4, overflow_threshold=500)),
+    "host_leaves": lambda rng: (
+        _zipf_coo(rng, 50000, 3000, 3000), 3000, 3000,
+        dict(cap=4, overflow_threshold=500, device=False)),
+    "dense_grid_forced_on": lambda rng: (
+        _coo(rng, 64, 17000 * 3, 17000), 17000 * 3, 17000,
+        dict(cap=4, dense_grid=True)),
+    "dense_grid_forced_off": lambda rng: (
+        _coo(rng, 30000, 70000, 70000), 70000, 70000,
+        dict(cap=8, dense_grid=False)),
+    "level_fails_the_economy_test": lambda rng: (
+        _scattered_spill_coo(rng)[:3], 40 * 16384, 20000,
+        dict(cap=4, overflow_threshold=500)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COO_CASES))
+def test_coo_direction_native_is_the_numpy_bodys_bytes(rng, case, builders):
+    """``build_grr_direction`` through ``pml_grr_plan_coo`` against the
+    numpy body behind it: every leaf of every level equal (ISSUE 33)."""
+    import jax
+
+    (idx, seg, val), L, S, options = COO_CASES[case](rng)
+    native = build_grr_direction(idx, seg, val, L, S, **options)
+    assert builders.coo and builders.coo[0] == np.count_nonzero(val)
+    levels, d = 0, native
+    while d is not None:
+        levels, d = levels + 1, d.overflow
+    if case == "chain_three_levels":
+        assert levels >= 3
+    # every level went to the C++, and was routed unless thrown away
+    kept_levels = len(builders.routed)
+    assert len(builders.coo) >= kept_levels == levels
+    del builders.coo[:]
+    builders.numpy()
+    python = build_grr_direction(idx, seg, val, L, S, **options)
+    assert not builders.coo
+    _assert_leaves_equal(native, python)
+    if options.get("device") is False:
+        assert all(isinstance(leaf, np.ndarray)
+                   for leaf in jax.tree_util.tree_leaves(native))
+    if "dense_grid" in options:
+        assert native.dense_grid is options["dense_grid"]
+    if val.size:
+        table = rng.normal(0, 1, L).astype(np.float32)
+        np.testing.assert_allclose(
+            np.asarray(native.contract(jnp.asarray(table))),
+            _direct(idx, seg, val, table, S), rtol=2e-5, atol=5e-4)
+
+
+@pytest.mark.parametrize("native", [True, False])
+def test_level_failing_the_economy_test_is_never_filled(rng, native,
+                                                       builders):
+    """A level that streams more than the economy bound a entry is
+    refused as soon as its supertiles are counted: the spill comes back
+    as it went in, and nothing of the level is routed."""
+    from photon_ml_tpu.data import grr
+
+    idx, seg, val, L, S = _scattered_spill_coo(rng)
+    if not native:
+        builders.numpy()
+    d = build_grr_direction(idx, seg, val, L, S, cap=4,
+                            overflow_threshold=500, device=False)
+    assert d.overflow is None and len(builders.routed) == 1
+    assert len(builders.coo) == (2 if native else 0)
+    m = int(np.count_nonzero(d.spill_val))
+    assert m == 4000 and d.n_spill == 4000
+    # the level as the parent built it before it asked
+    whole = build_grr_direction(d.spill_idx, d.spill_seg, d.spill_val, L, S,
+                                device=False)
+    assert whole.n_supertiles * grr.SLOTS \
+        > grr.ECONOMY_SLOTS_PER_ENTRY * m
+    plain = build_grr_direction(idx, seg, val, L, S, cap=4, device=False)
+    _assert_leaves_equal(d, plain)
+
+
+def _blocks_coo(rng, nnz, n_gw, n_ow, blocks):
+    """``nnz`` entries of value one over the given (ow, gw) blocks of an
+    ``n_ow`` x ``n_gw`` grid at cap 4 (4,096 segments a window)."""
+    ow, gw = np.asarray(blocks)[rng.integers(0, len(blocks), nnz)].T
+    return (gw * 16384 + rng.integers(0, 16384, nnz),
+            ow * 4096 + rng.integers(0, 4096, nnz),
+            np.ones(nnz, np.float32), n_gw * 16384, n_ow * 4096)
+
+
+# name: (entries, table windows, segment windows, blocks with entries);
+# supertiles as laid out, slots an entry
+ECONOMY_GRIDS = {
+    # 9 of a 3 x 4 grid: dense, 12 supertiles, 65.5
+    "full_grid": (3000, 3, 3, [(o, g) for o in range(3) for g in range(3)]),
+    # one table window: dense, 8 supertiles, 4.4 (the floor's count)
+    "one_window": (30000, 1, 8, [(o, 0) for o in range(8)]),
+    # two blocks a segment window of 8 x 20: legacy order, 36, 147.5
+    "diagonal": (4000, 8, 18, [(o, g % 8) for o in range(18)
+                               for g in (o, o + 1)]),
+    # 22 of 2 x 16 (under 0.7 of the grid): legacy order, 22, 180.2
+    "under_dense_fill": (2000, 2, 16, [(o, 0) for o in range(16)]
+                         + [(o, 1) for o in range(6)]),
+    # 23 of 2 x 16: laid out as the whole grid, 32, 262.1
+    "over_dense_fill": (2000, 2, 16, [(o, 0) for o in range(16)]
+                        + [(o, 1) for o in range(7)]),
+}
+
+
+@pytest.mark.parametrize("native", [True, False])
+@pytest.mark.parametrize("bound", [1, 3, 6, 48, 96, 160, 200, 400])
+def test_economy_decision_is_the_whole_builds(rng, bound, native, builders,
+                                              monkeypatch):
+    """The early test keeps and refuses the levels the test on the
+    finished level kept and refused (``n_supertiles`` as laid out,
+    dense grid and all), on both sides of the bound and on both sides
+    of the dense-grid rule."""
+    from photon_ml_tpu.data import grr
+
+    monkeypatch.setattr(grr, "ECONOMY_SLOTS_PER_ENTRY", bound)
+    if not native:
+        builders.numpy()
+    kept = {}
+    for name, (nnz, n_gw, n_ow, blocks) in ECONOMY_GRIDS.items():
+        idx, seg, val, L, S = _blocks_coo(rng, nnz, n_gw, n_ow, blocks)
+        whole = build_grr_direction(idx, seg, val, L, S, device=False)
+        assert whole.cap == 4 and (whole.n_gw, whole.n_ow) == (n_gw, n_ow)
+        assert whole.dense_grid == (name not in ("diagonal",
+                                                 "under_dense_fill"))
+        want = whole.n_supertiles * grr.SLOTS <= bound * nnz
+        del builders.routed[:]
+        level, s_idx, _s_seg, _s_val = grr._spill_overflow(
+            idx.astype(np.int32), seg.astype(np.int32), val, nnz, L, S,
+            True, 0, device=False, depth=1)
+        assert (level is not None) == want
+        if level is None:
+            assert s_idx.size == nnz and not builders.routed
+        else:
+            _assert_leaves_equal(level, whole)
+        kept[name] = want
+    assert sorted(name for name in kept if kept[name]) == {
+        1: [], 3: [], 6: ["one_window"], 48: ["one_window"],
+        96: ["full_grid", "one_window"],
+        160: ["diagonal", "full_grid", "one_window"],
+        200: ["diagonal", "full_grid", "one_window", "under_dense_fill"],
+        400: sorted(ECONOMY_GRIDS)}[bound]
+
+
+BAD_IDS = {
+    "idx_high": ([0, 1, 50], [0, 1, 2]),
+    "idx_negative": ([0, -1, 2], [0, 1, 2]),
+    "seg_high": ([0, 1, 2], [0, 7, 1]),
+    "seg_negative": ([0, 1, 2], [-1, 0, 1]),
+}
+
+
+@pytest.mark.parametrize("native", [True, False])
+@pytest.mark.parametrize("bad", sorted(BAD_IDS))
+def test_coo_id_out_of_range_raises(bad, native, builders):
+    """One error from ``build_grr_direction`` whichever builder is
+    behind it; the C++ entry, called alone, makes the same check."""
+    import photon_ml_tpu.native as nat
+
+    idx, seg = (np.array(ids) for ids in BAD_IDS[bad])
+    one = np.ones(3, np.float32)
+    if native:
+        with pytest.raises(ValueError, match="idx or seg out of range"):
+            nat.grr_plan_native_coo(idx, seg, one, 50, 7, 4)
+    else:
+        builders.numpy()
+    with pytest.raises(ValueError, match=f"^{bad[:3]} out of range$"):
+        build_grr_direction(idx, seg, one, 50, 7, cap=4)
+    # under a zero value it is no entry
+    zero = np.where((idx < 0) | (idx >= 50) | (seg < 0) | (seg >= 7),
+                    np.float32(0), one)
+    assert build_grr_direction(idx, seg, zero, 50, 7,
+                               cap=4).n_supertiles == 1
+
+
+@pytest.mark.parametrize("which", ["idx", "seg"])
+def test_coo_id_beyond_int32_raises_and_does_not_wrap(which, builders):
+    """2**32 + 5 narrowed to int32 is 5, in range: a plan built from
+    it would be silently wrong."""
+    import photon_ml_tpu.native as nat
+
+    ok, one = np.array([0, 1, 2]), np.ones(3, np.float32)
+    wide = np.array([0, 1, 2**32 + 5], np.int64)
+    idx, seg = (wide, ok) if which == "idx" else (ok, wide)
+    with pytest.raises(ValueError, match=f"^{which} out of range$"):
+        build_grr_direction(idx, seg, one, 50, 7, cap=4)
+    with pytest.raises(ValueError, match=f"{which} id exceeds int32"):
+        nat.grr_plan_native_coo(idx, seg, one, 2**40, 2**40, 4)
 
 
 def test_bad_cap_rejected_both_paths(rng):
@@ -644,6 +921,58 @@ def test_pair_leaves_equal_routed_in_blocks_or_in_one_call(
         a, b = np.asarray(a), np.asarray(b)
         assert a.dtype == b.dtype and a.shape == b.shape
         assert a.tobytes() == b.tobytes()
+
+
+def _levels(direction):
+    out = []
+    while direction is not None:
+        out.append(direction)
+        direction = direction.overflow
+    return out
+
+
+@pytest.mark.parametrize("build", ["resident", "sharded_x8"])
+def test_pair_leaves_equal_native_coo_levels_or_numpy_levels(
+        rng, build, builders):
+    """A whole pair with a mid split and overflow levels in both
+    directions: every overflow level and the mid plan through
+    ``pml_grr_plan_coo`` (ISSUE 33), against the numpy body building
+    them as it did before.  The first levels come from the ELL arrays
+    through ``pml_grr_plan`` on both sides, so an absent cap resolves
+    alike.  Eight shards: the mesh build's pooled overflow and forced
+    mid set."""
+    from photon_ml_tpu.data.grr import build_sharded_grr_pairs
+
+    n, k, dim = 20000, 12, 40000
+    cols, vals = _powerlaw_ell(rng, n, k, dim, x0=300.0)
+
+    def make():
+        if build == "resident":
+            return [build_grr_pair(cols, vals, dim, overflow_threshold=500)]
+        per = n // 8
+        return build_sharded_grr_pairs(
+            [cols[i * per:(i + 1) * per] for i in range(8)],
+            [vals[i * per:(i + 1) * per] for i in range(8)], dim,
+            overflow_threshold=500, mid_threshold=60)
+
+    native = make()
+    for pair in native:
+        row = pair.row_dir
+        assert len(_levels(pair.col_dir)) >= 2
+        assert len(_levels(pair.col_mid)) >= 2
+        assert max(len(_levels(p)) for p in getattr(row, "parts", (row,))) \
+            >= (5 if build == "resident" else 2)
+    # every level past the first and every mid plan went to the C++
+    n_coo = sum(len(_levels(d)[1:]) + (d is pair.col_mid)
+                for pair in native
+                for d in (pair.col_dir, pair.col_mid)
+                + tuple(getattr(pair.row_dir, "parts", (pair.row_dir,))))
+    assert len(builders.coo) >= n_coo
+    del builders.coo[:]
+    builders.numpy(ell=False)
+    python = make()
+    assert not builders.coo
+    _assert_leaves_equal(native, python)
 
 
 def test_col_range_split_reduces_spill(rng):

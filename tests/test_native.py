@@ -183,3 +183,39 @@ def test_grr_routes_callers_at_once_all_return_the_same(rng):
         t.join(timeout=120)
     assert not any(t.is_alive() for t in threads)
     assert got == [want] * len(got)
+
+
+def test_grr_plan_coo_callers_at_once_all_return_the_serial_bytes(rng):
+    """Four threads call the COO plan entry at once, as the plan
+    build's chains do with their overflow levels: they share nothing,
+    none waits on another (joined under a time limit), and each gets
+    the bytes of a call made alone."""
+    import threading
+
+    from photon_ml_tpu.native import grr_plan_native_coo
+
+    m, table_len, n_segments = 200_000, 5 * 16384, 30_000
+    idx = rng.integers(0, table_len, m)
+    seg = (n_segments * rng.random(m) ** 3.0).astype(np.int64)
+    val = rng.normal(size=m).astype(np.float32)
+
+    def plan():
+        out = grr_plan_native_coo(idx, seg, val, table_len, n_segments, 8)
+        return {name: a.tobytes() if isinstance(a, np.ndarray) else a
+                for name, a in out.items()}
+
+    want = plan()
+    assert want["n_st"] > 10 and len(want["spill_val"]) > 0
+    got = [None] * 4
+
+    def call(i):
+        got[i] = plan()
+
+    threads = [threading.Thread(target=call, args=(i,), daemon=True)
+               for i in range(len(got))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    assert got == [want] * len(got)

@@ -64,6 +64,12 @@ ROUTE_STAGE = "grr_routes"
 # on a pool thread (ISSUE 30); every column of the tiny fit pays for its
 # slots.
 TAIL_STAGE = "grr_tail_build"
+# Only a direction whose spill is worth a plan of its own runs this one,
+# once for every overflow level it starts, on the thread that builds the
+# direction and so inside that thread's chain stage and inside the level
+# above (ISSUE 33); the tiny fit spills too little.
+LEVEL_STAGE = "grr_overflow_level"
+LEVEL_COUNTS = {"depth", "entries", "supertiles", "native", "kept"}
 CLASS_COUNTS = {"active_columns", "hot_columns", "planned_columns",
                 "planned_nnz", "tail_columns", "tail_nnz"}
 # Counts every run of the stage must carry (a stage may carry more).
@@ -93,8 +99,8 @@ COUNTS = {
 
 
 def test_stage_table_is_the_whole_of_stages():
-    assert set(PARENT) | set(CACHE_STAGES) | {ROUTE_STAGE, TAIL_STAGE} \
-        == set(telemetry.STAGES)
+    assert set(PARENT) | set(CACHE_STAGES) \
+        | {ROUTE_STAGE, TAIL_STAGE, LEVEL_STAGE} == set(telemetry.STAGES)
     assert len(set(telemetry.STAGES)) == len(telemetry.STAGES)
 
 
@@ -394,15 +400,89 @@ def test_grr_routes_stage_only_for_calls_that_went_to_the_threads(
         assert args["workers"] == min(_usable_cores(), args["blocks"])
 
 
+def _around(spans, inner, names):
+    """The spans named in ``names`` on ``inner``'s thread that hold it."""
+    return [e for e in spans if e["name"] in names and e is not inner
+            and e["tid"] == inner["tid"] and e["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= e["ts"] + e["dur"]]
+
+
 def test_grr_routes_stage_nests_inside_its_chain_stage(routed_build):
+    """Directly inside the chain's stage, or inside the overflow levels
+    between the two where the routed plan is a level's."""
     spans, _routed = routed_build
-    chains = [e for e in spans if e["name"] in POOL_STAGES]
     for route in (e for e in spans if e["name"] == ROUTE_STAGE):
-        (chain,) = [c for c in chains if c["tid"] == route["tid"]
-                    and c["ts"] <= route["ts"]
-                    and route["ts"] + route["dur"] <= c["ts"] + c["dur"]]
-        assert route["depth"] == chain["depth"] + 1
+        (chain,) = _around(spans, route, POOL_STAGES)
+        levels = _around(spans, route, (LEVEL_STAGE,))
+        assert route["depth"] == chain["depth"] + 1 + len(levels)
         assert route["cat"] == "stage" and "parent" not in route["args"]
+
+
+@pytest.fixture(scope="module")
+def levelled_build(tmp_path_factory):
+    """(spans, the pair) of one plan build whose column direction spills
+    through two overflow levels or more, as telemetry spans."""
+    from photon_ml_tpu.data import grr
+
+    rng = np.random.default_rng(5)
+    n, k, dim = 20000, 6, 3000
+    cols = (dim * rng.random((n, k)) ** 3.0).astype(np.int32)
+    vals = rng.normal(size=(n, k)).astype(np.float32)
+    out = tmp_path_factory.mktemp("levelled")
+    session = telemetry.start("trace", str(out))
+    try:
+        pair = grr.build_grr_pair(cols, vals, dim, cap=4,
+                                  hot_threshold=10**9, mid_threshold=10**9,
+                                  overflow_threshold=500)
+    finally:
+        session.close()
+    spans = [e for e in read_run_log(str(out / "run_log.jsonl"))
+             if e["event"] == "span"]
+    return spans, pair
+
+
+def _chain_of(direction):
+    out = []
+    while direction is not None:
+        out.append(direction)
+        direction = direction.overflow
+    return out
+
+
+def test_grr_overflow_level_stage_once_a_level_with_its_counts(
+        levelled_build):
+    import photon_ml_tpu.native as nat
+
+    spans, pair = levelled_build
+    found = [e for e in spans if e["name"] == LEVEL_STAGE]
+    col_levels = _chain_of(pair.col_dir)[1:]
+    assert len(col_levels) >= 2
+    (col_build,) = [e for e in spans if e["name"] == "grr_col_build"]
+    of_col = sorted((e for e in found if e["tid"] == col_build["tid"]),
+                    key=lambda e: e["args"]["depth"])
+    assert [e["args"]["depth"] for e in of_col] \
+        == list(range(2, 2 + len(of_col)))
+    kept = [e for e in of_col if e["args"]["kept"]]
+    assert [e["args"]["supertiles"] for e in kept] \
+        == [d.n_supertiles for d in col_levels]
+    for event in found:
+        assert set(event["args"]) == LEVEL_COUNTS
+        assert event["args"]["native"] == int(nat.native_available())
+        assert event["args"]["entries"] > 500
+        assert event["cat"] == "stage"
+
+
+def test_grr_overflow_level_stage_nests_in_its_chain_and_the_level_above(
+        levelled_build):
+    spans, _pair = levelled_build
+    found = [e for e in spans if e["name"] == LEVEL_STAGE]
+    assert any(e["args"]["depth"] > 2 for e in found)
+    for event in found:
+        (chain,) = _around(spans, event, POOL_STAGES)
+        above = _around(spans, event, (LEVEL_STAGE,))
+        assert sorted(e["args"]["depth"] for e in above) \
+            == list(range(2, event["args"]["depth"]))
+        assert event["depth"] == chain["depth"] + 1 + len(above)
 
 
 def test_grr_tail_build_stage_only_where_the_input_has_a_tail(
