@@ -37,14 +37,26 @@ def load_module(path):
 
 def metrics_of(manifest, group, cell_name):
     """The metrics of ``group`` ("end_to_end" or "per_layer") that the
-    cell reports: those with no ``workloads`` key, or that list it."""
+    cell reports: those that list it under ``workloads``; of those
+    with no such key every end-to-end metric, and every per-layer
+    metric that ``moves`` an end-to-end metric the cell reports."""
+    def listed(m):
+        return "workloads" not in m or cell_name in m["workloads"]
+
+    if group == "end_to_end":
+        return [m for m in manifest[group] if listed(m)]
+    moved = {m["name"] for m in manifest["end_to_end"] if listed(m)}
     return [m for m in manifest[group]
-            if "workloads" not in m or cell_name in m["workloads"]]
+            if (cell_name in m["workloads"] if "workloads" in m
+                else m["moves"] in moved)]
 
 
 def resolve(manifest, cell_name, root=ROOT):
-    """Paths of everything the cell is made of; raises KeyError for a
-    cell the manifest does not have."""
+    """Paths of everything the cell is made of, under ``root`` (a
+    checkout: the manifest's ``file`` entries are relative to it, and
+    the benchmark's folders lie in its directory of this one's name);
+    raises KeyError for a cell the manifest does not have."""
+    bench_dir = os.path.join(root, os.path.basename(BENCH_DIR))
     cells = {w["name"]: w for w in manifest["workloads"]}
     if cell_name not in cells:
         raise KeyError(f"no workload {cell_name!r} in BENCHMARK.json; it has "
@@ -53,7 +65,7 @@ def resolve(manifest, cell_name, root=ROOT):
     config_entry = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     config_path = os.path.join(root, config_entry["file"])
     config = load_json(config_path)
-    traffic_path = os.path.join(BENCH_DIR, "traffic",
+    traffic_path = os.path.join(bench_dir, "traffic",
                                 cell["traffic"] + ".json")
     traffic = load_json(traffic_path)
     return {
@@ -61,13 +73,13 @@ def resolve(manifest, cell_name, root=ROOT):
         "config": config, "config_path": config_path,
         "traffic": traffic, "traffic_path": traffic_path,
         "generator_path": os.path.join(
-            BENCH_DIR, "generators", config["generator"]["name"] + ".py"),
+            bench_dir, "generators", config["generator"]["name"] + ".py"),
         "operation_path": os.path.join(
-            BENCH_DIR, "operations", traffic["operation"] + ".py"),
+            bench_dir, "operations", traffic["operation"] + ".py"),
         "end_to_end": metrics_of(manifest, "end_to_end", cell_name),
         "per_layer": metrics_of(manifest, "per_layer", cell_name),
         "layer_metric_paths": {
-            m["name"]: os.path.join(BENCH_DIR, "layer_metrics",
+            m["name"]: os.path.join(bench_dir, "layer_metrics",
                                     m["name"] + ".py")
             for m in metrics_of(manifest, "per_layer", cell_name)},
     }

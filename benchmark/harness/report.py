@@ -30,10 +30,12 @@ class CompileClock:
 
 
 def result_line(*, correct, attempted, failed, metrics, units, device,
-                breakdown=None):
+                compared, breakdown=None):
     """The contract's result line.  ``metrics`` is name -> number and
     ``units`` name -> unit (from the manifest); ``device`` already has
-    the contract's keys; ``breakdown`` is given only by a traced run."""
+    the contract's keys; ``breakdown`` is given only by a traced run;
+    ``compared`` is every number ``correct`` rests on beside its limit,
+    by short name, and comes last."""
     record = {
         "correct": bool(correct),
         "attempted": int(attempted),
@@ -44,4 +46,5 @@ def result_line(*, correct, attempted, failed, metrics, units, device,
     }
     if breakdown is not None:
         record["breakdown"] = breakdown
+    record["compared"] = compared
     return json.dumps(record)

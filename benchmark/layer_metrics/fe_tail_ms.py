@@ -35,25 +35,28 @@ ENCLOSING = re.compile(r"[)}\]] (while|conditional|call)\(")
 
 
 def tail_events(ctx):
-    """(the plan build's counts, [(duration_ns, name)] of the traced
-    fit's device operations that hold the tail's length as a
-    dimension); (None, []) where there is nothing to read.  An
-    operation that encloses others (the solver's ``while`` carries the
-    tail's arrays in its state, so its text holds their shapes) is left
-    out: what it encloses is counted where it is the tail's."""
+    """(the plan build's counts, {device: [(start_ns, duration_ns,
+    name)]} of the traced fit's device operations that hold the tail's
+    length as a dimension, in the trace's order); (None, {}) where
+    there is nothing to read.  An operation that encloses others (the
+    solver's ``while`` carries the tail's arrays in its state, so its
+    text holds their shapes) is left out: what it encloses is counted
+    where it is the tail's."""
     counts = manifests.load_module(os.path.join(
         HERE, "tail_nnz_share.py")).plan_build_counts(ctx)
     if not counts or not counts.get("tail_nnz"):
-        return None, []
+        return None, {}
     mark = f"[{counts.get('tail_len') or counts['tail_nnz']}]"
-    return counts, [(duration, name)
-                    for events in ctx["trace"]["device_events"].values()
-                    for _start, duration, name in events
-                    if mark in name and not ENCLOSING.search(name)]
+    found = {device: [event for event in events
+                      if mark in event[2] and not ENCLOSING.search(event[2])]
+             for device, events in ctx["trace"]["device_events"].items()}
+    return counts, {device: events for device, events in found.items()
+                    if events}
 
 
 def read(ctx):
     _counts, found = tail_events(ctx)
     if not found:
         return None
-    return sum(duration for duration, _name in found) / ctx["chips"] / 1e6
+    return sum(duration for events in found.values()
+               for _start, duration, _name in events) / ctx["chips"] / 1e6

@@ -123,36 +123,46 @@ def penalty(fixed, random_effects):
     return total
 
 
-def coordinate_gradient(block, own_scores, other_margins, labels):
-    """Gradient of the objective with respect to one coordinate's
-    coefficients, the others held.  ``block`` is the coordinate as
+def _value_and_gradient_norm(block, z, at, labels):
+    """(objective, norm of its gradient) of one coordinate with its
+    coefficients at ``at`` and every row's margin ``z``; ``block`` as
     ``penalty`` takes it (five entries for the fixed effect, whose
     first is the CSR ``indptr``; five for a random effect, whose first
-    is the dense ``x``), ``own_scores`` its rows' scores under those
-    coefficients and ``other_margins`` the sum of the other
-    coordinates' scores as its solver saw them.  Returns (the
-    gradient's norm, the norm of the same gradient with the
-    coordinate's coefficients at zero)."""
-    labels = np.asarray(labels, np.float64)
+    is the dense ``x``)."""
     *rows, coefs, lam = block
-    coefs = np.asarray(coefs, np.float64)
+    r = 1.0 / (1.0 + np.exp(-z)) - labels
+    loss = float(np.sum(np.logaddexp(0.0, z) - labels * z))
+    if np.ndim(coefs) == 1:  # fixed effect: intercept last, unregularised
+        g = csr_t_dot(*rows, r, len(coefs) - 1) + lam * at[:-1]
+        return (loss + 0.5 * lam * float(np.sum(at[:-1] ** 2)),
+                float(np.sqrt(np.sum(g ** 2) + np.sum(r) ** 2)))
+    g = entity_t_dot(*rows, r) + lam * at
+    return (loss + 0.5 * lam * float(np.sum(at ** 2)),
+            float(np.sqrt(np.sum(g ** 2))))
 
-    def norm(z, at):
-        r = 1.0 / (1.0 + np.exp(-z)) - labels
-        if coefs.ndim == 1:  # fixed effect: intercept last, unregularised
-            g = csr_t_dot(*rows, r, len(coefs) - 1) + lam * at[:-1]
-            return float(np.sqrt(np.sum(g ** 2) + np.sum(r) ** 2))
-        g = entity_t_dot(*rows, r) + lam * at
-        return float(np.sqrt(np.sum(g ** 2)))
 
-    return (norm(other_margins + own_scores, coefs),
-            norm(other_margins, np.zeros_like(coefs)))
+def coordinate_end(block, own_scores, other_margins, labels):
+    """Where one coordinate's solve ended, the others held: (the
+    objective its solver minimises, i.e. the summed logistic loss of
+    all training rows plus its own L2 term, at the coordinate's
+    coefficients; the norm of that objective's gradient there; the
+    same norm with the coordinate's coefficients at zero).
+    ``own_scores`` are its rows' scores under its coefficients and
+    ``other_margins`` the sum of the other coordinates' scores as its
+    solver saw them."""
+    labels = np.asarray(labels, np.float64)
+    coefs = np.asarray(block[-2], np.float64)
+    value, norm = _value_and_gradient_norm(
+        block, other_margins + own_scores, coefs, labels)
+    return value, norm, _value_and_gradient_norm(
+        block, other_margins, np.zeros_like(coefs), labels)[1]
 
 
 def check(*, valid_margins, valid_labels, train_margins, train_labels,
-          train_penalty, true_train_margins, gradients, reported_auc,
-          auc_floor, objective_gap, gradient_rtol):
-    """Four conditions, all needed for ``correct``:
+          train_penalty, true_train_margins, gradients, fixed_effect,
+          reported_auc, auc_floor, objective_gap, gradient_rtol,
+          fixed_effect_rtol):
+    """Five conditions, all needed for ``correct``:
 
     (a) scoring: the plain AUC of the exported coefficients equals the
         program's reported AUC within AUC_ATOL;
@@ -161,33 +171,72 @@ def check(*, valid_margins, valid_labels, train_margins, train_labels,
         margins on the same rows plus the configuration's
         ``objective_gap``.  The generating margins are this seed's own
         yardstick, so the bound moves with the seed's noise and can be a
-        few 1e-3 wide: fewer iterations, a coarser precision or a
-        coordinate's left-out tail leave the objective higher;
+        few 1e-3 wide: fewer iterations or a coordinate's left-out tail
+        leave the objective higher;
     (c) how well each solve finished: ``gradients`` gives, by
         coordinate, the norm of the objective's gradient with respect
         to that coordinate's coefficients at the state its solver saw,
-        and the same at zero coefficients; the first is at most the
+        and the same at zero coefficients (``coordinate_end``'s last
+        two); the first is at most the
         coordinate's ``gradient_rtol`` of the second.  A solve stopped
-        early, or run in a lower precision, stays above it;
-    (d) the AUC is above the configuration's floor."""
+        early, or a per-entity solve in a lower precision, stays above
+        it;
+    (d) the AUC is above the configuration's floor;
+    (e) the fixed effect's contractions are the configuration's
+        precision: ``fixed_effect`` gives what the fit itself computed
+        through its own plans (its training scores, its solver's last
+        gradient norm) as relative distances from the plain float64
+        numbers at the exported coefficients; each that
+        ``fixed_effect_rtol`` names is at most its limit there.  A
+        contraction whose result is rounded to bfloat16 is over it a
+        hundredfold.  A fit that hands none of it over is not correct.
+
+    ``compared`` lists every number compared beside its limit (a floor
+    as ``at_least``), and ``conditions`` the five verdicts."""
     plain_auc = auc(valid_margins, valid_labels)
     n = len(train_labels)
     objective = mean_log_loss(train_margins, train_labels) + train_penalty / n
     true_loss = mean_log_loss(true_train_margins, train_labels)
     relative = {name: g / g0 for name, (g, g0) in gradients.items()}
-    out = {
+    fixed_effect = fixed_effect or {}
+    compared = {
+        "auc_difference": {"value": abs(plain_auc - reported_auc),
+                           "limit": AUC_ATOL},
+        "objective_gap": {"value": objective - true_loss,
+                          "limit": objective_gap},
+        "auc": {"value": plain_auc, "at_least": auc_floor},
+    }
+    for name, value in relative.items():
+        compared["gradient." + name] = {"value": value,
+                                        "limit": gradient_rtol[name]}
+    for name, limit in fixed_effect_rtol.items():
+        compared["fixed_effect." + name] = {
+            "value": fixed_effect.get(name), "limit": limit}
+
+    def holds(entry):
+        if entry["value"] is None or not np.isfinite(entry["value"]):
+            return False
+        if "at_least" in entry:
+            return bool(entry["value"] > entry["at_least"])
+        return bool(entry["value"] <= entry["limit"])
+
+    conditions = {
+        "auc_agrees": holds(compared["auc_difference"]),
+        "objective_reached": holds(compared["objective_gap"]),
+        "gradient_small": all(holds(compared["gradient." + name])
+                              for name in relative),
+        "above_floor": holds(compared["auc"]),
+        "fixed_effect_exact": all(holds(compared["fixed_effect." + name])
+                                  for name in fixed_effect_rtol),
+    }
+    # the verdicts are keys of their own too: tests/test_grr_tail.py reads
+    # ``gradient_small`` and ``objective_reached`` there
+    return {
         "plain_auc": plain_auc, "reported_auc": float(reported_auc),
-        "auc_agrees": bool(abs(plain_auc - reported_auc) <= AUC_ATOL),
         "objective_per_row": objective, "true_margin_log_loss": true_loss,
         "objective_gap": objective - true_loss,
-        "objective_gap_limit": objective_gap,
-        "objective_reached": bool(objective - true_loss <= objective_gap),
-        "gradient_rel": relative, "gradient_rtol": gradient_rtol,
-        "gradient_small": all(relative[name] <= gradient_rtol[name]
-                              for name in relative),
-        "auc_floor": auc_floor,
-        "above_floor": bool(plain_auc > auc_floor),
+        "gradient_rel": relative, "fixed_effect_rel": fixed_effect,
+        **conditions, "conditions": conditions,
+        "correct": all(conditions.values()),
+        "compared": compared,
     }
-    out["correct"] = (out["auc_agrees"] and out["objective_reached"]
-                      and out["gradient_small"] and out["above_floor"])
-    return out

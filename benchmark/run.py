@@ -4,14 +4,17 @@
 
 One process.  Set-up (everything before the window, all of it
 ``setup_s``): find the cell's files by name, make its data from the
-seed, run the cell's operation once to compile or load every program,
-and check that warm-up result against the plain reference.  Window:
-start operations while less than ``--seconds`` have passed and always
-finish the one in flight.  With ``--trace 1`` the window's first
+seed, and run the cell's operation once to compile or load every
+program.  Window: start operations while less than ``--seconds`` have
+passed and always finish the one in flight.  With ``--trace 1`` the window's first
 operation runs under the JAX profiler and the per-layer metrics are
-read; with ``--trace 0`` the end-to-end metrics are.
+read; with ``--trace 0`` the end-to-end metrics are.  Once the window
+has closed and the device's peak has been read, the window's last
+result is checked against the plain reference: that decides
+``correct``, and its seconds are nobody's metric.
 
-Everything but the result goes to stderr, one JSON object per line.
+Everything but the result goes to stderr, one JSON object per line,
+the numbers that were compared, each beside its limit, last.
 The only line on stdout is the contract's result.  Without a TPU, or
 with fewer chips than the cell asks for, it exits non-zero and prints
 no result.  See README.md beside this file.
@@ -104,14 +107,11 @@ def run_cell(cell, seed, seconds, trace_on, devices):
     say(phase="warm_up", seconds=time.perf_counter() - t0,
         compile_seconds=clock.seconds - c0, compiles=clock.count,
         **operation.summary(warm))
-    t0 = time.perf_counter()
-    check = operation.reference_check(state, warm)
-    say(phase="reference_check", seconds=time.perf_counter() - t0, **check)
 
     # -- window -------------------------------------------------------------
     durations = []
     attempted = failed = 0
-    trace = None
+    trace = last = None
     c0, n0 = clock.seconds, clock.count
     setup_s = time.time() - T_START
     w0 = time.perf_counter()
@@ -139,6 +139,7 @@ def run_cell(cell, seed, seconds, trace_on, devices):
             continue
         if operation.ok(outcome, warm):
             durations.append(took)
+            last = outcome
         else:
             failed += 1
         say(phase=operation_name, seconds=took, **operation.summary(outcome))
@@ -186,9 +187,17 @@ def run_cell(cell, seed, seconds, trace_on, devices):
         compile_cache_entries_before=entries_before,
         compile_cache_entries_after=cache_entry_count(cache_dir),
         compile_seconds_total=clock.seconds)
+
+    # -- correct: what the timed path produced, against the reference ------
+    t0 = time.perf_counter()
+    check = operation.reference_check(state, last)
+    say(phase="reference_check", seconds=time.perf_counter() - t0,
+        **{k: v for k, v in check.items() if k != "compared"})
+    say(correct=check["correct"], compared=check["compared"])
     return dict(correct=check["correct"], attempted=attempted, failed=failed,
                 metrics=metrics, units={m["name"]: m["unit"] for m in group},
-                device=device, breakdown=breakdown)
+                device=device, breakdown=breakdown,
+                compared=check["compared"])
 
 
 def main(argv=None):

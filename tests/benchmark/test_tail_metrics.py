@@ -1,4 +1,5 @@
-"""The readers of the tail's three metrics (ISSUE 30) on a hand-written
+"""The readers of the tail's four metrics (ISSUE 30; the roofline by
+direction since ISSUE 34) on a hand-written
 trace: a traced ``fit`` whose ``photon/grr_plan_build`` stage carries the
 column classes' counts, and device operations named, as a v5e names
 them, by their HLO text without metadata, some of them on vectors as
@@ -19,7 +20,8 @@ from benchmark.harness import host_spans  # noqa: E402
 from benchmark.harness import manifest as manifests  # noqa: E402
 from benchmark.harness import trace_reduce  # noqa: E402
 
-NAMES = ("fe_tail_ms", "fe_tail_hbm_roofline", "tail_nnz_share")
+NAMES = ("fe_tail_ms", "fe_tail_dot_roofline", "fe_tail_tdot_roofline",
+         "tail_nnz_share")
 COUNTS = {"rows": 1000, "dim": 50000, "nnz": 11000, "tail_nnz": 4400}
 
 
@@ -66,10 +68,17 @@ DEVICE_OPS = [
     (OTHER, 60000, 9000), (ROWS_OP, 70000, 500),
     (SUM_COLS, 150000, 6000),
 ]
-TAIL_NS = 3 * (1000 + 2000 + 5000) + 2 * (2000 + 6000)
+DOT_NS = 3 * (1000 + 2000 + 5000)
+TDOT_NS = 2 * (2000 + 6000)
+TAIL_NS = DOT_NS + TDOT_NS
 # three calls read 4,400 entries of 16 B and write 1,000 rows of 4 B;
 # two read the same entries and write 50,000 columns of 4 B
-LEAST_BYTES = 3 * (4400 * 16 + 4 * 1000) + 2 * (4400 * 16 + 4 * 50000)
+DOT_BYTES = 3 * (4400 * 16 + 4 * 1000)
+TDOT_BYTES = 2 * (4400 * 16 + 4 * 50000)
+
+
+def _share(least_bytes, ns):
+    return 100.0 * least_bytes / (ns / 1e9) / 819e9
 
 
 def _xspace(counts=COUNTS, device_ops=DEVICE_OPS):
@@ -137,25 +146,42 @@ def test_readers_give_the_numbers_worked_out_by_hand(ctx):
     assert ctx["trace"]["interval"] == (0.0, 100000.0)
     assert _reader("fe_tail_ms").read(ctx) == pytest.approx(TAIL_NS / 1e6)
     assert _reader("tail_nnz_share").read(ctx) == pytest.approx(40.0)
-    share = _reader("fe_tail_hbm_roofline").read(ctx)
-    assert share == pytest.approx(
-        100.0 * LEAST_BYTES / (TAIL_NS / 1e9) / 819e9, rel=1e-12)
-    assert 0 < share < 100
-    roofline = _reader("fe_tail_hbm_roofline")
-    assert roofline.least_bytes(4400, 50000, 1000, 3, 2) == LEAST_BYTES
-    assert roofline.least_bytes(4400, 50000, 1000, 0, 0) == 0
+    dot = _reader("fe_tail_dot_roofline").read(ctx)
+    tdot = _reader("fe_tail_tdot_roofline").read(ctx)
+    assert dot == pytest.approx(_share(DOT_BYTES, DOT_NS), rel=1e-12)
+    assert tdot == pytest.approx(_share(TDOT_BYTES, TDOT_NS), rel=1e-12)
+    assert 0 < dot < 100 and 0 < tdot < 100
+    roofline = _reader("fe_tail_dot_roofline")
+    assert roofline.least_bytes(4400, 1000, 3) == DOT_BYTES
+    assert roofline.least_bytes(4400, 50000, 2) == TDOT_BYTES
+    assert roofline.least_bytes(4400, 50000, 0) == 0
 
 
 def test_the_calls_are_counted_by_what_each_contraction_writes(ctx):
     counts, found = _reader("fe_tail_ms").tail_events(ctx)
     assert counts["tail_nnz"] == 4400
-    assert len(found) == 13                 # the late one is outside
-    roofline = _reader("fe_tail_hbm_roofline")
-    assert roofline.calls(found, counts["rows"]) == 3
-    assert roofline.calls(found, counts["dim"]) == 2
+    (events,) = found.values()
+    assert len(events) == 13                # the late one is outside
+    roofline = _reader("fe_tail_dot_roofline")
+    assert roofline.by_direction(counts, found) == {
+        "dot": [3, DOT_NS], "tdot": [2, TDOT_NS]}
     # an operation that only writes [dim] or [rows] is not the tail's
     assert not any("%fusion.5 " in name or "%fusion.6 " in name
-                   for _d, name in found)
+                   for _s, _d, name in events)
+
+
+def test_a_direction_that_was_not_traced_is_left_out(tmp_path, monkeypatch):
+    """Only X.w calls in the traced fit: the other direction's reader
+    gives nothing, never a share of 0; and what follows the last
+    segment-sum (a call the trace cut off) is nobody's time."""
+    only_dot = [op for op in DEVICE_OPS
+                if op[0] not in (GATHER_R, SUM_COLS)] + [(PAD, 90000, 1000)]
+    ctx = _traced(tmp_path, monkeypatch, _xspace(COUNTS, only_dot))
+    assert _reader("fe_tail_dot_roofline").read(ctx) == pytest.approx(
+        _share(DOT_BYTES, DOT_NS), rel=1e-12)
+    assert _reader("fe_tail_tdot_roofline").read(ctx) is None
+    assert _reader("fe_tail_ms").read(ctx) == pytest.approx(
+        (DOT_NS + 1000) / 1e6)
 
 
 def test_the_operations_are_known_by_the_length_the_program_publishes(
@@ -168,8 +194,10 @@ def test_the_operations_are_known_by_the_length_the_program_publishes(
     ctx = _traced(tmp_path, monkeypatch,
                   _xspace(dict(COUNTS, tail_len=4480), padded))
     assert _reader("fe_tail_ms").read(ctx) == pytest.approx(TAIL_NS / 1e6)
-    assert _reader("fe_tail_hbm_roofline").read(ctx) == pytest.approx(
-        100.0 * LEAST_BYTES / (TAIL_NS / 1e9) / 819e9, rel=1e-12)
+    assert _reader("fe_tail_dot_roofline").read(ctx) == pytest.approx(
+        _share(DOT_BYTES, DOT_NS), rel=1e-12)
+    assert _reader("fe_tail_tdot_roofline").read(ctx) == pytest.approx(
+        _share(TDOT_BYTES, TDOT_NS), rel=1e-12)
     # and nothing is found under the entries' count
     ctx = _traced(tmp_path / "unsaid", monkeypatch, _xspace(COUNTS, padded))
     assert _reader("fe_tail_ms").read(ctx) is None
@@ -181,7 +209,7 @@ def test_readers_give_none_without_a_trace(name):
     assert _reader(name).read({"trace": None, "chips": 1}) is None
 
 
-def test_a_program_without_the_tail_class_leaves_all_three_out(
+def test_a_program_without_the_tail_class_leaves_them_all_out(
         tmp_path, monkeypatch):
     """The parent commit's trace: a plan build whose stage carries no
     class counts, and no device operation as long as a tail."""
@@ -199,16 +227,20 @@ def test_an_input_without_a_tail_reports_a_share_of_zero_and_no_time(
     ctx = _traced(tmp_path, monkeypatch,
                   _xspace(dict(COUNTS, tail_nnz=0), plain))
     assert _reader("tail_nnz_share").read(ctx) == 0.0
-    assert _reader("fe_tail_ms").read(ctx) is None
-    assert _reader("fe_tail_hbm_roofline").read(ctx) is None
+    for name in NAMES[:3]:
+        assert _reader(name).read(ctx) is None
 
 
-def test_the_manifest_gives_the_three_to_the_new_cell_alone():
+def test_the_manifest_gives_them_to_the_cells_with_a_tail_alone():
     manifest = manifests.load_manifest()
     by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert "fe_tail_hbm_roofline" not in by_name
     for name in NAMES:
         assert by_name[name]["workloads"] == ["game5-kdd12.fit-cold"]
         assert by_name[name]["moves"] == "fit_s"
+    for name in NAMES[1:3]:
+        assert (by_name[name]["unit"], by_name[name]["better"]) == (
+            "%", "higher")
     cell = manifests.resolve(manifest, "game5-kdd12.fit-cold")
     assert set(NAMES) <= set(cell["layer_metric_paths"])
     old = manifests.resolve(manifest, "game5-kdd.fit-cold")
