@@ -41,6 +41,15 @@ from photon_ml_tpu.data.grr import GrrPair, build_grr_pair
 
 Array = jax.Array
 
+# A float32 contraction on a TPU's matrix unit multiplies in bfloat16
+# unless told otherwise.  A matrix-vector product alone is lowered to
+# multiply + reduce in float32, and so are the vmapped per-entity
+# products of narrow blocks; a bucket of wide per-entity blocks (64 rows
+# by 391 columns) goes to the matrix unit, where its scores came back
+# 1.5e-2 from their float64 values (PERF.md section 6, PR 35).  The
+# configuration states float32: the dense contractions say so.
+F32 = jax.lax.Precision.HIGHEST
+
 
 @struct.dataclass
 class DenseBatch:
@@ -62,15 +71,15 @@ class DenseBatch:
 
     def margins(self, w: Array) -> Array:
         """x·w + offset, the GLM margin (one MXU matmul)."""
-        return self.x @ w + self.offsets
+        return jnp.matmul(self.x, w, precision=F32) + self.offsets
 
     def xt_dot(self, r: Array) -> Array:
         """X^T r — gradient-side contraction (masking folded into r)."""
-        return self.x.T @ r
+        return jnp.matmul(self.x.T, r, precision=F32)
 
     def x_dot(self, v: Array) -> Array:
         """X v — HVP-side contraction."""
-        return self.x @ v
+        return jnp.matmul(self.x, v, precision=F32)
 
 
 @struct.dataclass

@@ -50,7 +50,10 @@ from photon_ml_tpu.game.coordinates import (
     build_random_effect_coordinate,
     build_random_effect_coordinate_sparse,
 )
-from photon_ml_tpu.game.coordinate_descent import run_coordinate_descent
+from photon_ml_tpu.game.coordinate_descent import (
+    CoordinateDescentResult,
+    run_coordinate_descent,
+)
 from photon_ml_tpu.game.dataset import GameDataset
 from photon_ml_tpu.game.sampling import binary_classification_down_sample
 from photon_ml_tpu.models.coefficients import Coefficients
@@ -89,6 +92,11 @@ class FitResult:
     # Per-CD-iteration validation metrics (reference: CoordinateDescent
     # logs every evaluator each sweep); empty without validation data.
     validation_history: list = dataclasses.field(default_factory=list)
+    # What ``run_coordinate_descent`` returned for this point (its
+    # coefficients, each coordinate's final training scores, the
+    # solvers' records), as it stands; None where a point is not the
+    # product of one descent (the swept lanes).
+    descent: CoordinateDescentResult | None = None
 
 
 def _reg_context(settings: OptimizerSettings, weight: float, dim: int,
@@ -1122,6 +1130,7 @@ class GameEstimator:
                 c.name, c.optimizer.reg_weight)
                 for c in cfg.coordinates},
             validation_history=cd.validation_history,
+            descent=cd,
         )
 
     def fit(self, train: GameDataset,

@@ -194,7 +194,10 @@ def _projected_score_table(
         return np.zeros(0, np.int64), np.zeros(0, np.float64)
     keys = np.concatenate(keys_parts)
     vals = np.concatenate(vals_parts)
-    order = np.argsort(keys)
+    # a bucket's keys are already ascending where its entities' features
+    # are (``build_subspace_projection`` lays them out so): a stable
+    # sort merges those runs, and still sorts any other layout
+    order = np.argsort(keys, kind="stable")
     return keys[order], vals[order]
 
 

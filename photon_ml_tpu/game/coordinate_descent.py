@@ -620,7 +620,8 @@ def _run_sweep(coordinates, update_sequence, locked_coordinates, coefs,
                 w, diag = jax.block_until_ready(
                     coord.train(offsets, coefs.get(name),
                                 donate_warm_start=True))
-                train_stage.set(**_solve_counts(diag))
+                train_stage.set(**_solve_counts(diag),
+                                **coord.train_counts())
             with telemetry.stage("coord_score", coordinate=name):
                 new_scores = jax.block_until_ready(coord.score(w))
         # ``offsets`` already holds total − old scores; reusing it

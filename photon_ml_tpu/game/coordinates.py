@@ -47,7 +47,7 @@ from photon_ml_tpu import telemetry
 from photon_ml_tpu.telemetry import convergence as _conv
 from photon_ml_tpu.telemetry import device as _device
 from photon_ml_tpu.telemetry import monitor as _mon
-from photon_ml_tpu.data.batch import Batch, DenseBatch
+from photon_ml_tpu.data.batch import F32, Batch, DenseBatch
 from photon_ml_tpu.game.dataset import (
     EntityGrouping,
     GameDataset,
@@ -239,6 +239,13 @@ def _re_block_batch(blocks, b: int, offsets: Array) -> DenseBatch:
     )
 
 
+def _block_scores(x: Array, w: Array) -> Array:
+    """[E, cap] scores of a bucket's blocks [E, cap, p] under its
+    coefficients [E, p], in float32 whatever the width
+    (``data.batch.F32``)."""
+    return jnp.einsum("ecp,ep->ec", x, w, precision=F32)
+
+
 # The vmapped solve of a bucket compiles in time that grows faster than
 # its entity count (TPU compiler, described v5e: 9 s at 16,384 entities,
 # 52 s at 65,536, 148 s at 182,062, its temporaries padded to whole
@@ -251,6 +258,15 @@ _ONE_SHOT_ENTITIES = 131_072
 _CHUNK_ENTITIES = 16_384
 
 
+def _bucket_chunks(n: int) -> tuple[int, int]:
+    """(chunks, lanes a chunk) in which ``_solve_bucket`` solves a
+    bucket of ``n`` entities."""
+    if n <= _ONE_SHOT_ENTITIES:
+        return 1, n
+    n_chunks = -(-n // _CHUNK_ENTITIES)
+    return n_chunks, -(-n // n_chunks)
+
+
 def _solve_bucket(run, batch: DenseBatch, w0: Array):
     """``vmap(run)`` over a bucket's entities; a bucket over
     ``_ONE_SHOT_ENTITIES`` in equal chunks of at most
@@ -259,10 +275,9 @@ def _solve_bucket(run, batch: DenseBatch, w0: Array):
     so an entity's result does not depend on its neighbours; the lanes
     that pad the last chunk hold no example (mask 0) and are cut off."""
     n = w0.shape[0]
-    if n <= _ONE_SHOT_ENTITIES:
+    n_chunks, lanes = _bucket_chunks(n)
+    if n_chunks == 1:
         return jax.vmap(run)(batch, w0)
-    n_chunks = -(-n // _CHUNK_ENTITIES)
-    lanes = -(-n // n_chunks)
 
     def chunked(a):
         pad = [(0, n_chunks * lanes - n)] + [(0, 0)] * (a.ndim - 1)
@@ -308,7 +323,7 @@ def _re_chunk_train_impl(optimizer, config, has_l1, objective, x, labels,
     # Scores and per-entity movement come out of the SAME dispatch: the
     # chunk is already in device memory, so the CD sweep never pays a
     # second scoring pass over the store.
-    scores = jnp.einsum("ecp,ep->ec", x, res.w)
+    scores = _block_scores(x, res.w)
     dw = jnp.max(jnp.abs(res.w - w0), axis=-1)
     return res.w, scores, dw, res.converged, res.iterations
 
@@ -318,7 +333,7 @@ _re_chunk_train = jax.jit(_re_chunk_train_impl, static_argnums=(0, 1, 2))
 
 @jax.jit
 def _re_chunk_score(x, w):
-    return jnp.einsum("ecp,ep->ec", x, w)
+    return _block_scores(x, w)
 
 
 @jax.jit
@@ -371,7 +386,7 @@ def _re_score(n_examples: int, x_blocks, ex_idx, row_idx, col_idx,
               coefficient_blocks) -> Array:
     scores = jnp.zeros((n_examples,), jnp.float32)
     for b, w_b in enumerate(coefficient_blocks):
-        blk_scores = jnp.einsum("ecp,ep->ec", x_blocks[b], w_b)
+        blk_scores = _block_scores(x_blocks[b], w_b)
         scores = scores.at[ex_idx[b]].set(
             blk_scores[row_idx[b], col_idx[b]]
         )
@@ -412,6 +427,12 @@ class Coordinate:
     def score(self, coefficients) -> Array:
         """coefficients → per-example scores [n]."""
         raise NotImplementedError
+
+    def train_counts(self) -> dict:
+        """What the ``coord_train`` stage says of the shape of this
+        coordinate's solves, beside what the solver reports; nothing
+        by default."""
+        return {}
 
     def retire_converged(self) -> int | None:
         """Commit this sweep's converged-entity retirement candidates
@@ -751,6 +772,13 @@ class RandomEffectCoordinate(Coordinate):
             self._blocks(), offsets, w0s,
         )
         return [r.w for r in results], results
+
+    def train_counts(self) -> dict:
+        """``buckets``: the bucket programs a ``train`` runs;
+        ``chunks``: the ``_solve_bucket`` chunks they are solved in."""
+        return {"buckets": len(self.x_blocks),
+                "chunks": sum(_bucket_chunks(blk.shape[0])[0]
+                              for blk in self.x_blocks)}
 
     def score(self, coefficient_blocks: list[Array]) -> Array:
         """Block-space scoring: x·w per entity block, gathered back to
@@ -1432,18 +1460,23 @@ def build_random_effect_coordinate_sparse(
     values) rows in a wide global space; each entity's problem is solved
     in its observed-feature subspace (reference
     ``LinearSubspaceProjector`` path, SURVEY §2.4)."""
+    from photon_ml_tpu.data.sparse_rows import SparseRows
     from photon_ml_tpu.game.projector import build_subspace_projection
     from photon_ml_tpu.optim.base import OptimizerType
 
-    rows = dataset.features[feature_shard]
+    rows = SparseRows.from_rows(dataset.features[feature_shard])
     labels = dataset.labels.astype(np.float32)
     with telemetry.stage("group_entities", entity_key=name,
                          rows=len(labels)) as stage:
         grouping = group_by_entity(dataset.entity_ids[name],
                                    bucket_base=bucket_base)
-        projection, x_blocks = build_subspace_projection(
-            grouping, rows, global_dim
-        )
+        with telemetry.stage("re_project", entity_key=name,
+                             nnz=int(rows.nnz)) as project_stage:
+            projection, x_blocks = build_subspace_projection(
+                grouping, rows, global_dim
+            )
+            project_stage.set(**projection.counts(grouping),
+                              bytes=sum(b.nbytes for b in x_blocks))
         scalar_blocks = _scalar_blocks(grouping, labels,
                                        dataset.weight_array())
         index_maps = _index_maps(grouping)

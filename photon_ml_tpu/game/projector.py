@@ -42,6 +42,30 @@ class SubspaceProjection:
     def local_dim(self, bucket: int) -> int:
         return self.feature_ids[bucket].shape[1]
 
+    def counts(self, grouping: EntityGrouping) -> dict:
+        """What a ``re_project`` stage says of its result: the
+        entities' subspace widths summed (``subspace_columns``), the
+        elements of the per-entity design matrices, rows x width each
+        (``design_elements``), those of the dense blocks that hold
+        them, every entity of a bucket at the bucket's capacity and
+        widest subspace (``block_elements``), and that widest width of
+        all (``widest``)."""
+        subspace = design = block = 0
+        entity_at = grouping.entity_row_map()
+        for b, fids in enumerate(self.feature_ids):
+            rows = np.asarray(grouping.entity_counts, np.int64)[
+                entity_at[b, :len(fids)]]
+            width = (fids >= 0).sum(axis=1)
+            subspace += int(width.sum())
+            design += int((rows * width).sum())
+            block += fids.size * int(grouping.capacities[b])
+        return {"entities": int(grouping.n_total_entities),
+                "buckets": len(self.feature_ids),
+                "subspace_columns": subspace, "design_elements": design,
+                "block_elements": block,
+                "widest": max((f.shape[1] for f in self.feature_ids),
+                              default=0)}
+
     def project_back(self, bucket: int, w_local: np.ndarray) -> list[
             tuple[np.ndarray, np.ndarray]]:
         """[E_b, p_b] local coefficients → per-entity sparse global rows
@@ -83,56 +107,67 @@ def build_subspace_projection(
         ent_of = grouping.entity_row_map()
         ex_entity = ent_of[grouping.example_bucket, grouping.example_row]
 
-    # Distinct (entity, global feature) pairs, sorted — each entity's
-    # subspace is its run of distinct features; the run offset is the
-    # feature's LOCAL column.  All vectorized (SURVEY §7 ETL scale).
-    row_of = rows.row_of()
-    ent_nnz = np.asarray(ex_entity)[row_of]
-    order = np.lexsort((rows.cols, ent_nnz))
-    e_s = ent_nnz[order]
-    c_s = rows.cols[order].astype(np.int64)
-    nnz = len(e_s)
-    if nnz:
-        new_g = np.empty(nnz, bool)
-        new_g[0] = True
-        np.logical_or(e_s[1:] != e_s[:-1], c_s[1:] != c_s[:-1],
-                      out=new_g[1:])
-        gid_s = np.cumsum(new_g) - 1
-        starts = np.flatnonzero(new_g)
-        ge = e_s[starts]                    # entity of each distinct feat
-        gc = c_s[starts]                    # global col of each
-    else:
-        gid_s = np.zeros(0, np.int64)
-        ge = np.zeros(0, np.int64)
-        gc = np.zeros(0, np.int64)
-    ent_feat_count = np.bincount(ge, minlength=E)
-    ent_feat_start = np.zeros(E, np.int64)
-    np.cumsum(ent_feat_count[:-1], out=ent_feat_start[1:])
-    loc_of_group = np.arange(len(ge), dtype=np.int64) - ent_feat_start[ge]
-    # Local column of every stored entry, in original nnz order.
-    loc = np.empty(nnz, np.int64)
-    loc[order] = loc_of_group[gid_s]
+    # One sort of every stored entry by (bucket, slot, global feature):
+    # a bucket's entries are then one slice, an entity's distinct
+    # features one run whose offsets are the features' LOCAL columns,
+    # and the blocks fill front to back.  One int64 key and one argsort
+    # (rows are canonical, so no (row, feature) repeats and ties need
+    # no order).  All vectorized (SURVEY §7 ETL scale).
+    n_entities = np.asarray(grouping.n_entities, np.int64)
+    bucket_start = np.zeros(n_buckets + 1, np.int64)
+    np.cumsum(n_entities, out=bucket_start[1:])
+    # rank of an entity in (bucket, slot) order
+    ent_rank = (bucket_start[np.asarray(grouping.entity_bucket)]
+                + np.asarray(grouping.entity_slot))
+    width = max(int(global_dim), int(rows.cols.max()) + 1 if rows.nnz else 1)
+    if E * width >= 2 ** 63:
+        raise ValueError("entities x global_dim overflows the sort key")
+    per_row = np.diff(rows.indptr)
+    key = np.repeat(ent_rank[np.asarray(ex_entity)], per_row)
+    key *= width
+    key += rows.cols
+    order = np.argsort(key)
+    key = key[order]
+    new_g = np.empty(len(key), bool)
+    new_g[:1] = True
+    np.not_equal(key[1:], key[:-1], out=new_g[1:])
+    e_rank = key // width                  # per stored entry, sorted
+    starts = np.flatnonzero(new_g)         # per distinct (entity, feature)
+    g_rank = e_rank[starts]
+    g_col = (key[starts] - g_rank * width).astype(np.int32)
+    feat_count = np.bincount(g_rank, minlength=E)   # by rank
+    feat_start = np.zeros(E + 1, np.int64)
+    np.cumsum(feat_count, out=feat_start[1:])
+    e_loc = np.cumsum(new_g)               # the run's number, from 1
+    e_loc -= 1 + feat_start[e_rank]        # -> the feature's local column
+    g_loc = e_loc[starts]
+    entry_at = np.searchsorted(key, bucket_start * width)
+    group_at = feat_start[bucket_start]
+    del key, new_g
+    e_pos = np.repeat(np.asarray(grouping.example_col), per_row)[order]
+    e_val = rows.vals[order]
+    del order
 
     feature_ids = []
     x_blocks = []
-    ent_bucket = np.asarray(grouping.entity_bucket)
-    ent_slot = np.asarray(grouping.entity_slot)
     for b in range(n_buckets):
-        ne = grouping.n_entities[b]
-        members = ent_bucket == b
-        p = int(ent_feat_count[members].max()) if members.any() else 1
-        p = max(p, 1)
+        ne = int(n_entities[b])
+        lo, hi = bucket_start[b], bucket_start[b + 1]
+        p = max(int(feat_count[lo:hi].max()) if ne else 1, 1)
         fids = np.full((ne, p), -1, np.int32)
-        gsel = ent_bucket[ge] == b
-        fids[ent_slot[ge[gsel]], loc_of_group[gsel]] = gc[gsel]
+        g = slice(group_at[b], group_at[b + 1])
+        fids.reshape(-1)[(g_rank[g] - lo) * p + g_loc[g]] = g_col[g]
         feature_ids.append(fids)
 
         cap = grouping.capacities[b]
         xb = np.zeros((ne, cap, p), np.float32)
-        nsel = ent_bucket[ent_nnz] == b
-        ex = row_of[nsel]
-        xb[grouping.example_row[ex], grouping.example_col[ex],
-           loc[nsel]] = rows.vals[nsel]
+        s = slice(entry_at[b], entry_at[b + 1])
+        at = e_rank[s] - lo
+        at *= cap
+        at += e_pos[s]
+        at *= p
+        at += e_loc[s]
+        xb.reshape(-1)[at] = e_val[s]
         x_blocks.append(xb)
 
     return SubspaceProjection(feature_ids=feature_ids,

@@ -100,9 +100,9 @@ STAGES = (
     "grr_plan_ranges", "grr_tail_build", "grr_row_part", "grr_mid_split",
     "grr_col_build", "grr_overflow_level", "grr_routes", "plan_cache_save",
     "place_batch", "build_coordinates",
-    "group_entities", "place_re", "cd_initial_scores", "cd_coordinate",
-    "coord_train", "coord_score", "cd_validation", "export_model",
-    "validation", "transform", "score_coordinate",
+    "group_entities", "re_project", "place_re", "cd_initial_scores",
+    "cd_coordinate", "coord_train", "coord_score", "cd_validation",
+    "export_model", "validation", "transform", "score_coordinate",
 )
 _STAGE_SET = frozenset(STAGES)
 STAGE_PREFIX = "photon/"

@@ -69,6 +69,10 @@ TAIL_STAGE = "grr_tail_build"
 # direction and so inside that thread's chain stage and inside the level
 # above (ISSUE 33); the tiny fit spills too little.
 LEVEL_STAGE = "grr_overflow_level"
+# Only a random effect over a sparse shard runs this one, inside its
+# ``group_entities`` (ISSUE 35: tests/test_projected_fit.py has such a
+# fit); the tiny fit's random effects are dense.
+PROJECT_STAGE = "re_project"
 LEVEL_COUNTS = {"depth", "entries", "supertiles", "native", "kept"}
 CLASS_COUNTS = {"active_columns", "hot_columns", "planned_columns",
                 "planned_nnz", "tail_columns", "tail_nnz"}
@@ -100,7 +104,8 @@ COUNTS = {
 
 def test_stage_table_is_the_whole_of_stages():
     assert set(PARENT) | set(CACHE_STAGES) \
-        | {ROUTE_STAGE, TAIL_STAGE, LEVEL_STAGE} == set(telemetry.STAGES)
+        | {ROUTE_STAGE, TAIL_STAGE, LEVEL_STAGE, PROJECT_STAGE} \
+        == set(telemetry.STAGES)
     assert len(set(telemetry.STAGES)) == len(telemetry.STAGES)
 
 
@@ -210,6 +215,10 @@ def test_traced_fit_counts_say_what_was_done(traced, tiny):
     # contraction an iteration and one at the start, whatever the trials
     assert counts[trains[0]]["forward_passes"] == 31
     assert not any("forward_passes" in counts[e] for e in trains[1:])
+    # a random effect's says what shape its solves had (ISSUE 35)
+    assert "buckets" not in counts[trains[0]]
+    for e in trains[1:]:
+        assert counts[e]["chunks"] == counts[e]["buckets"] >= 2
     assert sorted(counts[e]["entity_key"]
                   for e in _events(traced, "group_entities")) \
         == ["itemId", "userId"]

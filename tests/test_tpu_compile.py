@@ -65,6 +65,11 @@ SHARD_MID_LEVELS = [(False, 120, 64, 15, 2, 64)]
 # one of ``game5-kdd12``'s, over the bound past which a bucket is solved
 # 16,384 entities at a time (9 s; in one shot 148 s).
 USER_BUCKETS = [(9260, 64), (182_062, 4)]
+# ``glmix-kdd12``'s per-user effect over a sparse shard, each entity in
+# the subspace of the columns it saw: its bucket of capacity 64, padded
+# to the bucket's widest subspace (entities, capacity, width): 1.85 GB,
+# solved in one shot (4.5 s to compile).
+PROJECTED_BUCKET = (18_515, 64, 391)
 
 
 @pytest.fixture(scope="module")
@@ -366,16 +371,19 @@ def test_fixed_effect_solve_compiles(one_chip, on_tpu):
     _assert_fits(compiled)
 
 
-@pytest.mark.parametrize("bucket", USER_BUCKETS, ids=str)
+@pytest.mark.parametrize("bucket", USER_BUCKETS + [PROJECTED_BUCKET],
+                         ids=str)
 def test_random_effect_bucket_solve_compiles(one_chip, bucket):
     """The vmapped per-entity solve over a per-user bucket
-    [E_b, cap_b, 2]."""
+    [E_b, cap_b, p]: p = 2 for the dense cells, the subspace's width
+    for the projected one, whose contractions reach the matrix unit and
+    must multiply in float32 there (no bfloat16 in the program)."""
     from photon_ml_tpu.game.coordinates import _re_train_donating
     from photon_ml_tpu.optim.base import OptimizerConfig, OptimizerType
 
     leaf = _abstract(one_chip)
-    p = 2
-    buckets = [bucket]
+    *bucket, p = bucket if len(bucket) == 3 else (*bucket, 2)
+    buckets = [tuple(bucket)]
     n_b = [min(e * c, N_TRAIN) for e, c in buckets]
     blocks = (
         [leaf((e, c, p)) for e, c in buckets],
@@ -392,6 +400,9 @@ def test_random_effect_bucket_solve_compiles(one_chip, bucket):
         leaf((N_TRAIN,)), [leaf((e, p)) for e, _ in buckets],
     ).compile()
     _assert_fits(compiled)
+    if p > 2:
+        assert "convolution" in compiled.as_text()
+        assert "bf16" not in compiled.as_text()
 
 
 def test_sharded_grr_step_compiles_on_four_chips(four_chips, on_tpu):
