@@ -33,11 +33,16 @@ class SubspaceProjection:
     """Per-bucket per-entity subspaces for one random-effect shard.
 
     ``feature_ids[b]`` is [E_b, p_b] int32: global feature id of each
-    local column (−1 padding).  ``local_dim[b]`` = p_b.
+    local column (−1 padding).  ``local_dim[b]`` = p_b.  ``native`` and
+    ``workers`` say who built it: 1 and the threads of the native
+    library's passes, or 0 and 1 for the numpy body and for a
+    projection read back from a saved model.
     """
 
     feature_ids: list[np.ndarray]
     global_dim: int
+    native: int = 0
+    workers: int = 1
 
     def local_dim(self, bucket: int) -> int:
         return self.feature_ids[bucket].shape[1]
@@ -64,7 +69,8 @@ class SubspaceProjection:
                 "subspace_columns": subspace, "design_elements": design,
                 "block_elements": block,
                 "widest": max((f.shape[1] for f in self.feature_ids),
-                              default=0)}
+                              default=0),
+                "native": self.native, "workers": self.workers}
 
     def project_back(self, bucket: int, w_local: np.ndarray) -> list[
             tuple[np.ndarray, np.ndarray]]:
@@ -93,37 +99,64 @@ def build_subspace_projection(
     Returns:
       (projection, x_blocks) where ``x_blocks[b]`` is a dense
       [E_b, cap_b, p_b] array of projected features.
+
+    The native library builds them where there is one, an entity at a
+    time on every core (``native.re_project_native``), and
+    ``_project_numpy`` where there is none: the same bytes either way,
+    and the projection says which (``native``, ``workers``).
     """
+    from photon_ml_tpu import native
     from photon_ml_tpu.data.sparse_rows import SparseRows
 
     rows = SparseRows.from_rows(rows)
-    n_buckets = len(grouping.capacities)
-    E = grouping.n_total_entities
-
     # Global entity index per example (stored by group_by_entity; rebuilt
     # from (bucket, slot) for groupings that predate the field).
     ex_entity = grouping.example_entity
     if ex_entity is None:
         ent_of = grouping.entity_row_map()
         ex_entity = ent_of[grouping.example_bucket, grouping.example_row]
-
-    # One sort of every stored entry by (bucket, slot, global feature):
-    # a bucket's entries are then one slice, an entity's distinct
-    # features one run whose offsets are the features' LOCAL columns,
-    # and the blocks fill front to back.  One int64 key and one argsort
-    # (rows are canonical, so no (row, feature) repeats and ties need
-    # no order).  All vectorized (SURVEY §7 ETL scale).
-    n_entities = np.asarray(grouping.n_entities, np.int64)
-    bucket_start = np.zeros(n_buckets + 1, np.int64)
-    np.cumsum(n_entities, out=bucket_start[1:])
-    # rank of an entity in (bucket, slot) order
+    bucket_start = np.zeros(len(grouping.capacities) + 1, np.int64)
+    np.cumsum(np.asarray(grouping.n_entities, np.int64),
+              out=bucket_start[1:])
+    # rank of an entity in (bucket, slot) order, and of each example's
     ent_rank = (bucket_start[np.asarray(grouping.entity_bucket)]
                 + np.asarray(grouping.entity_slot))
+    ex_rank = ent_rank[np.asarray(ex_entity)]
+
+    built = native.re_project_native(
+        rows.indptr, rows.cols, rows.vals, ex_rank, grouping.example_col,
+        bucket_start, grouping.capacities)
+    if built is not None:
+        feature_ids, x_blocks, workers = built
+    else:
+        workers = 1
+        feature_ids, x_blocks = _project_numpy(
+            rows, ex_rank, grouping.example_col, bucket_start,
+            grouping.capacities, global_dim)
+    return SubspaceProjection(
+        feature_ids=feature_ids, global_dim=global_dim,
+        native=int(built is not None), workers=workers), x_blocks
+
+
+def _project_numpy(rows, ex_rank, ex_pos, bucket_start, capacities,
+                   global_dim) -> tuple[list[np.ndarray], list[np.ndarray]]:
+    """``native.re_project_native``'s ``feature_ids`` and ``x_blocks``
+    in numpy on one thread: the fallback, and the reference the native
+    builder is held to byte for byte (tests/test_native.py).
+
+    One sort of every stored entry by (entity rank, global feature): a
+    bucket's entries are then one slice, an entity's distinct features
+    one run whose offsets are the features' LOCAL columns, and the
+    blocks fill front to back.  One int64 key and one argsort (rows are
+    canonical, so no (row, feature) repeats and ties need no order).
+    All vectorized (SURVEY §7 ETL scale)."""
+    n_buckets = len(capacities)
+    E = int(bucket_start[-1])
     width = max(int(global_dim), int(rows.cols.max()) + 1 if rows.nnz else 1)
     if E * width >= 2 ** 63:
         raise ValueError("entities x global_dim overflows the sort key")
     per_row = np.diff(rows.indptr)
-    key = np.repeat(ent_rank[np.asarray(ex_entity)], per_row)
+    key = np.repeat(ex_rank, per_row)
     key *= width
     key += rows.cols
     order = np.argsort(key)
@@ -144,22 +177,22 @@ def build_subspace_projection(
     entry_at = np.searchsorted(key, bucket_start * width)
     group_at = feat_start[bucket_start]
     del key, new_g
-    e_pos = np.repeat(np.asarray(grouping.example_col), per_row)[order]
+    e_pos = np.repeat(np.asarray(ex_pos), per_row)[order]
     e_val = rows.vals[order]
     del order
 
     feature_ids = []
     x_blocks = []
     for b in range(n_buckets):
-        ne = int(n_entities[b])
         lo, hi = bucket_start[b], bucket_start[b + 1]
+        ne = int(hi - lo)
         p = max(int(feat_count[lo:hi].max()) if ne else 1, 1)
         fids = np.full((ne, p), -1, np.int32)
         g = slice(group_at[b], group_at[b + 1])
         fids.reshape(-1)[(g_rank[g] - lo) * p + g_loc[g]] = g_col[g]
         feature_ids.append(fids)
 
-        cap = grouping.capacities[b]
+        cap = capacities[b]
         xb = np.zeros((ne, cap, p), np.float32)
         s = slice(entry_at[b], entry_at[b + 1])
         at = e_rank[s] - lo
@@ -169,6 +202,4 @@ def build_subspace_projection(
         at += e_loc[s]
         xb.reshape(-1)[at] = e_val[s]
         x_blocks.append(xb)
-
-    return SubspaceProjection(feature_ids=feature_ids,
-                              global_dim=global_dim), x_blocks
+    return feature_ids, x_blocks

@@ -3,11 +3,13 @@
 Reference counterpart: the JVM data plane (Spark executors deserializing
 Avro, shuffling, building per-partition iterables — SURVEY.md §5.8).
 The rebuild's data plane is host-side array construction; the hot parts
-(LIBSVM text parsing, the transposed-ELL counting sort) live in
+(LIBSVM text parsing, the transposed-ELL counting sort, the GRR plans
+and their routes, the random effects' subspace projection) live in
 ``fast_etl.cpp`` and are bound here.
 
 Build model: ``g++ -O3 -shared -fPIC -pthread`` on first use (seconds,
-once; the GRR router runs its supertiles on ``std::thread``s) into
+once; the GRR router and the projection run their blocks on
+``std::thread``s) into
 a .so next to the source whose name carries a hash of the source and the
 build command, so "is the binary stale" is decided from content, not
 from mtimes a copy does not keep.  The build is portable (no
@@ -165,6 +167,22 @@ def lib() -> "ctypes.CDLL | None":
         dll.pml_grr_plan_fill.argtypes = [ctypes.c_void_p] + [
             ctypes.c_void_p] * 9
         dll.pml_grr_plan_free.argtypes = [ctypes.c_void_p]
+        dll.pml_re_project_widths.restype = ctypes.c_int32
+        dll.pml_re_project_widths.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
+        ]
+        dll.pml_re_project_fill.restype = ctypes.c_int32
+        dll.pml_re_project_fill.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int32,
+        ]
         _lib = dll
         return dll
 
@@ -466,3 +484,97 @@ def grr_plan_native_coo(
         int(n_segments), int(cap),
         -1 if max_supertiles is None else int(max_supertiles),
     ))
+
+
+# Entries a projection block holds, about: an entity is never cut, so
+# a block runs over by its last entity's.  Small enough that the cell's
+# 28.7 million entries are 440 blocks for a dozen cores, large enough
+# that a block's counter step is nothing.  Not a setting: what adapts
+# is the number of blocks.
+_PROJECT_BLOCK = 1 << 16
+
+
+def re_project_native(
+    indptr: np.ndarray,
+    cols: np.ndarray,
+    vals: np.ndarray,
+    ex_rank: np.ndarray,
+    ex_pos: np.ndarray,
+    bucket_start: np.ndarray,
+    capacities,
+):
+    """Per-entity subspaces and their dense blocks from a shard's CSR →
+    ``(feature_ids, x_blocks, workers)``, or None when the native
+    library is unavailable or out of memory (numpy body in
+    ``game.projector``, whose bytes these are).
+
+    ``ex_rank``: each example's entity as its rank in (bucket, slot)
+    order; ``ex_pos``: the example's row within its entity;
+    ``bucket_start`` [n_buckets + 1]: the buckets' first ranks;
+    ``capacities``: rows an entity of each bucket holds.  Bucket ``b``
+    comes back as ``feature_ids[b]`` int32 [entities, p_b] (an entity's
+    distinct columns ascending, −1 padded; p_b the bucket's widest
+    subspace, at least 1) and ``x_blocks[b]`` float32 [entities,
+    capacity, p_b].
+
+    Entities share nothing, so from two blocks of ``_PROJECT_BLOCK``
+    entries on both passes run on every core the process may use
+    (``workers``; native threads that live for a call and write
+    disjoint rows and slabs in place: the bytes are those of one serial
+    call), and the blocks' zero pages are first touched there.  A
+    smaller call runs inline (``workers`` 1)."""
+    dll = lib()
+    if dll is None:
+        return None
+    indptr = np.ascontiguousarray(indptr, np.int64)
+    cols = _int32_ids(cols, "column")
+    vals = np.ascontiguousarray(vals, np.float32)
+    ex_rank = np.ascontiguousarray(ex_rank, np.int64)
+    ex_pos = np.ascontiguousarray(ex_pos, np.int64)
+    bucket_start = np.ascontiguousarray(bucket_start, np.int64)
+    capacity = np.ascontiguousarray(capacities, np.int64)
+    n = len(indptr) - 1
+    n_buckets = len(capacity)
+    n_entities = int(bucket_start[-1])
+    if not (len(ex_rank) == len(ex_pos) == n and len(cols) == len(vals)
+            and len(bucket_start) == n_buckets + 1):
+        raise ValueError("re_project_native: array lengths disagree")
+    workers = min(_usable_cores(),
+                  max(1, -(-int(indptr[-1]) // _PROJECT_BLOCK)))
+    ex_start = np.empty(n_entities + 1, np.int64)
+    ex_order = np.empty(n, np.int64)
+    ent_cum = np.empty(n_entities + 1, np.int64)
+    width = np.empty(n_entities, np.int32)
+    rc = dll.pml_re_project_widths(
+        _ptr(indptr), _ptr(cols), n, len(cols), _ptr(ex_rank), n_entities,
+        _ptr(ex_start), _ptr(ex_order), _ptr(ent_cum), _ptr(width),
+        _PROJECT_BLOCK, workers)
+    if rc == -2:
+        return None
+    if rc != 0:
+        raise ValueError("re_project_native: indptr is not the CSR of its "
+                         "entries, or an entity rank is out of range")
+    p = np.ones(n_buckets, np.int64)
+    feature_ids, x_blocks = [], []
+    for b in range(n_buckets):
+        lo, hi = int(bucket_start[b]), int(bucket_start[b + 1])
+        if hi > lo:
+            p[b] = max(int(width[lo:hi].max()), 1)
+        feature_ids.append(np.full((hi - lo, p[b]), -1, np.int32))
+        # zero pages nobody has touched: the fill's threads do
+        x_blocks.append(np.zeros((hi - lo, int(capacity[b]), p[b]),
+                                 np.float32))
+    pointers = ctypes.c_void_p * n_buckets
+    rc = dll.pml_re_project_fill(
+        _ptr(indptr), _ptr(cols), _ptr(vals), _ptr(ex_pos), n_entities,
+        _ptr(ex_start), _ptr(ex_order), _ptr(ent_cum), n_buckets,
+        _ptr(bucket_start), _ptr(capacity), _ptr(p),
+        pointers(*(a.ctypes.data for a in feature_ids)),
+        pointers(*(a.ctypes.data for a in x_blocks)),
+        _PROJECT_BLOCK, workers)
+    if rc == -2:
+        return None
+    if rc != 0:
+        raise ValueError("re_project_native: an example's row is outside "
+                         "its entity's capacity")
+    return feature_ids, x_blocks, workers

@@ -19,6 +19,8 @@
 //   LIBSVM text  -> CSR-ish (row_ptr, cols, vals, labels)
 //   row-ELL      -> transposed-ELL (the colmajor build: counting sort
 //                   by column + virtual-row splitting; O(nnz + dim))
+//   CSR + entity grouping -> per-entity subspaces and their dense
+//                   blocks (the projection of game/projector.py)
 
 #include <algorithm>
 #include <atomic>
@@ -274,6 +276,37 @@ void euler_split_level(const int32_t* src, const int32_t* dst,
   }
 }
 
+// body(k) for every k in [0, n_blocks), on `n_threads` threads at most
+// (the caller's included): each takes the next block off a shared
+// counter, so blocks that write disjoint memory leave the bytes of one
+// serial call.  The
+// threads live for this call only (nothing to carry across a fork) and
+// never touch Python; a call of one block runs inline.  Returns 0, or
+// the first non-zero a body returned (no further block is then begun).
+template <class Body>
+int32_t for_each_block(int64_t n_blocks, int32_t n_threads, Body body) {
+  std::atomic<int64_t> next{0};
+  std::atomic<int32_t> rc{0};
+  auto work = [&] {
+    while (rc.load() == 0) {
+      const int64_t k = next.fetch_add(1);
+      if (k >= n_blocks) break;
+      const int32_t got = body(k);
+      if (got != 0) rc.store(got);
+    }
+  };
+  std::vector<std::thread> others;
+  const int64_t n_others = std::min<int64_t>(n_threads, n_blocks) - 1;
+  try {
+    for (int64_t i = 0; i < n_others; ++i) others.emplace_back(work);
+  } catch (const std::system_error&) {
+    // The process may start no more threads: work on those it got.
+  }
+  work();
+  for (auto& th : others) th.join();
+  return rc;
+}
+
 }  // namespace
 
 extern "C" {
@@ -383,41 +416,23 @@ int32_t pml_grr_routes(const int32_t* dst, const int8_t* hi, int64_t n_st,
 }
 
 // pml_grr_routes over contiguous blocks of `block` supertiles on
-// `n_threads` threads (the caller's included): each thread takes the
-// next unrouted block off a shared counter and routes it in place, on
-// the pointer offsets of its slice, so the outputs are the bytes one
-// serial call writes.  The threads live for this call only (nothing
-// to carry across a fork) and never touch Python.  Returns 0, or -1 as
-// soon as any block does; the outputs are then unspecified.
+// `n_threads` threads (for_each_block): each routes its block in
+// place, on the pointer offsets of its slice, so the outputs are the
+// bytes one serial call writes.  Returns 0, or -1 as soon as any block
+// does; the outputs are then unspecified.
 int32_t pml_grr_routes_blocks(const int32_t* dst, const int8_t* hi,
                               int64_t n_st, int8_t* g1, int8_t* g2,
                               int8_t* g3, int64_t block,
                               int32_t n_threads) {
   constexpr int64_t S = 128 * 128;
   if (block < 1) return -1;
-  const int64_t n_blocks = (n_st + block - 1) / block;
-  std::atomic<int64_t> next{0};
-  std::atomic<int32_t> rc{0};
-  auto work = [&] {
-    while (rc.load() == 0) {
-      const int64_t t0 = next.fetch_add(1) * block;
-      if (t0 >= n_st) break;
-      if (pml_grr_routes(dst + t0 * S, hi + t0 * S,
-                         std::min(block, n_st - t0), g1 + t0 * S,
-                         g2 + t0 * S, g3 + t0 * S) != 0)
-        rc.store(-1);
-    }
-  };
-  std::vector<std::thread> others;
-  const int64_t n_others = std::min<int64_t>(n_threads, n_blocks) - 1;
-  try {
-    for (int64_t i = 0; i < n_others; ++i) others.emplace_back(work);
-  } catch (const std::system_error&) {
-    // The process may start no more threads: route on those it got.
-  }
-  work();
-  for (auto& th : others) th.join();
-  return rc;
+  return for_each_block(
+      (n_st + block - 1) / block, n_threads, [&](int64_t k) {
+        const int64_t t0 = k * block;
+        return pml_grr_routes(dst + t0 * S, hi + t0 * S,
+                              std::min(block, n_st - t0), g1 + t0 * S,
+                              g2 + t0 * S, g3 + t0 * S);
+      });
 }
 
 }  // extern "C"
@@ -882,5 +897,182 @@ void pml_grr_plan_fill(void* handle, int8_t* hi, float* vals, int32_t* dst,
 }
 
 void pml_grr_plan_free(void* handle) { delete static_cast<GrrPlan*>(handle); }
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// Per-entity subspace projection (game/projector.py): a random effect's
+// sparse shard into dense per-bucket blocks, each entity in the
+// subspace of the columns it saw.
+// ---------------------------------------------------------------------------
+//
+// Entities are numbered by RANK, their place in (bucket, slot) order,
+// and share nothing: an entity's subspace is the distinct columns of
+// its examples' entries, ascending, and a column's local index is its
+// place there -- what one sort of every entry by (rank, column) yields
+// in the numpy body, here a sort of one entity's entries at a time.
+// The outputs are that body's bytes (tests/test_native.py).
+//
+// Two calls, because a bucket's blocks are as wide as its widest
+// subspace: pml_re_project_widths lists every entity's examples and
+// counts its distinct columns; the caller allocates feature_ids[b]
+// (-1) and x_blocks[b] (zeros) for every bucket; pml_re_project_fill
+// writes each entity's row and slab in place.  Both share the entities
+// out in blocks of about `block` entries (re_block_ranks) over
+// for_each_block's threads, the widest entities first, so the first
+// touches of the blocks' pages happen on every core.
+
+namespace {
+
+// The ranks [r0, r1) of the k-th block taken, of n_blocks: counted
+// from the last rank down (the highest capacities, the widest slabs,
+// first), block j = n_blocks - 1 - k holds the ranks whose first entry,
+// in rank order, is one of entries [j * block, (j + 1) * block): an
+// entity is never cut, ent_cum[r] counts the entries of the ranks
+// before r, and the last block takes the empty entities after it.
+inline void re_block_ranks(const int64_t* ent_cum, int64_t n_entities,
+                           int64_t n_blocks, int64_t block, int64_t k,
+                           int64_t* r0, int64_t* r1) {
+  k = n_blocks - 1 - k;
+  const int64_t* end = ent_cum + n_entities;
+  *r0 = std::lower_bound(ent_cum, end, k * block) - ent_cum;
+  *r1 = k + 1 == n_blocks
+            ? n_entities
+            : std::lower_bound(ent_cum, end, (k + 1) * block) - ent_cum;
+}
+
+inline int64_t re_n_blocks(const int64_t* ent_cum, int64_t n_entities,
+                           int64_t block) {
+  return std::max<int64_t>(1, (ent_cum[n_entities] + block - 1) / block);
+}
+
+// The distinct columns of examples ex_order[e0:e1], ascending.
+inline void re_entity_columns(const int64_t* indptr, const int32_t* cols,
+                              const int64_t* ex_order, int64_t e0,
+                              int64_t e1, std::vector<int32_t>* out) {
+  out->clear();
+  for (int64_t j = e0; j < e1; ++j) {
+    const int64_t i = ex_order[j];
+    out->insert(out->end(), cols + indptr[i], cols + indptr[i + 1]);
+  }
+  std::sort(out->begin(), out->end());
+  out->erase(std::unique(out->begin(), out->end()), out->end());
+}
+
+}  // namespace
+
+extern "C" {
+
+// First call.  indptr [n + 1] and cols [nnz]: the shard's CSR;
+// ex_rank [n]: each example's entity.  Writes ex_start [n_entities + 1]
+// and ex_order [n] (rank r's examples are ex_order[ex_start[r] :
+// ex_start[r + 1]], in example order), ent_cum [n_entities + 1] and
+// width [n_entities], the size of each entity's subspace.  Returns 0;
+// -1: indptr is no CSR of nnz entries or a rank is out of range; -2:
+// out of memory (the caller's numpy body decides).
+int32_t pml_re_project_widths(const int64_t* indptr, const int32_t* cols,
+                              int64_t n, int64_t nnz, const int64_t* ex_rank,
+                              int64_t n_entities, int64_t* ex_start,
+                              int64_t* ex_order, int64_t* ent_cum,
+                              int32_t* width, int64_t block,
+                              int32_t n_threads) {
+  if (n < 0 || n_entities < 0 || block < 1 || indptr[0] < 0 ||
+      indptr[n] > nnz)
+    return -1;
+  std::fill(ex_start, ex_start + n_entities + 1, 0);
+  std::fill(ent_cum, ent_cum + n_entities + 1, 0);
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t r = ex_rank[i];
+    if (r < 0 || r >= n_entities || indptr[i + 1] < indptr[i]) return -1;
+    ++ex_start[r + 1];
+    ent_cum[r + 1] += indptr[i + 1] - indptr[i];
+  }
+  for (int64_t r = 0; r < n_entities; ++r) {
+    ex_start[r + 1] += ex_start[r];
+    ent_cum[r + 1] += ent_cum[r];
+  }
+  // A counting sort by rank: ex_start[r] is the cursor of rank r while
+  // the examples are dealt out, and is put back afterwards.
+  for (int64_t i = 0; i < n; ++i) ex_order[ex_start[ex_rank[i]]++] = i;
+  for (int64_t r = n_entities; r > 0; --r) ex_start[r] = ex_start[r - 1];
+  ex_start[0] = 0;
+
+  const int64_t n_blocks = re_n_blocks(ent_cum, n_entities, block);
+  return for_each_block(n_blocks, n_threads, [&](int64_t k) {
+    try {
+      int64_t r0, r1;
+      re_block_ranks(ent_cum, n_entities, n_blocks, block, k, &r0, &r1);
+      std::vector<int32_t> seen;
+      for (int64_t r = r0; r < r1; ++r) {
+        re_entity_columns(indptr, cols, ex_order, ex_start[r],
+                          ex_start[r + 1], &seen);
+        width[r] = static_cast<int32_t>(seen.size());
+      }
+    } catch (const std::bad_alloc&) {
+      return -2;
+    }
+    return 0;
+  });
+}
+
+// Second call, with the first call's ex_start, ex_order and ent_cum.
+// ex_pos [n]: an example's row in its entity's slab; bucket b holds
+// the ranks [bucket_start[b], bucket_start[b + 1]) at capacity[b] rows
+// and p[b] columns an entity; feature_ids[b]: int32 [entities, p[b]],
+// all -1; x_blocks[b]: float32 [entities, capacity[b], p[b]], all 0.
+// Returns 0; -1: a subspace wider than its bucket's p or a row outside
+// its capacity (the outputs are then unspecified); -2: out of memory.
+int32_t pml_re_project_fill(const int64_t* indptr, const int32_t* cols,
+                            const float* vals, const int64_t* ex_pos,
+                            int64_t n_entities, const int64_t* ex_start,
+                            const int64_t* ex_order, const int64_t* ent_cum,
+                            int64_t n_buckets, const int64_t* bucket_start,
+                            const int64_t* capacity, const int64_t* p,
+                            int32_t* const* feature_ids,
+                            float* const* x_blocks, int64_t block,
+                            int32_t n_threads) {
+  if (n_entities < 0 || n_buckets < 0 || block < 1) return -1;
+  const int64_t n_blocks = re_n_blocks(ent_cum, n_entities, block);
+  return for_each_block(n_blocks, n_threads, [&](int64_t k) {
+    try {
+      int64_t r0, r1;
+      re_block_ranks(ent_cum, n_entities, n_blocks, block, k, &r0, &r1);
+      std::vector<int32_t> seen;
+      int64_t b = std::upper_bound(bucket_start, bucket_start + n_buckets + 1,
+                                   r0) - bucket_start - 1;
+      for (int64_t r = r0; r < r1; ++r) {
+        if (ent_cum[r + 1] == ent_cum[r]) continue;  // no entry: -1s, zeros
+        while (b < n_buckets && r >= bucket_start[b + 1]) ++b;
+        if (b < 0 || b >= n_buckets) return -1;
+        re_entity_columns(indptr, cols, ex_order, ex_start[r],
+                          ex_start[r + 1], &seen);
+        const int64_t width = static_cast<int64_t>(seen.size());
+        if (width > p[b]) return -1;
+        const int64_t slot = r - bucket_start[b];
+        std::memcpy(feature_ids[b] + slot * p[b], seen.data(),
+                    seen.size() * sizeof(int32_t));
+        float* slab = x_blocks[b] + slot * capacity[b] * p[b];
+        for (int64_t j = ex_start[r]; j < ex_start[r + 1]; ++j) {
+          const int64_t i = ex_order[j];
+          if (ex_pos[i] < 0 || ex_pos[i] >= capacity[b]) return -1;
+          float* row = slab + ex_pos[i] * p[b];
+          // A canonical row's columns ascend, so each is found from
+          // the one before; any other row searches from the start.
+          auto from = seen.begin();
+          int64_t before = -1;
+          for (int64_t e = indptr[i]; e < indptr[i + 1]; ++e) {
+            if (cols[e] <= before) from = seen.begin();
+            from = std::lower_bound(from, seen.end(), cols[e]);
+            row[from - seen.begin()] = vals[e];
+            before = cols[e];
+          }
+        }
+      }
+    } catch (const std::bad_alloc&) {
+      return -2;
+    }
+    return 0;
+  });
+}
 
 }  // extern "C"

@@ -40,6 +40,23 @@ def rng():
 
 
 @pytest.fixture
+def sha256_of():
+    """``sha256_of(leaves)``: one digest over the dtype, shape and bytes
+    of every array, in order: how a test holds two builders to the same
+    bytes."""
+    import hashlib
+
+    def digest(leaves):
+        h = hashlib.sha256()
+        for leaf in leaves:
+            h.update(repr((leaf.dtype.str, leaf.shape)).encode())
+            h.update(np.ascontiguousarray(leaf).tobytes())
+        return h.hexdigest()
+
+    return digest
+
+
+@pytest.fixture
 def spans_of(tmp_path):
     """``spans_of(fn, *args, **kwargs)`` calls ``fn`` under a telemetry
     session of its own in trace mode and returns (its result, the

@@ -219,3 +219,193 @@ def test_grr_plan_coo_callers_at_once_all_return_the_serial_bytes(rng):
         t.join(timeout=120)
     assert not any(t.is_alive() for t in threads)
     assert got == [want] * len(got)
+
+
+# -- the subspace projection through the native library (ISSUE 36) --------------
+
+def _shard(row_cols, rng):
+    """``SparseRows`` of the given rows' columns with random values."""
+    from photon_ml_tpu.data.sparse_rows import SparseRows
+
+    indptr = np.zeros(len(row_cols) + 1, np.int64)
+    np.cumsum([len(c) for c in row_cols], out=indptr[1:])
+    cols = (np.concatenate([np.asarray(c, np.int32) for c in row_cols])
+            if indptr[-1] else np.zeros(0, np.int32))
+    return SparseRows(indptr=indptr, cols=cols.astype(np.int32),
+                      vals=rng.normal(size=len(cols)).astype(np.float32))
+
+
+def _power_law(rng, n, users, k, dim):
+    """Entity ids and a shard of ``k`` ascending columns a row, both
+    power-law: one entity in the widest bucket, thousands in the first."""
+    user = (users * rng.random(n) ** 3.0).astype(np.int64) * 3 + 1
+    cols = np.sort((dim * rng.random((n, k)) ** 2.2).astype(np.int64), axis=1)
+    cols += np.arange(k) * dim              # ascending and distinct in a row
+    return user, _shard(list(cols), rng), k * dim
+
+
+def _project_case(case, rng):
+    """(entity id per example, shard, global_dim, whether the grouping
+    keeps ``example_entity``) of one case."""
+    kept = True
+    if case == "one_entity_in_the_last_of_several_buckets":
+        user = np.concatenate([np.repeat(np.arange(40), 2),
+                               np.repeat(np.arange(40, 50), 9),
+                               np.full(70, 99)])
+        rng.shuffle(user)
+        rows = [np.unique(rng.integers(0, 50, rng.integers(1, 6)))
+                for _ in user]
+        dim = 50
+    elif case == "an_entity_with_one_feature":
+        user = np.array([4, 4, 4, 7, 7, 4, 9])
+        rows = [[3], [3], [3], [0, 5], [5, 6], [3], [1, 2, 3]]
+        dim = 8
+    elif case == "entities_sharing_every_column":
+        user = rng.integers(0, 9, 60)
+        rows = [[2, 5, 11]] * 60
+        dim = 12
+    elif case == "a_column_at_global_dim_less_one":
+        user = rng.integers(0, 6, 40)
+        rows = [np.unique(np.append(rng.integers(0, 999, 2), 999))
+                for _ in user]
+        dim = 1000
+    elif case == "rows_with_no_entries":
+        user = np.array([1, 1, 2, 3, 3, 3, 3, 3, 5, 5])   # 2 and 5: no entry
+        rows = [[0, 4], [], [], [1], [], [4, 6], [], [0], [], []]
+        dim = 7
+    elif case == "an_empty_shard":
+        user = rng.integers(0, 5, 30)
+        rows = [[]] * 30
+        dim = 10
+    elif case == "a_grouping_without_example_entity":
+        user = (20 * rng.random(300) ** 2.5).astype(np.int64)
+        rows = [np.unique(rng.integers(0, 40, 4)) for _ in user]
+        dim, kept = 40, False
+    elif case == "a_power_law_shard":
+        user, shard, dim = _power_law(rng, 3000, 400, 5, 300)
+        return user, shard, dim, kept
+    else:
+        raise AssertionError(case)
+    return np.asarray(user), _shard(rows, rng), dim, kept
+
+
+PROJECT_CASES = [
+    "one_entity_in_the_last_of_several_buckets",
+    "an_entity_with_one_feature", "entities_sharing_every_column",
+    "a_column_at_global_dim_less_one", "rows_with_no_entries",
+    "an_empty_shard", "a_grouping_without_example_entity",
+    "a_power_law_shard"]
+
+
+def _both_builders(monkeypatch, user, shard, dim, kept=True):
+    """(the native builder's projection and blocks, the numpy body's)
+    of one grouping."""
+    import dataclasses
+
+    import photon_ml_tpu.native as nat
+    from photon_ml_tpu.game.dataset import group_by_entity
+    from photon_ml_tpu.game.projector import build_subspace_projection
+
+    grouping = group_by_entity(user, bucket_base=4)
+    if not kept:
+        grouping = dataclasses.replace(grouping, example_entity=None)
+    native = build_subspace_projection(grouping, shard, dim)
+    with monkeypatch.context() as patch:
+        patch.setattr(nat, "lib", lambda: None)
+        numpy = build_subspace_projection(grouping, shard, dim)
+    assert (native[0].native, numpy[0].native, numpy[0].workers) == (1, 0, 1)
+    return native, numpy
+
+
+@pytest.fixture
+def assert_the_same_bytes(sha256_of):
+    """``feature_ids`` and ``x_blocks`` of two builds: equal leaf by
+    leaf, in dtype and shape, and by one sha256 over all of them."""
+    def check(got, want):
+        got_leaves = got[0].feature_ids + got[1]
+        want_leaves = want[0].feature_ids + want[1]
+        assert len(got_leaves) == len(want_leaves)
+        for a, b in zip(got_leaves, want_leaves):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(a, b)
+        assert sha256_of(got_leaves) == sha256_of(want_leaves)
+    return check
+
+
+@pytest.mark.parametrize("blocks", ["inline", "threaded"])
+@pytest.mark.parametrize("case", PROJECT_CASES)
+def test_native_projection_is_the_numpy_bodys_bytes(
+        rng, monkeypatch, assert_the_same_bytes, case, blocks):
+    """``feature_ids`` and ``x_blocks`` of the native builder against
+    the numpy body's, leaf by leaf and by one sha256 over all of them:
+    in one block on the calling thread, and cut into blocks of about
+    eight entries on five threads."""
+    import photon_ml_tpu.native as nat
+
+    user, shard, dim, kept = _project_case(case, rng)
+    if blocks == "threaded":
+        monkeypatch.setattr(nat, "_PROJECT_BLOCK", 8)
+        monkeypatch.setattr(nat, "_usable_cores", lambda: 5)
+    native, numpy = _both_builders(monkeypatch, user, shard, dim, kept)
+    assert_the_same_bytes(native, numpy)
+    many = blocks == "threaded" and shard.nnz > 8
+    assert native[0].workers == (min(5, -(-shard.nnz // 8)) if many else 1)
+    if case == "one_entity_in_the_last_of_several_buckets":
+        assert [len(f) for f in native[0].feature_ids] == [40, 10, 1]
+    if case == "an_empty_shard":
+        assert all(f.shape[1] == 1 and (f == -1).all()
+                   for f in native[0].feature_ids)
+        assert not any(b.any() for b in native[1])
+
+
+def test_native_projection_on_one_worker_is_the_bytes_of_many(
+        rng, monkeypatch, assert_the_same_bytes):
+    """The same blocks of entities taken by one thread, in order, and by
+    seven at once: one serial call's bytes."""
+    import photon_ml_tpu.native as nat
+
+    user, shard, dim = _power_law(rng, 3000, 400, 5, 300)
+    monkeypatch.setattr(nat, "_PROJECT_BLOCK", 64)
+    built = {}
+    for workers in (1, 7):
+        monkeypatch.setattr(nat, "_usable_cores", lambda: workers)
+        built[workers], numpy = _both_builders(monkeypatch, user, shard, dim)
+        assert built[workers][0].workers == workers
+    assert_the_same_bytes(built[1], built[7])
+    assert_the_same_bytes(built[1], numpy)
+
+
+def test_native_projection_takes_its_threads_at_the_blocks_own_size(
+        rng, monkeypatch, assert_the_same_bytes):
+    """A power-law shard of four blocks of ``_PROJECT_BLOCK`` entries:
+    the threaded branch as a fit takes it, nothing patched but the
+    count of cores."""
+    import photon_ml_tpu.native as nat
+
+    n = 4 * nat._PROJECT_BLOCK // 5 - 100
+    user, shard, dim = _power_law(rng, n, n // 8, 5, 2000)
+    monkeypatch.setattr(nat, "_usable_cores", lambda: 6)
+    native, numpy = _both_builders(monkeypatch, user, shard, dim)
+    assert native[0].workers == 4
+    assert len(native[1]) >= 4 and len(native[0].feature_ids[-1]) == 1
+    assert_the_same_bytes(native, numpy)
+
+
+def test_native_projection_refuses_what_would_write_outside_a_block(rng):
+    """A rank past the last entity, a row past its entity's capacity
+    and an ``indptr`` that is no CSR of the entries raise; none writes."""
+    from photon_ml_tpu.native import re_project_native
+
+    shard = _shard([[0, 1], [1], [2]], rng)
+    good = dict(indptr=shard.indptr, cols=shard.cols, vals=shard.vals,
+                ex_rank=np.array([0, 0, 1]), ex_pos=np.array([0, 1, 0]),
+                bucket_start=np.array([0, 2]), capacities=[4])
+    feature_ids, x_blocks, workers = re_project_native(**good)
+    assert feature_ids[0].tolist() == [[0, 1], [2, -1]] and workers == 1
+    assert x_blocks[0].shape == (2, 4, 2)
+    for bad in (dict(ex_rank=np.array([0, 2, 1])),
+                dict(ex_pos=np.array([0, 4, 0])),
+                dict(indptr=np.array([0, 2, 1, 4])),
+                dict(indptr=np.array([0, 2, 3, 5]))):
+        with pytest.raises(ValueError, match="re_project_native"):
+            re_project_native(**dict(good, **bad))

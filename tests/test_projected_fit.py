@@ -10,7 +10,7 @@ import json
 import numpy as np
 import pytest
 
-from photon_ml_tpu import telemetry
+from photon_ml_tpu import native, telemetry
 from photon_ml_tpu.config import training_config_from_json
 from photon_ml_tpu.data.sparse_rows import SparseRows
 from photon_ml_tpu.estimators import game_estimator
@@ -185,7 +185,8 @@ def test_projection_counts_are_a_direct_count():
     grouping = group_by_entity(user, bucket_base=4)
     projection, x_blocks = build_subspace_projection(grouping, shard, WIDTH)
     direct = _direct_counts(user, shard)
-    assert projection.counts(grouping) == direct
+    built_by = {"native": int(native.lib() is not None), "workers": 1}
+    assert projection.counts(grouping) == dict(direct, **built_by)
     assert direct["block_elements"] == sum(b.size for b in x_blocks)
     assert direct["design_elements"] < direct["block_elements"]
     assert direct["buckets"] >= 3 and direct["widest"] > 20
@@ -199,18 +200,27 @@ def test_projection_counts_are_a_direct_count():
     assert projection.counts(grouping) == {
         "entities": 3, "buckets": 2, "subspace_columns": 2 + 3 + 4,
         "design_elements": 2 * 2 + 2 * 3 + 5 * 4,
-        "block_elements": 2 * 4 * 3 + 1 * 16 * 4, "widest": 4}
+        "block_elements": 2 * 4 * 3 + 1 * 16 * 4, "widest": 4, **built_by}
     assert [b.shape for b in x_blocks] == [(2, 4, 3), (1, 16, 4)]
 
 
+@pytest.fixture(params=["native", "numpy"])
+def library(request, monkeypatch):
+    """Both builders of a projection: the native library's (where there
+    is one) and, with the library away, the numpy body."""
+    if request.param == "numpy":
+        monkeypatch.setattr(native, "lib", lambda: None)
+    return request.param
+
+
 @pytest.mark.parametrize("example_entity_kept", [True, False])
-def test_projection_is_each_entitys_own_layout(example_entity_kept):
+def test_projection_is_each_entitys_own_layout(example_entity_kept, library):
     """Entity by entity, in plain loops: an entity's local columns are
     its distinct global columns in ascending order, and each of its
     rows' entries sits at (its slot, the row's place in the entity,
     the column's local index); everything else is padding.  A grouping
     without ``example_entity`` (one read back from a saved model) is
-    projected from its (bucket, slot) maps."""
+    projected from its (bucket, slot) maps.  By either builder."""
     import dataclasses
 
     train, _valid = _data()
@@ -268,20 +278,40 @@ class _Recorded:
         return Stage()
 
 
-def test_the_stages_of_a_projected_fit_say_what_was_built(monkeypatch):
+@pytest.mark.parametrize("library", ["native", "numpy"])
+def test_the_stages_of_a_projected_fit_say_what_was_built(
+        monkeypatch, sha256_of, library):
+    """With the library away the fit runs the numpy body, says so
+    (``native`` 0) and places the same blocks."""
+    train, valid = _data()
+    shard = train.features["user_shard"]
+    _projection, blocks = build_subspace_projection(
+        group_by_entity(train.entity_ids["userId"], bucket_base=4),
+        shard, WIDTH)           # by the library, where there is one
+    if library == "numpy":
+        monkeypatch.setattr(native, "lib", lambda: None)
     recorded = _Recorded()
     monkeypatch.setattr(telemetry, "stage", recorded)
-    train, valid = _data()
+    placed_blocks = []
+    place = coordinates._place_re
+
+    def placing(name, x_blocks, *rest):
+        placed_blocks.append(sha256_of(x_blocks))
+        return place(name, x_blocks, *rest)
+
+    monkeypatch.setattr(coordinates, "_place_re", placing)
     GameEstimator(_config(n_iterations=1)).fit(train, valid)
+    assert placed_blocks == [sha256_of(blocks)]
     names = [name for name, _counts in recorded.stages]
     assert "re_project" in telemetry.STAGES
     # inside group_entities: it closes first
     assert names.index("re_project") + 1 == names.index("group_entities")
     (project,) = [c for name, c in recorded.stages if name == "re_project"]
-    shard = train.features["user_shard"]
     direct = _direct_counts(train.entity_ids["userId"], shard)
+    by_library = library == "native" and native.lib() is not None
     assert project == dict(direct, entity_key="userId", nnz=shard.nnz,
-                           bytes=4 * direct["block_elements"])
+                           bytes=4 * direct["block_elements"],
+                           native=int(by_library), workers=1)
     (placed,) = [c for name, c in recorded.stages if name == "place_re"]
     assert placed["bytes"] > project["bytes"]
     trains = {c["coordinate"]: c for name, c in recorded.stages
