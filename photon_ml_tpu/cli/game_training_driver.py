@@ -278,7 +278,13 @@ def main(argv: list[str] | None = None) -> dict:
         description="photon-ml-tpu GAME training driver"
     )
     parser.add_argument("--config", required=True,
-                        help="training config JSON file")
+                        help="training config JSON file.  Its input_path "
+                             "names JSON-lines or Avro records of label, "
+                             "weight, offset, features and ids; a record's "
+                             "`offset` (a Poisson model's log exposure, a "
+                             "prior model's margin) enters every TRAINING "
+                             "margin as it enters validation and scoring; "
+                             "cd_fused refuses a dataset that has any")
     parser.add_argument("--output-dir", default=None,
                         help="override config output_dir")
     parser.add_argument("--spill-dir", default=None,

@@ -123,6 +123,14 @@ def _optimizer_config(settings: OptimizerSettings) -> OptimizerConfig:
     )
 
 
+def _training_offsets(train: GameDataset):
+    """The dataset's offsets as training takes them ([n] float32), or
+    None where it brought none: nothing is then added to any margin."""
+    if train.offsets is None:
+        return None
+    return jnp.asarray(train.offset_array())
+
+
 def _resolve_layout(requested: str, off_tpu: str) -> str:
     """``AUTO`` → the GRR compiled plan on a TPU (the only backend the
     Mosaic kernel runs on), ``off_tpu`` elsewhere; anything else as
@@ -158,6 +166,13 @@ class GameEstimator:
 
     def _prepare(self, train: GameDataset):
         cfg = self.config
+        if cfg.cd_fused and train.offsets is not None:
+            # before any chunk is built: the fused sweep composes its
+            # margins from coefficients (game/fused_sweep._fused_chunk)
+            raise ValueError(
+                "cd_fused does not carry a dataset's offsets: the fused "
+                "sweep composes its margins from coefficients; fit with "
+                "cd_fused=false")
         prep = {}
         for coord_cfg in cfg.coordinates:
             if coord_cfg.kind == CoordinateKind.FIXED_EFFECT:
@@ -659,6 +674,7 @@ class GameEstimator:
         with telemetry.stage("export_model",
                              coordinates=len(cd.coefficients)) as stage:
             pulled = 0
+            sparsity = {}
             for name, w in cd.coefficients.items():
                 coord_cfg = by_name[name]
                 coord = coords[name]
@@ -674,6 +690,11 @@ class GameEstimator:
                     # The fixed effect's export is the one host pull
                     # here; a random effect's blocks stay on the device.
                     pulled += int(w.nbytes)
+                    if coord.problem.has_l1():
+                        sparsity["nonzero_coefficients"] = (
+                            sparsity.get("nonzero_coefficients", 0)
+                            + int(np.count_nonzero(np.asarray(
+                                models[name].coefficients.means))))
                 else:
                     models[name] = coord.as_model(w)
                     if vtype != VarianceComputationType.NONE:
@@ -684,7 +705,7 @@ class GameEstimator:
                             coord.compute_variance_blocks(w, offsets))
                     models[name].feature_shard = coord_cfg.feature_shard
                     models[name].entity_key = coord_cfg.entity_key
-            stage.set(bytes_pulled=pulled)
+            stage.set(bytes_pulled=pulled, **sparsity)
         return GameModel(models=models)
 
     # -- batched λ-sweep (one data stream for the whole grid) --------------
@@ -721,11 +742,13 @@ class GameEstimator:
                 return None
         return name
 
-    def _locked_offsets(self, coords, locked: dict, n: int):
-        """Offsets the trainable coordinate sees = Σ locked scores
-        (CD semantics with one trainable coordinate: total −
-        own-scores, and own scores cancel)."""
-        total = jnp.zeros((n,), jnp.float32)
+    def _locked_offsets(self, coords, locked: dict, train: GameDataset):
+        """Offsets the trainable coordinate sees = the dataset's own +
+        Σ locked scores (CD semantics with one trainable coordinate:
+        total − own-scores, and own scores cancel)."""
+        total = _training_offsets(train)
+        if total is None:
+            total = jnp.zeros((train.n,), jnp.float32)
         for ln, lw in locked.items():
             total = total + coords[ln].score(lw)
         return total
@@ -948,7 +971,7 @@ class GameEstimator:
             raise ValueError(
                 f"locked coordinates {sorted(missing)} absent from "
                 "the warm-start model")
-        offsets = self._locked_offsets(coords, locked, train.n)
+        offsets = self._locked_offsets(coords, locked, train)
         return coords, locked, offsets, warm.get(name)
 
     def _fit_grid_swept(self, train: GameDataset, prep: dict, name: str,
@@ -1115,6 +1138,7 @@ class GameEstimator:
             run_logger=run_logger,
             checkpointer=checkpointer,
             fused_engine=fused,
+            offsets=_training_offsets(train),
         )
         model = self._to_game_model(coords, cd)
         if cd.validation_history:

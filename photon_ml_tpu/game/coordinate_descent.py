@@ -109,15 +109,28 @@ def _solve_counts(diag) -> dict:
     if not isinstance(diag, OptimizationResult) \
             or jnp.ndim(diag.iterations) != 0:
         return {}
-    iterations, passes, tracked, trials = jax.device_get((
-        diag.iterations, diag.forward_passes, diag.tracker.count,
-        diag.tracker.ls_trials))
+    iterations, passes, counted, tracked, trials = jax.device_get((
+        diag.iterations, diag.forward_passes, diag.ls_trials,
+        diag.tracker.count, diag.tracker.ls_trials))
     out = {"solver_iterations": int(iterations)}
-    if tracked and trials is not None:   # hand-built trackers have no plane
+    if counted is not None:              # the solve's own count, untracked
+        out["ls_trials"] = int(counted)
+    elif tracked and trials is not None:  # hand-built trackers have no plane
         out["ls_trials"] = int(np.nansum(trials))
     if passes is not None:
         out["forward_passes"] = int(passes)
     return out
+
+
+def _nonzero_counts(coord, w) -> dict:
+    """``nonzero_coefficients`` of a coordinate whose objective has an L1
+    term (an array, or a random effect's list of blocks): the sparsity
+    its solve ended with.  Nothing, and no device work, without one."""
+    problem = getattr(coord, "problem", None)
+    if problem is None or not problem.has_l1():
+        return {}
+    return {"nonzero_coefficients": int(sum(
+        jnp.count_nonzero(leaf) for leaf in jax.tree.leaves(w)))}
 
 
 def _diag_fields(diag) -> dict:
@@ -134,6 +147,10 @@ def _diag_fields(diag) -> dict:
             "solver_iterations": int(diag.iterations),
             "converged": bool(diag.converged),
         }
+        # what a whole-evaluation solve paid, where it counts it
+        if getattr(diag, "ls_trials", None) is not None:
+            out["ls_trials"] = int(diag.ls_trials)
+            out["forward_passes"] = int(diag.forward_passes)
         tracker = getattr(diag, "tracker", None)
         if tracker is not None and int(tracker.count) > 0:
             # Per-solver-iteration convergence trace (reference
@@ -212,7 +229,10 @@ class CoordinateDescentResult:
 
     coefficients: dict          # name → coordinate-specific coefficients
     scores: dict                # name → final per-example scores [n]
-    total_scores: jnp.ndarray   # [n]
+    # [n] the margins training ended with: the dataset's offsets, where
+    # it brought any, plus every coordinate's scores.  ``total_scores −
+    # scores[c]`` is what coordinate c's last solve saw.
+    total_scores: jnp.ndarray
     history: list               # per iteration: {coordinate: scalar
                                 # diagnostic fields (plain dict — the
                                 # checkpoint-serializable form, uniform
@@ -232,6 +252,7 @@ def run_coordinate_descent(
     run_logger=None,
     checkpointer=None,
     fused_engine=None,
+    offsets=None,
 ) -> CoordinateDescentResult:
     """Run GAME coordinate descent.
 
@@ -278,9 +299,23 @@ def run_coordinate_descent(
         computed against cycle-START offsets (Jacobi staleness — the
         ``validator``'s ``total_scores`` are therefore the cycle-start
         scores).  Locked coordinates are not supported on this path.
+      offsets: the dataset's per-example offsets [n] (``GameDataset
+        .offsets``: a Poisson model's log exposure, a prior model's
+        margins), or None.  They are part of every training margin:
+        the total starts at them, so each coordinate trains against
+        ``offsets + Σ other coordinates' scores``, the margins the
+        scored model is validated at (``GameTransformer`` adds the
+        same).  None starts the total at the scores alone: no zeros
+        are added for it.  The fused path composes its margins from
+        coefficients and refuses them.
     """
     if fused_engine is not None and locked_coordinates:
         raise ValueError("fused CD does not support locked coordinates")
+    if fused_engine is not None and offsets is not None:
+        raise ValueError(
+            "fused CD (cd_fused) composes its margins from coefficients "
+            "and does not carry a dataset's offsets; fit with "
+            "cd_fused=false")
     locked_coordinates = locked_coordinates or {}
     initial_coefficients = dict(initial_coefficients or {})
     for name in update_sequence:
@@ -381,7 +416,8 @@ def run_coordinate_descent(
     # what is left of the data's host-to-device transfers (placement
     # only enqueues them) shows here and not in the first train.
     with telemetry.stage("cd_initial_scores",
-                         coordinates=len(update_sequence)):
+                         coordinates=len(update_sequence),
+                         **({} if offsets is None else {"offsets": 1})):
         # Locked coordinates score once, up front, and never move.
         for name, locked_coefs in locked_coordinates.items():
             coefs[name] = locked_coefs
@@ -406,9 +442,11 @@ def run_coordinate_descent(
                 scores[name] = jnp.zeros_like(s)
 
         if "__cd_total__" in ckpt_scores:
+            # the running total as it was saved, offsets and all
             total = ckpt_scores["__cd_total__"]
         else:
-            total = None
+            total = (None if offsets is None
+                     else jnp.asarray(offsets, jnp.float32))
             for s in scores.values():
                 total = s if total is None else total + s
         jax.block_until_ready(total)
@@ -620,7 +658,8 @@ def _run_sweep(coordinates, update_sequence, locked_coordinates, coefs,
                 w, diag = jax.block_until_ready(
                     coord.train(offsets, coefs.get(name),
                                 donate_warm_start=True))
-                train_stage.set(**_solve_counts(diag),
+                sparsity = _nonzero_counts(coord, w)
+                train_stage.set(**_solve_counts(diag), **sparsity,
                                 **coord.train_counts())
             with telemetry.stage("coord_score", coordinate=name):
                 new_scores = jax.block_until_ready(coord.score(w))
@@ -630,7 +669,9 @@ def _run_sweep(coordinates, update_sequence, locked_coordinates, coefs,
         total = offsets + new_scores
         scores[name] = new_scores
         coefs[name] = w
-        iter_diag[name] = diag
+        # an L1 coordinate's record says how sparse its solve ended
+        iter_diag[name] = ({**_diag_fields(diag), **sparsity} if sparsity
+                           else diag)
         elapsed = coordinate_stage.duration_s
         # Retirement hook (streamed random effects, ISSUE 5): the
         # coordinate stashed this sweep's converged-entity
@@ -649,8 +690,9 @@ def _run_sweep(coordinates, update_sequence, locked_coordinates, coefs,
                       n_iterations * len(update_sequence),
                       unit="updates", iteration=it + 1,
                       coordinate=name)
-        extra = ({} if newly_retired is None
-                 else {"entities_newly_retired": newly_retired})
+        extra = dict(sparsity)
+        if newly_retired is not None:
+            extra["entities_newly_retired"] = newly_retired
         telemetry.count("cd.coordinate_updates")
         # Objective delta vs this coordinate's previous sweep, and
         # a convergence trace for resident solves (streaming

@@ -149,11 +149,17 @@ class OptimizationResult:
     iterations: Array   # int32 iterations executed
     converged: Array    # bool: tolerance met (vs iteration-capped)
     tracker: StatesTracker
-    # int32 forward contractions X·v the solve made, where the solver
-    # counts them in its carry (L-BFGS along the margins, which makes
-    # iterations + 1; one that evaluates each trial from w makes
-    # iterations + 1 + Σ ls_trials and counts none).
+    # int32 forward contractions X·v the solve made, counted in its
+    # carry: L-BFGS along the margins makes iterations + 1; one that
+    # evaluates each trial from w (OWL-QN, a swept lane, a bare
+    # callable) makes 1 + ls_trials + iterations, the accepted point of
+    # every search being evaluated once more.  None from a solver that
+    # counts none (TRON, the streamed solvers).
     forward_passes: Array | None = None
+    # int32 line-search trials, where every trial is a whole evaluation
+    # (then a cost of its own); None along the margins, where a trial is
+    # [rows]-vector work and the tracker's plane has them when tracked.
+    ls_trials: Array | None = None
 
 
 def grad_converged(g_norm: Array, g0_norm: Array, tolerance: float) -> Array:

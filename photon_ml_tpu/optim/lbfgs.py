@@ -32,7 +32,9 @@ TPU-native design notes:
   where evaluating each trial from w pays one more forward contraction a
   trial.  OWL-QN's orthant projection breaks ``w⁺ = w + α·d``, so with L1
   (and for a bare ``value_and_grad`` callable, which shows no margins)
-  each trial is a whole evaluation.
+  each trial is a whole evaluation, and so is the accepted point's
+  gradient: ``1 + ls_trials + iterations`` forward contractions, both
+  counted in the carry.
 """
 
 from __future__ import annotations
@@ -73,6 +75,7 @@ class _LbfgsCarry:
     tracker: StatesTracker
     # where the search walks the margins (``_along_margins``), else None:
     margins: Array | None = None         # [n] X·w + o
+    # counted in both modes:
     forward_passes: Array | None = None  # int32 — contractions X·v so far
 
 
@@ -200,21 +203,25 @@ def _by_whole_evaluations(value_and_grad: ValueAndGrad, l1_vec):
 
     - ``start(w0) → (margins, forward_passes, f_smooth, g)``;
     - ``open_search(c, d) → (trial, accept)``: ``trial(α, w_try) → f``
-      scores a point of the search, ``accept(α, w_new) → (margins,
-      forward_passes, g)`` takes the gradient where it ended.
+      scores a point of the search, ``accept(α, w_new, trials) →
+      (margins, forward_passes, g)`` takes the gradient where it ended,
+      after ``trials`` trials.
 
-    This mode carries no margins and counts no contractions."""
+    This mode carries no margins.  Each evaluation is one forward
+    contraction: the start, every trial, every accepted point, so its
+    trials are ``forward_passes − 1 − iterations``."""
 
     def start(w0):
-        return (None, None, *value_and_grad(w0))
+        return (None, jnp.asarray(1, jnp.int32), *value_and_grad(w0))
 
     def open_search(c, d):
         def trial(alpha, w_try):
             f, _ = value_and_grad(w_try)
             return f if l1_vec is None else f + jnp.sum(l1_vec * jnp.abs(w_try))
 
-        def accept(alpha, w_new):
-            return None, None, value_and_grad(w_new)[1]
+        def accept(alpha, w_new, trials):
+            return (None, c.forward_passes + trials + 1,
+                    value_and_grad(w_new)[1])
 
         return trial, accept
 
@@ -238,7 +245,7 @@ def _along_margins(split: MarginSplit):
         def trial(alpha, w_try):
             return split.value(c.margins + alpha * xd, w_try)
 
-        def accept(alpha, w_new):
+        def accept(alpha, w_new, trials):
             m_new = c.margins + alpha * xd
             return m_new, passes, split.value_and_grad(m_new, w_new)[1]
 
@@ -274,7 +281,8 @@ def lbfgs_solve(
     owlqn = l1_weight is not None
     l1_vec = (jnp.broadcast_to(jnp.asarray(l1_weight, w0.dtype), (d,))
               if owlqn else None)
-    if isinstance(objective, MarginSplit) and not owlqn:
+    walks_margins = isinstance(objective, MarginSplit) and not owlqn
+    if walks_margins:
         start, open_search = _along_margins(objective)
     else:
         if isinstance(objective, MarginSplit):
@@ -331,7 +339,7 @@ def lbfgs_solve(
         w_new, f_new, ls_ok, alpha, trials = _line_search(
             trial, c.w, c.f, pg, d_dir, config, xi
         )
-        m_new, passes, g_new = accept(alpha, w_new)
+        m_new, passes, g_new = accept(alpha, w_new, trials)
 
         s = w_new - c.w
         y = g_new - c.g
@@ -411,6 +419,8 @@ def lbfgs_solve(
         converged=final.converged,
         tracker=final.tracker,
         forward_passes=final.forward_passes,
+        ls_trials=(None if walks_margins
+                   else final.forward_passes - 1 - final.iteration),
     )
 
 

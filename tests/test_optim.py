@@ -516,8 +516,10 @@ def test_search_along_margins_is_the_search_by_whole_evaluations(
         <= 1e-9 * float(jnp.abs(want.value))
     # what each solve says it made, and what it made
     assert int(got.forward_passes) == iterations + 1 == len(forward_along)
-    assert want.forward_passes is None
-    assert len(forward_whole) == iterations + 1 + trials
+    assert got.ls_trials is None      # a trial there is no contraction
+    assert int(want.ls_trials) == trials
+    assert int(want.forward_passes) == iterations + 1 + trials \
+        == len(forward_whole)
 
 
 def test_search_along_margins_under_vmap_each_lane_is_its_solo_solve(rng):
@@ -556,7 +558,8 @@ def test_search_along_margins_under_vmap_each_lane_is_its_solo_solve(rng):
 
 def test_an_l1_solve_through_the_split_is_the_solve_of_the_bare_callable(rng):
     """OWL-QN projects each trial onto an orthant, so it evaluates each
-    from w, split or no split: the same program text, and no count."""
+    from w, split or no split: the same program text, and a count of
+    its own: the start, every trial, every accepted point."""
     from photon_ml_tpu.optim.problem import as_margin_split
 
     x, y, _batch, _obj = _logistic_problem(rng)
@@ -574,4 +577,6 @@ def test_an_l1_solve_through_the_split_is_the_solve_of_the_bare_callable(rng):
                 l1_weight=0.3))]
     assert texts[0] == texts[1]
     res = lbfgs_solve(as_margin_split(obj, batch), w0, cfg, l1_weight=0.3)
-    assert res.forward_passes is None
+    trials = int(np.nansum(np.asarray(res.tracker.ls_trials)))
+    assert int(res.ls_trials) == trials >= int(res.iterations) > 0
+    assert int(res.forward_passes) == 1 + trials + int(res.iterations)

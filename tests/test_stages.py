@@ -231,10 +231,10 @@ def test_traced_fit_counts_say_what_was_done(traced, tiny):
 
 
 def test_coord_train_counts_of_one_solve():
-    """What ``coord_train`` says of a solve: the trials only where the
-    solver tracked its states, the forward passes only where it counts
-    them (L-BFGS along the margins), nothing for a list of batched
-    results."""
+    """What ``coord_train`` says of a solve: along the margins the
+    trials only where the solver tracked its states; by whole
+    evaluations (an L1 term) trials and forward passes from the carry,
+    tracked or not; nothing for a list of batched results."""
     import jax.numpy as jnp
 
     from photon_ml_tpu.data.batch import make_dense_batch
@@ -264,7 +264,9 @@ def test_coord_train_counts_of_one_solve():
                                track_states=False)) \
         == {"solver_iterations": 6, "forward_passes": 7}
     whole = _solve_counts(solve(RegularizationContext.l1(0.1)))
-    assert set(whole) == {"solver_iterations", "ls_trials"}
+    assert whole["forward_passes"] == 1 + whole["ls_trials"] + 6
+    assert _solve_counts(solve(RegularizationContext.l1(0.1),
+                               track_states=False)) == whole
     assert _solve_counts([solve(RegularizationContext.l2(1.0))]) == {}
     assert _solve_counts({"entities": 3}) == {}
 

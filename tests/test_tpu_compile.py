@@ -355,6 +355,44 @@ def test_tailed_solve_contracts_the_tail_once_an_iteration(one_chip, on_tpu):
     _assert_fits(compiled)
 
 
+def test_owlqn_poisson_solve_compiles_at_the_public_width(one_chip, on_tpu):
+    """The fixed-effect solve of ``poisson-enet-kdd12`` (ISSUE 37): the
+    Poisson loss under an elastic net, so OWL-QN by whole evaluations:
+    the tail's X.w in the line search's loop (every trial from the
+    coefficients) and once more an iteration for the accepted point,
+    beside one X^T r; an L1 vector of the full width beside ``w``; and
+    the whole solve fits the chip."""
+    from photon_ml_tpu.data.normalization import NormalizationContext
+    from photon_ml_tpu.game.coordinates import _fixed_train_local_donating
+    from photon_ml_tpu.ops import losses
+    from photon_ml_tpu.ops.objective import GLMObjective
+    from photon_ml_tpu.ops.regularization import (
+        RegularizationContext,
+        exclude_intercept_mask,
+    )
+    from photon_ml_tpu.optim.base import OptimizerConfig, OptimizerType
+
+    leaf = _abstract(one_chip)
+    objective = jax.tree.map(
+        lambda a: leaf(a.shape, a.dtype),
+        GLMObjective(
+            loss=losses.POISSON,
+            reg=RegularizationContext.elastic_net(
+                1.0, 0.5, exclude_intercept_mask(KDD12_WIDTH,
+                                                 KDD12_WIDTH - 1)),
+            norm=NormalizationContext.identity()))
+    compiled = _fixed_train_local_donating.lower(
+        OptimizerType.LBFGS, OptimizerConfig(max_iters=30), True,
+        objective, _tailed_batch(one_chip), leaf((KDD12_ROWS,)), None, None,
+        leaf((KDD12_WIDTH,))).compile()
+    text = compiled.as_text()
+    assert "/while/body/while/body/photon/fe_tail_dot/" in text
+    assert "/while/body/photon/fe_tail_tdot/" in text
+    assert "/while/body/while/body/photon/fe_tail_tdot/" not in text
+    assert "tpu_custom_call" in text
+    _assert_fits(compiled)
+
+
 def test_fixed_effect_solve_compiles(one_chip, on_tpu):
     """The program the coordinate really dispatches: the whole L-BFGS
     solve (while_loop carries included) with the warm start donated."""
