@@ -103,9 +103,12 @@ def _solve_counts(diag) -> dict:
     """What one solve did, for the ``coord_train`` stage (a scalar
     ``OptimizationResult`` only; the random effects hand over lists and
     dicts): its iterations, the line-search trials they paid (the
+    solve's own count where a trial is a contraction, else the
     tracker's plane, where states are tracked) and the forward
-    contractions X·v, where the solver counts them.  One device→host
-    copy for all of them."""
+    contractions X·v, where the solver counts them: ``iterations + 1``
+    along the margins, ``1 + ls_trials`` for an L1 coordinate
+    (``optim.base.OptimizationResult``).  One device→host copy for all
+    of them."""
     if not isinstance(diag, OptimizationResult) \
             or jnp.ndim(diag.iterations) != 0:
         return {}
@@ -147,7 +150,7 @@ def _diag_fields(diag) -> dict:
             "solver_iterations": int(diag.iterations),
             "converged": bool(diag.converged),
         }
-        # what a whole-evaluation solve paid, where it counts it
+        # what a solve whose trials are contractions paid, where it counts
         if getattr(diag, "ls_trials", None) is not None:
             out["ls_trials"] = int(diag.ls_trials)
             out["forward_passes"] = int(diag.forward_passes)

@@ -47,7 +47,9 @@ class MarginSplit(NamedTuple):
     """An objective over one batch whose margins are affine in w (a GLM:
     ``ops.objective.GLMObjective``), split at the margins: what
     ``lbfgs_solve`` needs to search along ``m + a·X·d`` instead of
-    evaluating ``w + a·d`` from scratch.  ``optim.problem`` builds it."""
+    evaluating ``w + a·d`` from scratch, or, where OWL-QN's projection
+    bends the step, to take a gradient from the margins a trial already
+    paid for.  ``optim.problem`` builds it."""
 
     margins: Callable[[Array], Array]             # w → m = X·w + o
     margin_step: Callable[[Array], Array]         # d → X·d
@@ -150,15 +152,18 @@ class OptimizationResult:
     converged: Array    # bool: tolerance met (vs iteration-capped)
     tracker: StatesTracker
     # int32 forward contractions X·v the solve made, counted in its
-    # carry: L-BFGS along the margins makes iterations + 1; one that
-    # evaluates each trial from w (OWL-QN, a swept lane, a bare
-    # callable) makes 1 + ls_trials + iterations, the accepted point of
-    # every search being evaluated once more.  None from a solver that
-    # counts none (TRON, the streamed solvers).
+    # carry: L-BFGS along the margins makes iterations + 1; OWL-QN
+    # through a ``MarginSplit`` 1 + ls_trials (a trial contracts its own
+    # point, and the accepted point's gradient is taken from the margins
+    # its last trial kept); a solve that evaluates each trial from w (a
+    # swept lane, a bare callable) 1 + ls_trials + iterations, the
+    # accepted point of every search being evaluated once more.  None
+    # from a solver that counts none (TRON, the streamed solvers).
     forward_passes: Array | None = None
-    # int32 line-search trials, where every trial is a whole evaluation
-    # (then a cost of its own); None along the margins, where a trial is
-    # [rows]-vector work and the tracker's plane has them when tracked.
+    # int32 line-search trials, where every trial is a forward
+    # contraction (then a cost of its own); None along the margins, where
+    # a trial is [rows]-vector work and the tracker's plane has them when
+    # tracked.
     ls_trials: Array | None = None
 
 

@@ -31,10 +31,12 @@ TPU-native design notes:
   ``iterations + 1`` forward contractions and as many transposed ones,
   where evaluating each trial from w pays one more forward contraction a
   trial.  OWL-QN's orthant projection breaks ``w⁺ = w + α·d``, so with L1
-  (and for a bare ``value_and_grad`` callable, which shows no margins)
-  each trial is a whole evaluation, and so is the accepted point's
-  gradient: ``1 + ls_trials + iterations`` forward contractions, both
-  counted in the carry.
+  each trial contracts ``X·w⁺`` anew; but the split still lets the search
+  keep the margins of the trial it scored last, and the accepted point's
+  gradient is taken from them: ``1 + ls_trials`` forward contractions.  A
+  bare ``value_and_grad`` callable shows no margins, so each trial is a
+  whole evaluation and so is the accepted point's gradient: ``1 +
+  ls_trials + iterations``.  Both counts ride in the carry.
 """
 
 from __future__ import annotations
@@ -73,9 +75,9 @@ class _LbfgsCarry:
     converged: Array  # bool — finished due to tolerance
     g0_norm: Array    # scalar — initial gradient norm (for rel. tolerance)
     tracker: StatesTracker
-    # where the search walks the margins (``_along_margins``), else None:
+    # where the objective is split at its margins, else None:
     margins: Array | None = None         # [n] X·w + o
-    # counted in both modes:
+    # counted in every mode:
     forward_passes: Array | None = None  # int32 — contractions X·v so far
 
 
@@ -150,8 +152,9 @@ def _orthant(w: Array, pg: Array) -> Array:
 def _line_search(
     value_fn, w: Array, f0: Array, pg: Array, d: Array,
     config: OptimizerConfig, xi: Array | None,
-) -> tuple[Array, Array, Array, Array, Array]:
-    """Backtracking Armijo; returns (w_new, f_new, ok, alpha, trials).
+) -> tuple[Array, Array, Array, Array, Array, Array | None]:
+    """Backtracking Armijo; returns (w_new, f_new, ok, alpha, trials,
+    kept).
 
     Sufficient-decrease test (Andrew & Gao's modified condition, which
     reduces to standard Armijo when there is no orthant projection):
@@ -161,55 +164,56 @@ def _line_search(
     For OWL-QN (``xi`` given) trial points are projected onto the starting
     orthant and the slope uses the *actual* displacement x⁺ − x (which may
     differ from α·d where coordinates were clipped to zero).
-    ``value_fn(α, x⁺)`` scores a trial: given α, a caller that knows the
-    objective along the step need not start from x⁺.
+    ``value_fn(α, x⁺) → (f, kept)`` scores a trial: given α, a caller that
+    knows the objective along the step need not start from x⁺; ``kept`` is
+    whatever it wants back of the trial the search ended on (a pytree,
+    None for nothing).
     """
 
     def trial(alpha):
         w_try = w + alpha * d
         if xi is not None:
             w_try = jnp.where(jnp.sign(w_try) == xi, w_try, 0.0)
-        return w_try, value_fn(alpha, w_try)
+        return (w_try, *value_fn(alpha, w_try))
 
     def accepts(w_try, f_try):
         return f_try <= f0 + config.ls_c1 * jnp.vdot(pg, w_try - w)
 
     def cond(state):
-        _, w_try, f_try, steps = state
+        _, w_try, f_try, _, steps = state
         return jnp.logical_and(
             jnp.logical_not(accepts(w_try, f_try)),
             steps < config.ls_max_steps,
         )
 
     def body(state):
-        alpha, _, _, steps = state
+        alpha, _, _, _, steps = state
         alpha = alpha * config.ls_shrink
-        w_try, f_try = trial(alpha)
-        return alpha, w_try, f_try, steps + 1
+        return (alpha, *trial(alpha), steps + 1)
 
     alpha0 = jnp.asarray(1.0, w.dtype)
-    w1, f1 = trial(alpha0)
-    alpha, w_new, f_new, steps = jax.lax.while_loop(
-        cond, body, (alpha0, w1, f1, jnp.asarray(0, jnp.int32))
+    alpha, w_new, f_new, kept, steps = jax.lax.while_loop(
+        cond, body, (alpha0, *trial(alpha0), jnp.asarray(0, jnp.int32))
     )
     ok = f_new < f0  # any strict decrease counts; stall otherwise
-    return w_new, f_new, ok, alpha, steps + 1
+    return w_new, f_new, ok, alpha, steps + 1, kept
 
 
 def _by_whole_evaluations(value_and_grad: ValueAndGrad, l1_vec):
-    """How a solve evaluates its points when all it has is ``w → (f, g)``
-    (or an orthant projection that bends the step): every trial, and the
-    accepted point once more, from w.  ``(start, open_search)``:
+    """How a solve evaluates its points when all it has is ``w → (f, g)``:
+    every trial, and the accepted point once more, from w.
+    ``(start, open_search)``:
 
     - ``start(w0) → (margins, forward_passes, f_smooth, g)``;
-    - ``open_search(c, d) → (trial, accept)``: ``trial(α, w_try) → f``
-      scores a point of the search, ``accept(α, w_new, trials) →
-      (margins, forward_passes, g)`` takes the gradient where it ended,
-      after ``trials`` trials.
+    - ``open_search(c, d) → (trial, accept)``: ``trial(α, w_try) → (f,
+      kept)`` scores a point of the search and says what of it to keep,
+      ``accept(α, w_new, trials, kept) → (margins, forward_passes, g)``
+      takes the gradient where the search ended, after ``trials`` trials,
+      handed what its last trial kept.
 
-    This mode carries no margins.  Each evaluation is one forward
-    contraction: the start, every trial, every accepted point, so its
-    trials are ``forward_passes − 1 − iterations``."""
+    This mode carries no margins and keeps nothing.  Each evaluation is
+    one forward contraction: the start, every trial, every accepted
+    point, so its trials are ``forward_passes − 1 − iterations``."""
 
     def start(w0):
         return (None, jnp.asarray(1, jnp.int32), *value_and_grad(w0))
@@ -217,15 +221,26 @@ def _by_whole_evaluations(value_and_grad: ValueAndGrad, l1_vec):
     def open_search(c, d):
         def trial(alpha, w_try):
             f, _ = value_and_grad(w_try)
-            return f if l1_vec is None else f + jnp.sum(l1_vec * jnp.abs(w_try))
+            return (f if l1_vec is None
+                    else f + jnp.sum(l1_vec * jnp.abs(w_try))), None
 
-        def accept(alpha, w_new, trials):
+        def accept(alpha, w_new, trials, kept):
             return (None, c.forward_passes + trials + 1,
                     value_and_grad(w_new)[1])
 
         return trial, accept
 
     return start, open_search
+
+
+def _start_at_margins(split: MarginSplit):
+    """``start`` of the two modes that carry the margins."""
+
+    def start(w0):
+        m0 = split.margins(w0)
+        return (m0, jnp.asarray(1, jnp.int32), *split.value_and_grad(m0, w0))
+
+    return start
 
 
 def _along_margins(split: MarginSplit):
@@ -235,23 +250,42 @@ def _along_margins(split: MarginSplit):
     work, and the accepted point's margins are known when its gradient
     is taken."""
 
-    def start(w0):
-        m0 = split.margins(w0)
-        return (m0, jnp.asarray(1, jnp.int32), *split.value_and_grad(m0, w0))
-
     def open_search(c, d):
         xd, passes = split.margin_step(d), c.forward_passes + 1
 
         def trial(alpha, w_try):
-            return split.value(c.margins + alpha * xd, w_try)
+            return split.value(c.margins + alpha * xd, w_try), None
 
-        def accept(alpha, w_new, trials):
+        def accept(alpha, w_new, trials, kept):
             m_new = c.margins + alpha * xd
             return m_new, passes, split.value_and_grad(m_new, w_new)[1]
 
         return trial, accept
 
-    return start, open_search
+    return _start_at_margins(split), open_search
+
+
+def _keeping_margins(split: MarginSplit, l1_vec):
+    """And for a split objective whose step the orthant projection bends
+    (OWL-QN): a trial's margins must be contracted from its own point,
+    ``X·w_try + o``, but the search keeps those of the trial it ends on,
+    and the accepted point's gradient is taken from them.  One forward
+    contraction at the start and one a trial, none at accept: its trials
+    are ``forward_passes − 1``."""
+
+    def open_search(c, d):
+        def trial(alpha, w_try):
+            m_try = split.margins(w_try)
+            return (split.value(m_try, w_try)
+                    + jnp.sum(l1_vec * jnp.abs(w_try))), m_try
+
+        def accept(alpha, w_new, trials, m_new):
+            return (m_new, c.forward_passes + trials,
+                    split.value_and_grad(m_new, w_new)[1])
+
+        return trial, accept
+
+    return _start_at_margins(split), open_search
 
 
 def lbfgs_solve(
@@ -265,9 +299,9 @@ def lbfgs_solve(
     Args:
       objective: smooth part — ``w → (f_smooth, ∇f_smooth)``, or a GLM
         objective split at its margins (``optim.base.MarginSplit``):
-        without an L1 term the line search then walks the margins
-        (module docstring) and the result counts its
-        ``forward_passes``.  The L1 term must NOT be folded in; pass it
+        without an L1 term the line search then walks the margins, with
+        one it keeps its last trial's for the accepted point's gradient
+        (module docstring).  The L1 term must NOT be folded in; pass it
         via ``l1_weight``.
       w0: [dim] initial point.
       l1_weight: None (plain L-BFGS) or per-coordinate L1 weights [dim]
@@ -281,14 +315,17 @@ def lbfgs_solve(
     owlqn = l1_weight is not None
     l1_vec = (jnp.broadcast_to(jnp.asarray(l1_weight, w0.dtype), (d,))
               if owlqn else None)
-    walks_margins = isinstance(objective, MarginSplit) and not owlqn
-    if walks_margins:
-        start, open_search = _along_margins(objective)
-    else:
-        if isinstance(objective, MarginSplit):
-            split = objective
-            objective = lambda w: split.value_and_grad(split.margins(w), w)
+    # what the objective shows decides how its points are evaluated, and
+    # with that how the trials are read off the count of contractions
+    if not isinstance(objective, MarginSplit):
         start, open_search = _by_whole_evaluations(objective, l1_vec)
+        trials_of = lambda c: c.forward_passes - 1 - c.iteration
+    elif owlqn:
+        start, open_search = _keeping_margins(objective, l1_vec)
+        trials_of = lambda c: c.forward_passes - 1
+    else:
+        start, open_search = _along_margins(objective)
+        trials_of = lambda c: None
 
     m0, passes0, f0_s, g0 = start(w0)
     f0 = f0_s + jnp.sum(l1_vec * jnp.abs(w0)) if owlqn else f0_s
@@ -336,10 +373,10 @@ def lbfgs_solve(
         d_dir = jnp.where(bad, -pg, d_dir)
 
         trial, accept = open_search(c, d_dir)
-        w_new, f_new, ls_ok, alpha, trials = _line_search(
+        w_new, f_new, ls_ok, alpha, trials, kept = _line_search(
             trial, c.w, c.f, pg, d_dir, config, xi
         )
-        m_new, passes, g_new = accept(alpha, w_new, trials)
+        m_new, passes, g_new = accept(alpha, w_new, trials, kept)
 
         s = w_new - c.w
         y = g_new - c.g
@@ -419,8 +456,7 @@ def lbfgs_solve(
         converged=final.converged,
         tracker=final.tracker,
         forward_passes=final.forward_passes,
-        ls_trials=(None if walks_margins
-                   else final.forward_passes - 1 - final.iteration),
+        ls_trials=trials_of(final),
     )
 
 

@@ -463,14 +463,18 @@ def test_cd_mid_sweep_resume_restores_the_total_with_its_offsets(tmp_path):
 # .as_text()`` under this file's settings), read on the parent commit
 # d819ca5 and on this tree with ``_solve_texts`` below: the same on both.
 # A change of JAX moves them all at once; a change of one solver's
-# arithmetic moves the cells that run it.
+# arithmetic moves the cells that run it.  PR 38 read them again on its
+# parent 02da805 and on its tree: L-BFGS along the margins lowers to the
+# same text with ``_line_search`` carrying what a trial keeps (nothing,
+# in that mode).
 PARENT_PROGRAMS = json.loads(open(os.path.join(
     REPO, "tests", "resources", "solve_programs_d819ca5.json")).read())
 
 
-def _solve_texts(cell_name):
-    """{coordinate: StableHLO text} of the cell's solves for a dataset
-    without offsets, at rehearsal size."""
+def _solve_texts(cell_name, offsets=False):
+    """{coordinate: StableHLO text} of the cell's solves at rehearsal
+    size, for a dataset without offsets (or, asked so, with them: a
+    solve is handed one vector either way)."""
     cell = manifests.resolve(MANIFEST, cell_name)
     operation = manifests.load_module(cell["operation_path"])
     config = operation.rehearsal_config(cell["config"])
@@ -479,7 +483,7 @@ def _solve_texts(cell_name):
     state = operation.prepare(config, cell["traffic"], data)
     estimator = GameEstimator(state["training_config"])
     train = state["train"]
-    assert train.offsets is None
+    assert (train.offsets is not None) == offsets
     coordinates = estimator._build_coordinates(
         train, estimator._prepare(train), {})
     seen = jnp.zeros((train.n,), jnp.float32)
@@ -504,6 +508,25 @@ def test_without_offsets_a_cell_s_solves_lower_to_the_parent_s_programs(
     found = {name: hashlib.sha256(text.encode()).hexdigest()
              for name, text in _solve_texts(cell_name).items()}
     assert found == PARENT_PROGRAMS[cell_name]
+
+
+# The same of the exposure cell's three solves, read on the parent commit
+# 02da805 by PR 38, which changed what OWL-QN keeps of a trial: its two
+# random effects are L2 and walk the margins, the parent's programs; its
+# fixed effect is the L1 solve, whose program is the one that moved.
+EXPOSURE_PARENT_PROGRAMS = {
+    "global": "dba3ca6e65dfae37f036a19de1baa9c104d63319a9bb959c8384299ceaa27bbf",
+    "per_item": "f04174fc736b0fbb3e02f89f1f680e74c4708c5509afbf33a2e8dea6e4ad3e05",
+    "per_user": "d4799ecfd557561dcdb483b2be443941b6f2993ae0a991db9e579eaadd251d08",
+}
+
+
+def test_of_the_exposure_cell_s_solves_only_the_l1_one_left_the_parent_s():
+    found = {name: hashlib.sha256(text.encode()).hexdigest()
+             for name, text in _solve_texts(CELL, offsets=True).items()}
+    assert sorted(name for name in found
+                  if found[name] != EXPOSURE_PARENT_PROGRAMS[name]) \
+        == ["global"]
 
 
 def test_without_offsets_the_total_starts_at_the_scores_alone():
@@ -578,8 +601,8 @@ def test_the_stages_of_an_exposure_fit_say_so(rehearsed, spans_of):
     fixed = [args for args in by_name["coord_train"]
              if args["coordinate"] == "global"][0]
     assert fixed["nonzero_coefficients"] == np.count_nonzero(block[3])
-    assert fixed["forward_passes"] == (1 + fixed["ls_trials"]
-                                       + fixed["solver_iterations"])
+    assert fixed["forward_passes"] == 1 + fixed["ls_trials"] \
+        > fixed["solver_iterations"] > 0
     assert all("nonzero_coefficients" not in args
                for args in by_name["coord_train"]
                if args["coordinate"] != "global")

@@ -6,6 +6,7 @@ extra obligation: the same solver must converge per-problem under vmap
 (the random-effect prerequisite, SURVEY.md §7 "masked while_loop").
 """
 
+import hashlib
 from functools import partial
 
 import jax
@@ -522,25 +523,29 @@ def test_search_along_margins_is_the_search_by_whole_evaluations(
         == len(forward_whole)
 
 
-def test_search_along_margins_under_vmap_each_lane_is_its_solo_solve(rng):
+@pytest.mark.parametrize("loss,l1", [("poisson", False), ("logistic", True),
+                                     ("poisson", True)])
+def test_search_under_vmap_each_lane_is_its_solo_solve(rng, loss, l1):
     """Lanes whose searches take different numbers of trials: the
     margins of a lane that has accepted wait, like its w, while the
-    others backtrack."""
+    others backtrack: those it walked to along the step, or with an L1
+    term (ISSUE 38) those its last trial kept."""
     lanes, n, dim = 6, 60, 5
     xs = rng.normal(size=(lanes, n, dim)) * rng.uniform(
         0.5, 6.0, (lanes, 1, 1))
-    ys = np.stack([_labels(rng, "poisson", x @ rng.normal(0, 0.3, dim))
+    ys = np.stack([_labels(rng, loss, x @ rng.normal(0, 0.3, dim))
                    for x in xs])
     batches = jax.vmap(lambda x, y, o: jax.tree.map(
         jnp.asarray, make_dense_batch(x, y, offsets=o, dtype=jnp.float64))
     )(xs, ys, rng.normal(0, 0.5, (lanes, n)))
+    reg = (RegularizationContext.elastic_net(1.0, 0.5) if l1
+           else RegularizationContext.l2(0.5))
     problem = OptimizationProblem(
-        objective=GLMObjective(loss=losses.POISSON,
-                               reg=RegularizationContext.l2(0.5),
+        objective=GLMObjective(loss=LOSSES[loss], reg=reg,
                                norm=NormalizationContext.identity()),
         config=OptimizerConfig(max_iters=40, tolerance=1e-6))
     w0s = jnp.zeros((lanes, dim), jnp.float64)
-    run = partial(problem.run, has_l1=False)
+    run = partial(problem.run, has_l1=l1)
     together = jax.jit(jax.vmap(run))(batches, w0s)
     trials = np.nansum(np.asarray(together.tracker.ls_trials), axis=1)
     assert len(set(trials.tolist())) > 2
@@ -549,34 +554,179 @@ def test_search_along_margins_under_vmap_each_lane_is_its_solo_solve(rng):
                             w0s[lane])
         assert int(solo.iterations) == int(together.iterations[lane])
         assert int(solo.forward_passes) == int(
-            together.forward_passes[lane]) == int(solo.iterations) + 1
+            together.forward_passes[lane]) == 1 + (
+                int(trials[lane]) if l1 else int(solo.iterations))
         np.testing.assert_array_equal(solo.tracker.ls_trials,
                                       together.tracker.ls_trials[lane])
         np.testing.assert_allclose(together.w[lane], solo.w, rtol=0,
                                    atol=1e-5 * float(jnp.max(jnp.abs(solo.w))))
 
 
-def test_an_l1_solve_through_the_split_is_the_solve_of_the_bare_callable(rng):
-    """OWL-QN projects each trial onto an orthant, so it evaluates each
-    from w, split or no split: the same program text, and a count of
-    its own: the start, every trial, every accepted point."""
+# -- OWL-QN keeps its last trial's margins (ISSUE 38) ------------------------
+# The orthant projection bends the step, so a trial's margins are
+# contracted from its own point; but handed the split, the search keeps
+# those of the trial it ends on and the accepted point's gradient is taken
+# from them: 1 + ls_trials forward contractions where a bare callable,
+# which shows no margins, pays 1 + ls_trials + iterations.
+
+# sha256 of the StableHLO text ``_owlqn_from_callable`` lowers to with
+# ``_l1_problem(rng, "logistic", float64)``, read on the parent commit
+# 02da805 (``git archive`` into a scratch directory) and on this tree: a
+# bare callable's OWL-QN is the parent's program.
+PARENT_OWLQN_FROM_A_CALLABLE = (
+    "226df47ae6cbb5f5d04012295e3b1449ff8ecaf55e24ec74d2d1f2f4c1ae473c")
+
+
+def _l1_problem(rng, loss, dtype, n=200, dim=8):
+    """A dressed objective of ``loss`` over a dense batch with weights
+    and an offset, and the L1 weights to solve it with."""
+    x = rng.normal(size=(n, dim)) * rng.uniform(0.5, 3.0, dim)
+    y = _labels(rng, loss, x @ rng.normal(0, 0.3, dim))
+    batch = make_dense_batch(x, y, weights=rng.uniform(0.5, 2.0, n),
+                             offsets=rng.normal(0, 0.5, n), dtype=dtype)
+    obj = jax.tree.map(lambda a: a.astype(dtype),
+                       _dressed_objective(rng, loss, dim))
+    return obj, batch, jnp.full(dim, 0.3, dtype).at[0].set(0.0)
+
+
+def _owlqn_from_callable(cfg, calls=None):
+    def solve(obj, batch, w0, l1):
+        vg = lambda v: obj.value_and_gradient(v, batch)
+        return lbfgs_solve(vg if calls is None else _counting(vg, calls),
+                           w0, cfg, l1_weight=l1)
+    return solve
+
+
+def _owlqn_from_split(cfg, margins=None, margin_step=None, gradient=None):
+    """``gradient(m, w)`` is called with the arguments of every
+    ``value_and_grad`` the solve executes."""
     from photon_ml_tpu.optim.problem import as_margin_split
 
-    x, y, _batch, _obj = _logistic_problem(rng)
-    batch = make_dense_batch(x, y, dtype=jnp.float64)
-    obj = _dressed_objective(rng, "logistic", 8)
-    cfg = OptimizerConfig(max_iters=9)
+    def solve(obj, batch, w0, l1):
+        split = as_margin_split(obj, batch)
+        if margins is not None:
+            split = split._replace(
+                margins=_counting(split.margins, margins),
+                margin_step=_counting(split.margin_step, margin_step))
+        if gradient is not None:
+            whole = split.value_and_grad
+
+            def seen(m, w):
+                jax.debug.callback(gradient, m, w)
+                return whole(m, w)
+            split = split._replace(value_and_grad=seen)
+        return lbfgs_solve(split, w0, cfg, l1_weight=l1)
+    return solve
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+def test_an_l1_solve_through_the_split_is_the_solve_of_the_bare_callable(
+        rng, dtype):
+    """The same trial points, projection and Armijo test, and what they
+    reach; what differs is what each pays for it."""
+    obj, batch, l1 = _l1_problem(rng, "logistic", dtype)
+    cfg = OptimizerConfig(max_iters=9, tolerance=1e-6)
+    w0 = jnp.zeros(8, dtype)
+    whole_calls, margins_calls, step_calls = [], [], []
+
+    # (i) a bare callable: the parent's program and the parent's count
+    if dtype == jnp.float64:
+        text = jax.jit(_owlqn_from_callable(cfg)).lower(
+            obj, batch, w0, l1).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest() \
+            == PARENT_OWLQN_FROM_A_CALLABLE
+    want = jax.jit(_owlqn_from_callable(cfg, whole_calls))(obj, batch, w0, l1)
+    # (ii) the split: a contraction at the start and one a trial
+    got = jax.jit(_owlqn_from_split(cfg, margins_calls, step_calls))(
+        obj, batch, w0, l1)
+    jax.effects_barrier()
+    iterations = int(want.iterations)
+    assert iterations > 0 and int(want.ls_trials) > iterations
+    assert int(want.forward_passes) \
+        == 1 + int(want.ls_trials) + iterations == len(whole_calls)
+    assert int(got.forward_passes) == 1 + int(got.ls_trials) \
+        == len(margins_calls)
+    assert not step_calls
+    assert int(got.ls_trials) == int(
+        np.nansum(np.asarray(got.tracker.ls_trials)))
+
+    # (iii) and they reach the same point
+    if dtype == jnp.float64:
+        assert int(got.iterations) == iterations
+        np.testing.assert_array_equal(got.tracker.ls_trials,
+                                      want.tracker.ls_trials)
+    tight = 1e-5 if dtype == jnp.float32 else 1e-9
+    scale = float(jnp.max(jnp.abs(want.w)))
+    assert float(jnp.max(jnp.abs(got.w - want.w))) <= tight * scale
+    assert float(jnp.abs(got.value - want.value)) \
+        <= tight * float(jnp.abs(want.value))
+
+
+@pytest.mark.parametrize("loss", ["logistic", "poisson"])
+def test_owlqn_takes_each_accepted_gradient_from_the_point_s_own_margins(
+        rng, loss):
+    """Every gradient the solve takes is handed the margins of that very
+    point, and the result's is the one recomputed from its w."""
+    from photon_ml_tpu.optim.lbfgs import _pseudo_gradient
+
+    obj, batch, l1 = _l1_problem(rng, loss, jnp.float64)
+    cfg = OptimizerConfig(max_iters=8, tolerance=0.0)
+    taken = []
+    res = jax.jit(_owlqn_from_split(
+        cfg, gradient=lambda m, w: taken.append((m, w))))(
+        obj, batch, jnp.zeros(8, jnp.float64), l1)
+    jax.effects_barrier()
+    assert len(taken) == 1 + int(res.iterations) == 9
+    for m, w in taken:
+        np.testing.assert_allclose(m, obj.margins(jnp.asarray(w), batch),
+                                   rtol=1e-12, atol=1e-12)
+    np.testing.assert_array_equal(taken[-1][1], res.w)
+    g = obj.value_and_gradient(res.w, batch)[1]
+    np.testing.assert_allclose(
+        res.grad_norm, jnp.linalg.norm(_pseudo_gradient(g, res.w, l1)),
+        rtol=1e-10)
+
+
+@pytest.mark.parametrize("loss", ["logistic", "poisson"])
+def test_owlqn_search_that_exhausts_its_steps_stays_at_the_old_point(
+        rng, loss, monkeypatch):
+    """Allowed three trials a search, the solve soon meets one it cannot
+    end (the examples weigh a thousandth, so that the first step, of
+    steepest descent, is not that one): it stops there, and its carry
+    (w, f, g, margins) is the one a solve capped an iteration earlier
+    ends with, though the rejected trial's margins went through
+    ``accept``."""
+    from photon_ml_tpu.optim import lbfgs
+
+    obj, batch, l1 = _l1_problem(rng, loss, jnp.float64)
+    batch = batch.replace(weights=batch.weights * 1e-3)
+    carries = []
+    whole_loop = jax.lax.while_loop
+
+    def spying(cond, body, init):
+        out = whole_loop(cond, body, init)
+        if isinstance(out, lbfgs._LbfgsCarry):
+            carries.append(out)
+        return out
+
+    monkeypatch.setattr(lbfgs.jax.lax, "while_loop", spying)
     w0 = jnp.zeros(8, jnp.float64)
-    texts = [
-        jax.jit(solve).lower(batch, w0).as_text()
-        for solve in (
-            lambda b, w: lbfgs_solve(as_margin_split(obj, b), w, cfg,
-                                     l1_weight=0.3),
-            lambda b, w: lbfgs_solve(
-                lambda v: obj.value_and_gradient(v, b), w, cfg,
-                l1_weight=0.3))]
-    assert texts[0] == texts[1]
-    res = lbfgs_solve(as_margin_split(obj, batch), w0, cfg, l1_weight=0.3)
-    trials = int(np.nansum(np.asarray(res.tracker.ls_trials)))
-    assert int(res.ls_trials) == trials >= int(res.iterations) > 0
-    assert int(res.forward_passes) == 1 + trials + int(res.iterations)
+
+    def solve(max_iters):
+        return _owlqn_from_split(OptimizerConfig(
+            max_iters=max_iters, tolerance=0.0, ls_max_steps=2))(
+            obj, batch, w0, l1 * 1e-3)
+
+    stalled = solve(30)
+    iterations = int(stalled.iterations)
+    assert 1 < iterations < 30 and bool(stalled.converged)
+    assert float(stalled.tracker.step_sizes[iterations]) == 0.0
+    assert int(stalled.tracker.ls_trials[iterations]) == 3
+    solve(iterations - 1)
+    rejected, old = carries
+    for name in ("w", "f", "g", "margins"):
+        np.testing.assert_array_equal(getattr(rejected, name),
+                                      getattr(old, name))
+    np.testing.assert_allclose(old.margins, obj.margins(old.w, batch),
+                               rtol=1e-12, atol=1e-12)
+    assert int(rejected.forward_passes) == int(old.forward_passes) + 3

@@ -232,8 +232,8 @@ def test_traced_fit_counts_say_what_was_done(traced, tiny):
 
 def test_coord_train_counts_of_one_solve():
     """What ``coord_train`` says of a solve: along the margins the
-    trials only where the solver tracked its states; by whole
-    evaluations (an L1 term) trials and forward passes from the carry,
+    trials only where the solver tracked its states; with an L1 term
+    (a contraction a trial) trials and forward passes from the carry,
     tracked or not; nothing for a list of batched results."""
     import jax.numpy as jnp
 
@@ -264,7 +264,10 @@ def test_coord_train_counts_of_one_solve():
                                track_states=False)) \
         == {"solver_iterations": 6, "forward_passes": 7}
     whole = _solve_counts(solve(RegularizationContext.l1(0.1)))
-    assert whole["forward_passes"] == 1 + whole["ls_trials"] + 6
+    # through the split OWL-QN keeps its last trial's margins: nothing
+    # is contracted at the accepted point
+    assert whole["solver_iterations"] == 6 \
+        and whole["forward_passes"] == 1 + whole["ls_trials"] > 7
     assert _solve_counts(solve(RegularizationContext.l1(0.1),
                                track_states=False)) == whole
     assert _solve_counts([solve(RegularizationContext.l2(1.0))]) == {}

@@ -357,11 +357,11 @@ def test_tailed_solve_contracts_the_tail_once_an_iteration(one_chip, on_tpu):
 
 def test_owlqn_poisson_solve_compiles_at_the_public_width(one_chip, on_tpu):
     """The fixed-effect solve of ``poisson-enet-kdd12`` (ISSUE 37): the
-    Poisson loss under an elastic net, so OWL-QN by whole evaluations:
-    the tail's X.w in the line search's loop (every trial from the
-    coefficients) and once more an iteration for the accepted point,
-    beside one X^T r; an L1 vector of the full width beside ``w``; and
-    the whole solve fits the chip."""
+    Poisson loss under an elastic net, so OWL-QN: the tail's X.w in the
+    line search's loop (every trial contracts its own point) and, since
+    ISSUE 38, one X^T r an iteration from the margins the last trial
+    kept, with no X.w of the accepted point's own; an L1 vector of the
+    full width beside ``w``; and the whole solve fits the chip."""
     from photon_ml_tpu.data.normalization import NormalizationContext
     from photon_ml_tpu.game.coordinates import _fixed_train_local_donating
     from photon_ml_tpu.ops import losses
