@@ -41,9 +41,15 @@ def enable_compilation_cache() -> str:
 
     The min-compile-time floor is dropped to 0.5 s so the solver and
     scoring programs (seconds to minutes of XLA time each) all persist
-    without caching the dispatch-layer trivia.  Idempotent."""
+    without caching the dispatch-layer trivia.  Every driver's first
+    act, so the compile path's ledger starts listening here: what
+    compiles before the first stage is charged to its (0, "") row.
+    Idempotent."""
     import jax
 
+    from photon_ml_tpu import telemetry
+
+    telemetry.listen_to_compiles()
     if not jax.config.jax_compilation_cache_dir:
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)

@@ -642,8 +642,11 @@ def _native_direction(cols, vals_masked, direction, table_len, n_segments,
     table SLICE [lo, hi)."""
     from photon_ml_tpu.native import grr_plan_native
 
-    plan = grr_plan_native(cols, vals_masked, direction, table_len,
-                           n_segments, cap, idx_range=idx_range)
+    with telemetry.stage("grr_plan_scan", entries=int(cols.size)) as scan:
+        plan = grr_plan_native(cols, vals_masked, direction, table_len,
+                               n_segments, cap, idx_range=idx_range)
+        scan.set(supertiles=0 if plan is None else int(plan["n_st"]),
+                 native=int(plan is not None))
     if plan is None:
         return None
     if idx_range is not None:
@@ -746,7 +749,9 @@ def build_grr_direction(
     distribution; overflow spills to the COO fallback.
     ``device=False`` keeps leaves as host numpy (see _native_direction).
     """
-    plan = _plan_coo(idx, seg, val, table_len, n_segments, cap)
+    with telemetry.stage("grr_plan_scan", entries=int(np.size(idx))) as scan:
+        plan = _plan_coo(idx, seg, val, table_len, n_segments, cap)
+        scan.set(supertiles=int(plan["n_st"]), native=int(plan["native"]))
     return _finish_direction(plan, table_len, n_segments, validate,
                              overflow_threshold, device=device,
                              dense_grid=dense_grid,

@@ -12,6 +12,9 @@ and device accounting, and the reconciliation checks over a
 - **Stage spans**: per-name duration stats from the
   ``telemetry_summary`` event (count, total, mean, share of the
   busiest thread's wall clock).
+- **Compile path by stage** (ISSUE 39): the summary's ``compile_path``
+  rows, what each (fit, stage) traced, lowered, loaded from the
+  persistent cache and compiled while the session was open.
 - **Prefetcher**: overlap efficiency — the fraction of streamed pass
   time the consumer was NOT blocked on the prefetch queue (1.0 = the
   disk+staging tier fully hidden under device compute) — plus producer
@@ -353,6 +356,20 @@ def report(path: str, threshold: float = 0.9, out=None) -> dict:
               f"{st['total_s']:>10.3f} {mean_ms:>9.2f} {share:>6.1%}")
         w()
 
+    compile_path = (summary or {}).get("compile_path", [])
+    if compile_path:
+        w("Compile path by stage:")
+        w(f"  {'fit':>3} {'stage':<20} {'programs':>8} {'trace_s':>9} "
+          f"{'lower_s':>9} {'load_s':>9} {'compile_s':>9} {'hits':>5} "
+          f"{'misses':>6}")
+        for row in compile_path:
+            w(f"  {row['fit']:>3} {row['stage'] or '-':<20} "
+              f"{row['programs']:>8} {row['trace_s']:>9.3f} "
+              f"{row['lower_s']:>9.3f} {row['cache_load_s']:>9.3f} "
+              f"{row['compile_s']:>9.3f} {row['cache_hits']:>5} "
+              f"{row['cache_misses']:>6}")
+        w()
+
     derived = (summary or {}).get("derived", {})
     counters = (summary or {}).get("counters", {})
     overlap = derived.get("overlap_efficiency")
@@ -522,6 +539,7 @@ def report(path: str, threshold: float = 0.9, out=None) -> dict:
         "reconciliation_thread": recon["thread"],
         "reconciliation_threads": recon["threads"],
         "counters": counters,
+        "compile_path": compile_path,
         "alerts": alerts,
         "heartbeats": beats,
         "thread_exceptions": len(deaths),

@@ -48,8 +48,6 @@ KNOWN_UPWARD = {
     ("photon_ml_tpu.telemetry.monitor", "serving"),
     ("photon_ml_tpu.telemetry.serve_report", "serving"),
     ("photon_ml_tpu.telemetry.monitor", "parallel"),
-    # the compile bridge borrows the guards' log pattern
-    ("photon_ml_tpu.telemetry", "analysis"),
     ("photon_ml_tpu.native", "ops"),
     # the plan codec knows GrrPair
     ("photon_ml_tpu.cache.plan_cache", "data"),

@@ -73,12 +73,21 @@ LEVEL_STAGE = "grr_overflow_level"
 # ``group_entities`` (ISSUE 35: tests/test_projected_fit.py has such a
 # fit); the tiny fit's random effects are dense.
 PROJECT_STAGE = "re_project"
+# Every direction's first scan of its entries (the level-1 plan, C++ or
+# numpy) runs this one, on the thread that builds the direction and so
+# directly inside that thread's chain stage (ISSUE 39).
+SCAN_STAGE = "grr_plan_scan"
 LEVEL_COUNTS = {"depth", "entries", "supertiles", "native", "kept"}
+SCAN_COUNTS = {"entries", "supertiles", "native"}
+# What a stage that was charged anything by the compile path's listener
+# sets when it closes, and ``estimator_fit`` always (ISSUE 39).
+COMPILE_COUNTS = {"programs", "trace_s", "lower_s", "cache_load_s",
+                  "compile_s"}
 CLASS_COUNTS = {"active_columns", "hot_columns", "planned_columns",
                 "planned_nnz", "tail_columns", "tail_nnz"}
 # Counts every run of the stage must carry (a stage may carry more).
 COUNTS = {
-    "estimator_fit": {"fit", "rows"},
+    "estimator_fit": {"fit", "rows"} | COMPILE_COUNTS,
     "prepare_fixed": {"coordinate", "rows", "dim"},
     "to_ell": {"rows", "dim", "k", "padded_rows"},
     "grr_plan_build": {"rows", "k", "dim", "nnz", "directions", "spill",
@@ -104,7 +113,7 @@ COUNTS = {
 
 def test_stage_table_is_the_whole_of_stages():
     assert set(PARENT) | set(CACHE_STAGES) \
-        | {ROUTE_STAGE, TAIL_STAGE, LEVEL_STAGE, PROJECT_STAGE} \
+        | {ROUTE_STAGE, TAIL_STAGE, LEVEL_STAGE, PROJECT_STAGE, SCAN_STAGE} \
         == set(telemetry.STAGES)
     assert len(set(telemetry.STAGES)) == len(telemetry.STAGES)
 
@@ -200,6 +209,12 @@ def test_traced_fit_counts_say_what_was_done(traced, tiny):
     counts = traced["counts"]
     (fit,) = _events(traced, "estimator_fit")
     assert counts[fit]["rows"] == train.n and counts[fit]["fit"] >= 2
+    # the warm-up fit compiled every program: this one none, and no
+    # stage of it was charged a trace or a lowering
+    assert {c: counts[fit][c] for c in COMPILE_COUNTS} \
+        == dict.fromkeys(COMPILE_COUNTS, 0)
+    assert not any(COMPILE_COUNTS & set(counts[e])
+                   for e in counts if e != fit)
     (build,) = _events(traced, "grr_plan_build")
     assert counts[build]["cache_hit"] == 0 and counts[build]["nnz"] > 0
     assert counts[build]["directions"] == len(
@@ -228,6 +243,25 @@ def test_traced_fit_counts_say_what_was_done(traced, tiny):
     (validation,) = _events(traced, "validation")
     assert counts[validation]["rows"] == valid.n
     assert len(_events(traced, "place_batch")) == 2   # the fence, the rest
+
+
+def test_traced_fit_scans_each_direction_inside_its_chain_stage(traced):
+    """``grr_plan_scan``: one for every direction built, on a pool
+    thread, while a chain stage is open (the run log, which knows the
+    threads apart, says inside which:
+    ``test_run_log_scan_is_directly_inside_its_chain_stage``)."""
+    counts = traced["counts"]
+    scans = _events(traced, SCAN_STAGE)
+    (build,) = _events(traced, "grr_plan_build")
+    assert len(scans) == counts[build]["directions"]
+    chains = [e for stage in POOL_STAGES for e in _events(traced, stage)]
+    for start, duration, name in scans:
+        assert (start, duration, name) in traced["other"]
+        assert set(counts[(start, duration, name)]) == SCAN_COUNTS
+        assert counts[(start, duration, name)]["entries"] > 0
+        assert counts[(start, duration, name)]["supertiles"] > 0
+        assert any(s <= start and start + duration <= s + d
+                   for s, d, _n in chains)
 
 
 def test_coord_train_counts_of_one_solve():
@@ -339,6 +373,16 @@ def test_run_log_duration_is_the_stage_and_holds_its_children(run_log):
         assert event["duration_s"] == pytest.approx(whole["dur"], abs=5e-3)
         assert whole["dur"] >= train["dur"] + score["dur"]
         assert train["depth"] == score["depth"] == whole["depth"] + 1
+
+
+def test_run_log_scan_is_directly_inside_its_chain_stage(run_log):
+    spans = [e for e in run_log if e["event"] == "span"]
+    scans = [e for e in spans if e["name"] == SCAN_STAGE]
+    assert scans
+    for scan in scans:
+        (chain,) = _around(spans, scan, POOL_STAGES)
+        assert scan["depth"] == chain["depth"] + 1
+        assert scan["cat"] == "stage" and set(scan["args"]) == SCAN_COUNTS
 
 
 @pytest.mark.parametrize("stage", CACHE_STAGES)
