@@ -1011,21 +1011,58 @@ def classify_columns(counts: np.ndarray, n_row_windows: int,
 _TAIL_CODE = np.iinfo(np.int32).min
 
 
+def _column_counts(cols, vals, dim):
+    """Every column's count of nonzero entries in one ELL batch:
+    ``(np.bincount(cols[vals != 0].reshape(-1), minlength=dim),
+    threads)``.  The native library counts on every core
+    (``native.column_counts_native``; ``threads`` of its call, the
+    caller's included); without it ``np.bincount`` does, and ``threads``
+    is 0."""
+    from photon_ml_tpu.native import column_counts_native
+
+    counted = column_counts_native(cols, vals, dim)
+    if counted is not None:
+        return counted
+    return np.bincount(cols[vals != 0].reshape(-1), minlength=dim), 0
+
+
 def _split_classes(cols, vals, dim, n_rows, classes: ColumnClasses):
     """One ELL batch taken apart by column class.  Returns (x_hot
     [n_rows, H] f32, the planned class as (cols, vals, width) for the
     direction builders, with every other entry's value zeroed and the
     columns remapped where ``classes.planned`` says so, the tail's
     entries in row order as (row, col, val) or None)."""
-    nz = vals != 0
+    return _split_classes_threads(cols, vals, dim, n_rows, classes)[0]
+
+
+def _split_classes_threads(cols, vals, dim, n_rows,
+                           classes: ColumnClasses):
+    """(``_split_classes``' result, the threads that made it): the
+    native library's two passes on every core
+    (``native.split_classes_native``) or, without it, the numpy body
+    below, whose bytes the library's are (0 threads)."""
+    from photon_ml_tpu.native import split_classes_native
+
     if classes.planned is None:             # planned: its (new) id
         code = np.arange(dim, dtype=np.int32)
+        width = dim
     else:
         code = np.zeros(dim, np.int32)
         code[classes.planned] = np.arange(classes.planned.size,
                                           dtype=np.int32)
+        width = int(classes.planned.size)
     code[classes.hot] = -1 - np.arange(classes.hot.size, dtype=np.int32)
     code[classes.tail] = _TAIL_CODE
+    split = split_classes_native(
+        cols, vals, code, n_rows, n_hot=int(classes.hot.size),
+        remap=classes.planned is not None)
+    if split is not None:
+        x_hot, cols_planned, vals_planned, tail, threads = split
+        return (x_hot,
+                (cols if cols_planned is None else cols_planned,
+                 vals_planned, width),
+                tail if classes.tail.size else None), threads
+    nz = vals != 0
     code = code[cols]
     is_hot = nz & (code < 0) & (code != _TAIL_CODE)
     x_hot = np.zeros((n_rows, classes.hot.size), np.float32)
@@ -1037,10 +1074,9 @@ def _split_classes(cols, vals, dim, n_rows, classes: ColumnClasses):
         tail = (r_idx.astype(np.int32),
                 cols[r_idx, k_idx].astype(np.int32), vals[r_idx, k_idx])
     vals_planned = np.where(nz & (code >= 0), vals, np.float32(0.0))
-    if classes.planned is None:
-        return x_hot, (cols, vals_planned, dim), tail
-    return x_hot, (np.maximum(code, 0), vals_planned,
-                   int(classes.planned.size)), tail
+    cols_planned = (cols if classes.planned is None
+                    else np.maximum(code, 0))
+    return (x_hot, (cols_planned, vals_planned, width), tail), 0
 
 
 @struct.dataclass
@@ -1346,20 +1382,24 @@ def _plan_col_ranges(cols, vals_masked, dim, max_parts=4,
 
 def _mid_hot_split(cols, vals_masked, dim, n, mid_threshold, validate,
                    overflow_threshold, device=True, mid=None, cap=None,
-                   dense_grid=None):
+                   dense_grid=None, stage=None):
     """Mid-hot column split for the gradient direction (see GrrPair
     docstring): columns whose per-row-window occupancy would overflow
     the tail plan's capacities get a compact GrrDirection over remapped
     ids.  ``mid``/``cap``/``dense_grid`` may be forced (the sharded
     build needs one global mid set and mesh-uniform plan structure).
+    ``stage``, where given, is told who counted the columns (``native``
+    1: the library; 0: ``np.bincount``).
     Returns (mid_ids [M] i32 | None, col_mid | None, vals_masked_tail).
     """
-    nz = vals_masked != 0
     if mid is None:
-        counts = np.bincount(cols[nz].reshape(-1), minlength=dim)
+        counts, threads = _column_counts(cols, vals_masked, dim)
+        if stage is not None:
+            stage.set(native=int(threads > 0))
         mid = np.flatnonzero(counts > mid_threshold)
     if not mid.size:
         return None, None, vals_masked
+    nz = vals_masked != 0
     pos = np.full(dim, -1, np.int64)
     pos[mid] = np.arange(mid.size)
     is_mid = nz & (pos[cols] >= 0)
@@ -1548,18 +1588,31 @@ def _build_pair_cold(cols, vals, dim, cap, hot_threshold, max_hot,
             # is exactly right: small-d problems ARE dense matmuls.)
             hot_threshold = min(max(64, n // 16), 48 * n_row_windows)
         max_hot = min(max_hot, max(1, max_hot_bytes // (4 * n)))
-        counts = np.bincount(cols[vals != 0].reshape(-1), minlength=dim)
+        # The stage's two passes over every entry, the count and the
+        # split, run in the native library on every core; its three
+        # parts are timed apart (``*_s``), since only the classifying
+        # between them is left to one numpy thread.
+        t0 = time.perf_counter()
+        counts, count_threads = _column_counts(cols, vals, dim)
+        t1 = time.perf_counter()
         classes = classify_columns(counts, n_row_windows, hot_threshold,
                                    max_hot)
         del counts
         hot_ids = classes.hot.astype(np.int32)
+        t2 = time.perf_counter()
         # From here on the builders see the planned class alone, over
         # its own columns: ``dim`` is that class's width, ``width`` the
         # batch's.
         width = dim
-        x_hot, (cols, vals_masked, dim), tail_entries = _split_classes(
-            cols, vals, dim, n, classes)
-        hot_split.set(hot_columns=len(hot_ids))
+        entries = int(cols.size)
+        (x_hot, (cols, vals_masked, dim), tail_entries), split_threads = \
+            _split_classes_threads(cols, vals, dim, n, classes)
+        hot_split.set(
+            hot_columns=len(hot_ids), entries=entries,
+            native=int(count_threads > 0 and split_threads > 0),
+            workers=max(1, count_threads, split_threads),
+            count_s=round(t1 - t0, 4), classify_s=round(t2 - t1, 4),
+            split_s=round(time.perf_counter() - t2, 4))
     auto_mid = mid_threshold is None
     if auto_mid:
         mid_threshold = 16 * n_row_windows
@@ -1614,7 +1667,7 @@ def _build_pair_cold(cols, vals, dim, cap, hot_threshold, max_hot,
             if not auto_mid or n >= WIN:
                 mid_ids_h, col_mid_h, vals_tail = _mid_hot_split(
                     cols, vals_masked, dim, n, mid_threshold, validate,
-                    overflow_threshold, device=False)
+                    overflow_threshold, device=False, stage=mid_split)
             else:
                 mid_ids_h, col_mid_h, vals_tail = None, None, vals_masked
             # Transfer the mid plan under the tail col build.
@@ -1902,9 +1955,7 @@ def build_sharded_grr_pairs(
     # Global hot-column split: one hot id set for every shard.
     counts = np.zeros(dim, np.int64)
     for c, v in zip(shard_cols, shard_vals):
-        nz = np.asarray(v) != 0
-        counts += np.bincount(
-            np.asarray(c)[nz].reshape(-1), minlength=dim)
+        counts += _column_counts(np.asarray(c), np.asarray(v), dim)[0]
     n_row_windows = max(1, -(-per // WIN)) * n_shards
     if hot_threshold is None:
         # Same economics as build_grr_pair, scaled to the shard-local

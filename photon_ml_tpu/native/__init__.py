@@ -3,9 +3,10 @@
 Reference counterpart: the JVM data plane (Spark executors deserializing
 Avro, shuffling, building per-partition iterables — SURVEY.md §5.8).
 The rebuild's data plane is host-side array construction; the hot parts
-(LIBSVM text parsing, the transposed-ELL counting sort, the GRR plans
-and their routes, the random effects' subspace projection) live in
-``fast_etl.cpp`` and are bound here.
+(LIBSVM text parsing, the transposed-ELL counting sort, the planner's
+column count and class split, the GRR plans and their routes, the random
+effects' subspace projection) live in ``fast_etl.cpp`` and are bound
+here.
 
 Build model: ``g++ -O3 -shared -fPIC -pthread`` on first use (seconds,
 once; the GRR router and the projection run their blocks on
@@ -183,6 +184,24 @@ def lib() -> "ctypes.CDLL | None":
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
             ctypes.c_int32,
         ]
+        dll.pml_column_counts.restype = ctypes.c_int32
+        dll.pml_column_counts.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int32,
+        ]
+        dll.pml_split_classes_sizes.restype = ctypes.c_int32
+        dll.pml_split_classes_sizes.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
+        ]
+        dll.pml_split_classes_fill.restype = ctypes.c_int32
+        dll.pml_split_classes_fill.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
+        ] + [ctypes.c_void_p] * 6
         _lib = dll
         return dll
 
@@ -578,3 +597,129 @@ def re_project_native(
         raise ValueError("re_project_native: an example's row is outside "
                          "its entity's capacity")
     return feature_ids, x_blocks, workers
+
+
+# Entries a block of the planner's hot split holds (the class split
+# rounds it down to whole rows): 38 million entries are 583 blocks for
+# a dozen cores, and a block's private count cache (4,096 slots) is
+# flushed once for every sixteen entries at most.  Not a setting: what
+# adapts is the number of blocks.
+_SPLIT_BLOCK = 1 << 16
+
+
+def _ell_pair(cols, vals):
+    """An ELL batch's ``cols`` and ``vals`` as the library reads them:
+    contiguous int32 and float32 of one 2-D shape."""
+    cols = _int32_ids(cols, "column")
+    vals = np.ascontiguousarray(vals, np.float32)
+    if cols.ndim != 2 or cols.shape != vals.shape:
+        raise ValueError("cols and vals must be 2-D and of one shape")
+    return cols, vals
+
+
+def _no_room(entry: str) -> None:
+    """An entry's outputs could not be allocated: said on stderr, as
+    the library's other reasons are, and the caller's numpy body
+    decides (it may fail the same way, with its own traceback)."""
+    sys.stderr.write(f"photon_ml_tpu.native: {entry}: no memory for the "
+                     "outputs; using the numpy body\n")
+
+
+def _split_workers(entries: int) -> int:
+    """Threads a pass of the hot split over ``entries`` ELL slots runs
+    on, the caller's included: 1 (inline) under two blocks."""
+    return min(_usable_cores(), max(1, entries // _SPLIT_BLOCK))
+
+
+def column_counts_native(cols: np.ndarray, vals: np.ndarray, dim: int):
+    """``(np.bincount(cols[vals != 0].reshape(-1), minlength=dim),
+    workers)`` of an ELL batch, or None when the native library is
+    unavailable or the table cannot be allocated (the caller's
+    ``np.bincount`` decides).  Raises ValueError at a column outside
+    [0, dim), where ``np.bincount`` returns a longer table or raises.
+
+    Row blocks of ``_SPLIT_BLOCK`` entries on every core the process
+    may use (``workers``; native threads that live for the call), each
+    counting in a small private cache it adds into the one shared
+    table: integer sums commute, so the bytes are the serial count's,
+    and the table's zero pages are first touched there.  An input under
+    two blocks runs inline (``workers`` 1)."""
+    dll = lib()
+    if dll is None:
+        return None
+    cols, vals = _ell_pair(cols, vals)
+    workers = _split_workers(cols.size)
+    try:
+        counts = np.zeros(int(dim), np.int64)
+    except MemoryError:
+        return _no_room("column_counts_native")
+    rc = dll.pml_column_counts(_ptr(cols), _ptr(vals), cols.size, int(dim),
+                               _ptr(counts), _SPLIT_BLOCK, workers)
+    if rc != 0:
+        raise ValueError("column_counts_native: column id out of range")
+    return counts, workers
+
+
+def split_classes_native(cols: np.ndarray, vals: np.ndarray,
+                         code: np.ndarray, n_rows: int, n_hot: int,
+                         remap: bool):
+    """An ELL batch taken apart by column class (``data.grr.
+    _split_classes``, whose bytes these are) → ``(x_hot, cols_planned,
+    vals_planned, (tail_row, tail_col, tail_val), workers)``, or None
+    when the native library is unavailable or an output cannot be
+    allocated (the numpy body decides).
+
+    ``code`` [dim] int32: a planned column's id in the plans (>= 0), a
+    hot column's ``-1 - rank``, a tail column's ``INT32_MIN``.
+    ``x_hot`` [n_rows, n_hot] float32: a row's hot entries added in
+    entry order.  ``cols_planned`` [n, k] int32: ``max(code[cols], 0)``
+    with ``remap``, else None (the plans keep ``cols``).
+    ``vals_planned`` [n, k] float32: a planned column's entry keeps its
+    value, every other is 0.0.  The tail's entries (nonzero value, tail
+    column) as int32 rows, int32 columns and float32 values in row
+    order, then entry order; empty where there is none.  Raises
+    ValueError at a column outside [0, dim) or a hot rank outside
+    [0, n_hot).
+
+    Two passes over row blocks of ``_SPLIT_BLOCK`` entries on every
+    core the process may use (``workers``): the first counts each
+    block's tail entries, which places every block in the tail's
+    arrays; the second writes every output in place, so its pages are
+    first touched by the threads that fill them.  An input under two
+    blocks runs inline (``workers`` 1)."""
+    dll = lib()
+    if dll is None:
+        return None
+    cols, vals = _ell_pair(cols, vals)
+    code = np.ascontiguousarray(code, np.int32)
+    n, k = cols.shape
+    if n_rows < n:
+        raise ValueError("split_classes_native: n_rows is under the "
+                         "batch's rows")
+    block_rows = max(1, _SPLIT_BLOCK // max(k, 1))
+    workers = _split_workers(cols.size)
+    tail_start = np.empty(-(-n // block_rows) + 1, np.int64)
+    rc = dll.pml_split_classes_sizes(
+        _ptr(cols), _ptr(vals), n, k, _ptr(code), code.size, block_rows,
+        workers, _ptr(tail_start))
+    if rc != 0:
+        raise ValueError("split_classes_native: column id out of range")
+    n_tail = int(tail_start[-1])
+    try:
+        # zero pages nobody has touched: the fill's threads do
+        x_hot = np.zeros((int(n_rows), int(n_hot)), np.float32)
+        cols_planned = np.empty((n, k), np.int32) if remap else None
+        vals_planned = np.empty((n, k), np.float32)
+        tail = (np.empty(n_tail, np.int32), np.empty(n_tail, np.int32),
+                np.empty(n_tail, np.float32))
+    except MemoryError:
+        return _no_room("split_classes_native")
+    rc = dll.pml_split_classes_fill(
+        _ptr(cols), _ptr(vals), n, k, _ptr(code), int(n_hot), block_rows,
+        workers, _ptr(tail_start), _ptr(x_hot),
+        None if cols_planned is None else _ptr(cols_planned),
+        _ptr(vals_planned), *map(_ptr, tail))
+    if rc != 0:
+        raise ValueError("split_classes_native: hot rank out of range "
+                         "(or the batch changed between the passes)")
+    return x_hot, cols_planned, vals_planned, tail, workers

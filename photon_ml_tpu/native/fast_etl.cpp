@@ -21,6 +21,11 @@
 //                   by column + virtual-row splitting; O(nnz + dim))
 //   CSR + entity grouping -> per-entity subspaces and their dense
 //                   blocks (the projection of game/projector.py)
+//   row-ELL      -> per-column entry counts (pml_column_counts), and
+//                   the batch taken apart by column class: hot block,
+//                   planned ELL, tail COO (pml_split_classes_sizes /
+//                   pml_split_classes_fill; the planner's hot split,
+//                   data/grr.py)
 
 #include <algorithm>
 #include <atomic>
@@ -897,6 +902,155 @@ void pml_grr_plan_fill(void* handle, int8_t* hi, float* vals, int32_t* dst,
 }
 
 void pml_grr_plan_free(void* handle) { delete static_cast<GrrPlan*>(handle); }
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// The planner's hot split (data/grr.py _build_pair_cold): the two passes
+// over every entry of an ELL batch that come before any plan is built.
+// ---------------------------------------------------------------------------
+//
+// Rows share nothing but the count table, so both run on row blocks
+// over for_each_block's threads and leave the bytes of the numpy bodies
+// (np.bincount; _split_classes): integer sums commute, and every other
+// output is written at a place its row alone decides.  Nothing of the
+// size of the batch or of `dim` is allocated here: the outputs are the
+// caller's, zeroed where said and otherwise untouched, so their pages
+// are first touched by the threads that fill them.
+
+namespace {
+
+constexpr int32_t kTailCode = INT32_MIN;  // data/grr.py _TAIL_CODE
+
+// Slots of a block's private count cache, direct-mapped: 32 KB.  A
+// third of a one-hot batch's entries fall on a few dozen columns (the
+// small fields, the intercept): bare atomic increments from every
+// thread meet on those cache lines, so a block counts in private and
+// adds a slot to the shared table when another column takes it.
+constexpr int32_t kCountCacheBits = 12;
+
+}  // namespace
+
+extern "C" {
+
+// counts[c] += the entries of cols[0:m] equal to c whose value is not
+// zero.  counts: [dim] int64, zeroed by the caller.  Entries of value
+// zero (ELL padding) are not looked at.  Returns 0, or -1 at a column
+// outside [0, dim) (counts is then unspecified; nothing is written
+// outside it).
+int32_t pml_column_counts(const int32_t* cols, const float* vals, int64_t m,
+                          int64_t dim, int64_t* counts, int64_t block,
+                          int32_t n_threads) {
+  // a slot counts in int32: a block holds fewer entries than that
+  if (m < 0 || dim < 0 || block < 1 || block > (int64_t{1} << 30)) return -1;
+  constexpr int64_t kSlots = int64_t{1} << kCountCacheBits;
+  return for_each_block((m + block - 1) / block, n_threads, [&](int64_t b) {
+    int32_t key[kSlots], held[kSlots];
+    std::fill(key, key + kSlots, -1);
+    auto flush = [&](int64_t s) {
+      __atomic_fetch_add(&counts[key[s]], int64_t{held[s]}, __ATOMIC_RELAXED);
+    };
+    const int64_t e1 = std::min(m, (b + 1) * block);
+    for (int64_t e = b * block; e < e1; ++e) {
+      if (vals[e] == 0.0f) continue;
+      const int32_t c = cols[e];
+      if (c < 0 || c >= dim) return -1;
+      // multiplicative hash: ids a power of two apart share no slot
+      const uint32_t s =
+          (static_cast<uint32_t>(c) * 2654435761u) >> (32 - kCountCacheBits);
+      if (key[s] == c) {
+        ++held[s];
+        continue;
+      }
+      if (key[s] >= 0) flush(s);
+      key[s] = c;
+      held[s] = 1;
+    }
+    for (int64_t s = 0; s < kSlots; ++s)
+      if (key[s] >= 0) flush(s);
+    return 0;
+  });
+}
+
+// First call of the class split.  cols/vals: the [n, k] ELL batch;
+// code [dim]: every column's class (a planned column: its id in the
+// plans, >= 0; a hot column: -1 - its rank among the hot; a tail
+// column: INT32_MIN).  Writes tail_start [n_blocks + 1], n_blocks =
+// ceil(n / block_rows): block b's tail entries are
+// [tail_start[b], tail_start[b + 1]) of the tail's arrays.  Returns 0,
+// or -1 at a column outside [0, dim), padding entries included (the
+// second call reads every entry's code).
+int32_t pml_split_classes_sizes(const int32_t* cols, const float* vals,
+                                int64_t n, int64_t k, const int32_t* code,
+                                int64_t dim, int64_t block_rows,
+                                int32_t n_threads, int64_t* tail_start) {
+  if (n < 0 || k < 0 || dim < 0 || block_rows < 1) return -1;
+  const int64_t n_blocks = (n + block_rows - 1) / block_rows;
+  tail_start[0] = 0;
+  const int32_t rc = for_each_block(n_blocks, n_threads, [&](int64_t b) {
+    const int64_t e1 = std::min(n, (b + 1) * block_rows) * k;
+    int64_t in_tail = 0;
+    for (int64_t e = b * block_rows * k; e < e1; ++e) {
+      const int32_t c = cols[e];
+      if (c < 0 || c >= dim) return -1;
+      in_tail += vals[e] != 0.0f && code[c] == kTailCode;
+    }
+    tail_start[b + 1] = in_tail;
+    return 0;
+  });
+  if (rc != 0) return rc;
+  for (int64_t b = 0; b < n_blocks; ++b) tail_start[b + 1] += tail_start[b];
+  return 0;
+}
+
+// Second call, with the first call's tail_start and the same blocks.
+// x_hot: [n, n_hot] float32, zeroed: a row's hot entries added in entry
+// order.  cols_out: [n, k] int32, max(code, 0) of every entry, or null
+// (the plans keep the batch's own column ids).  vals_out: [n, k]
+// float32, the value of an entry of a planned column and 0.0 of every
+// other.  tail_row / tail_col / tail_val: [tail_start[n_blocks]], the
+// tail's entries in row order, then entry order.  cols_out, vals_out
+// and the tail's three need no initial value: every element is written.
+// Returns 0, or -1 at a hot rank outside [0, n_hot) (the outputs are
+// then unspecified; nothing is written outside them).  The columns
+// were checked by the first call.
+int32_t pml_split_classes_fill(const int32_t* cols, const float* vals,
+                               int64_t n, int64_t k, const int32_t* code,
+                               int64_t n_hot, int64_t block_rows,
+                               int32_t n_threads, const int64_t* tail_start,
+                               float* x_hot, int32_t* cols_out,
+                               float* vals_out, int32_t* tail_row,
+                               int32_t* tail_col, float* tail_val) {
+  if (n < 0 || k < 0 || n_hot < 0 || block_rows < 1) return -1;
+  const int64_t n_blocks = (n + block_rows - 1) / block_rows;
+  return for_each_block(n_blocks, n_threads, [&](int64_t b) {
+    const int64_t r1 = std::min(n, (b + 1) * block_rows);
+    int64_t t = tail_start[b];
+    for (int64_t r = b * block_rows; r < r1; ++r) {
+      float* hot_row = x_hot + r * n_hot;
+      for (int64_t e = r * k; e < (r + 1) * k; ++e) {
+        const int32_t c = cols[e];
+        const int32_t cls = code[c];
+        const float v = vals[e];
+        if (cols_out) cols_out[e] = cls > 0 ? cls : 0;
+        vals_out[e] = (cls >= 0 && v != 0.0f) ? v : 0.0f;
+        if (cls >= 0 || v == 0.0f) continue;
+        if (cls == kTailCode) {
+          if (t >= tail_start[b + 1]) return -1;
+          tail_row[t] = static_cast<int32_t>(r);
+          tail_col[t] = c;
+          tail_val[t] = v;
+          ++t;
+        } else {
+          const int64_t h = -1 - int64_t{cls};
+          if (h >= n_hot) return -1;
+          hot_row[h] += v;
+        }
+      }
+    }
+    return 0;
+  });
+}
 
 }  // extern "C"
 

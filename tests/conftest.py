@@ -79,3 +79,61 @@ def spans_of(tmp_path):
                         if e["event"] == "span"]
 
     return run
+
+
+@pytest.fixture
+def hot_split(monkeypatch, sha256_of):
+    """The planner's hot split under test (ISSUE 40):
+    ``hot_split.calls`` lists the calls that reached the native
+    library's column count and class split ("count", "split");
+    ``hot_split.without("the_two_entries")`` takes both away, so
+    ``np.bincount`` and ``_split_classes``' numpy body run beside the
+    other native builders, ``hot_split.without("the_library")`` the
+    whole library, as ``PHOTON_ML_TPU_NATIVE=0`` does;
+    ``hot_split.same_bytes(a, b)`` holds two plans (pairs, lists of
+    pairs) to one tree structure, every leaf to one dtype, shape and
+    content, and both to one sha256."""
+    import types
+
+    import photon_ml_tpu.native as nat
+
+    if not nat.native_available():
+        pytest.skip("native library unavailable")
+    real = {"count": nat.column_counts_native,
+            "split": nat.split_classes_native}
+    calls = []
+
+    def recording(name):
+        def entry(*args, **kwargs):
+            built = real[name](*args, **kwargs)
+            if built is not None:
+                calls.append(name)
+            return built
+        return entry
+
+    monkeypatch.setattr(nat, "column_counts_native", recording("count"))
+    monkeypatch.setattr(nat, "split_classes_native", recording("split"))
+
+    def without(what):
+        if what == "the_library":
+            monkeypatch.setenv("PHOTON_ML_TPU_NATIVE", "0")
+            monkeypatch.setattr(nat, "_lib", False)
+            assert nat.lib() is None
+        else:
+            assert what == "the_two_entries"
+            for name in ("column_counts_native", "split_classes_native"):
+                monkeypatch.setattr(nat, name, lambda *a, **k: None)
+
+    def same_bytes(a, b):
+        (leaves_a, structure_a), (leaves_b, structure_b) = (
+            jax.tree_util.tree_flatten(tree) for tree in (a, b))
+        assert structure_a == structure_b and leaves_a
+        leaves_a = [np.asarray(leaf) for leaf in leaves_a]
+        leaves_b = [np.asarray(leaf) for leaf in leaves_b]
+        for x, y in zip(leaves_a, leaves_b):
+            assert x.dtype == y.dtype and x.shape == y.shape
+            assert x.tobytes() == y.tobytes()
+        assert sha256_of(leaves_a) == sha256_of(leaves_b)
+
+    return types.SimpleNamespace(calls=calls, without=without,
+                                 same_bytes=same_bytes)

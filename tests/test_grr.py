@@ -975,6 +975,49 @@ def test_pair_leaves_equal_native_coo_levels_or_numpy_levels(
     _assert_leaves_equal(native, python)
 
 
+@pytest.mark.parametrize("without", ["the_two_entries", "the_library"])
+def test_pair_leaves_equal_native_hot_split_or_numpy_body(
+        rng, monkeypatch, hot_split, spans_of, without):
+    """A whole pair with all three column classes and a mid split: the
+    count and the class split through the native library (ISSUE 40: the
+    cold build's count, the mid split's count, ``_split_classes``)
+    against ``np.bincount`` and the numpy body, with the other native
+    builders in place and with ``PHOTON_ML_TPU_NATIVE=0``: every leaf
+    equal, one sha256 over the pair, and the stages say who ran."""
+    import photon_ml_tpu.native as native_lib
+    from photon_ml_tpu.data import grr
+
+    monkeypatch.setattr(grr, "ECONOMY_SLOTS_PER_ENTRY", 2)
+    n, k, dim = 17000, 8, 30000
+    cols, vals = _powerlaw_ell(rng, n, k, dim, x0=300.0)
+    cols[:, 0] = 0
+    vals[rng.random((n, k)) < 0.05] = 0.0
+
+    def build():
+        pair, spans = spans_of(build_grr_pair, cols, vals, dim)
+        said = {s["name"]: s["args"] for s in spans
+                if s["name"] in ("grr_hot_split", "grr_mid_split")}
+        return pair, said
+
+    native, said = build()
+    assert hot_split.calls == ["count", "split", "count"]
+    assert native.hot_ids.size and native.planned_ids.size
+    assert native.tail.nnz and native.mid_ids.size
+    hot = said["grr_hot_split"]
+    assert (hot["native"], hot["entries"]) == (1, n * k)
+    assert hot["workers"] == native_lib._split_workers(n * k) == min(
+        2, native_lib._usable_cores())
+    assert said["grr_mid_split"]["native"] == 1
+    del hot_split.calls[:]
+    hot_split.without(without)
+    numpy, said = build()
+    assert not hot_split.calls
+    hot = said["grr_hot_split"]
+    assert (hot["native"], hot["workers"], hot["entries"]) == (0, 1, n * k)
+    assert said["grr_mid_split"]["native"] == 0
+    hot_split.same_bytes(native, numpy)
+
+
 def test_col_range_split_reduces_spill(rng):
     """On power-law columns the per-range capacities must hold in the
     level-1 kernel what the single global cap pushed to overflow/COO

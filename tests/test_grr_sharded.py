@@ -368,3 +368,36 @@ def test_sharded_pairs_col_range_split(rng):
         leaves, tdef = jax.tree.flatten(p)
         assert tdef == t0
         assert [lf.shape for lf in leaves] == s0
+
+
+@pytest.mark.parametrize("without", ["the_two_entries", "the_library"])
+def test_sharded_pairs_leaves_equal_native_hot_split_or_numpy_body(
+        rng, monkeypatch, hot_split, without):
+    """Four shards with all three column classes: every shard's count
+    and class split through the native library (ISSUE 40: the mesh
+    build runs the resident build's two functions per shard) against
+    ``np.bincount`` and the numpy body, with the other native builders
+    in place and with ``PHOTON_ML_TPU_NATIVE=0``: every leaf of every
+    shard's pair equal, and one sha256 over the list."""
+    from photon_ml_tpu.data import grr
+
+    monkeypatch.setattr(grr, "ECONOMY_SLOTS_PER_ENTRY", 2)
+    n_shards, per, d, k = 4, 3000, 20000, 8
+    cols, vals = _ell(rng, n_shards * per, d, k, hot_col=7, skew=True)
+    vals[rng.random(vals.shape) < 0.05] = 0.0
+
+    def build():
+        return build_sharded_grr_pairs(
+            [cols[i * per:(i + 1) * per] for i in range(n_shards)],
+            [vals[i * per:(i + 1) * per] for i in range(n_shards)], d)
+
+    native = build()
+    assert hot_split.calls == ["count"] * n_shards + ["split"] * n_shards
+    for pair in native:
+        assert pair.hot_ids.size and pair.planned_ids.size
+        assert pair.tail.nnz
+    del hot_split.calls[:]
+    hot_split.without(without)
+    numpy = build()
+    assert not hot_split.calls
+    hot_split.same_bytes(native, numpy)

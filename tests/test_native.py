@@ -409,3 +409,274 @@ def test_native_projection_refuses_what_would_write_outside_a_block(rng):
                 dict(indptr=np.array([0, 2, 3, 5]))):
         with pytest.raises(ValueError, match="re_project_native"):
             re_project_native(**dict(good, **bad))
+
+
+# -- the planner's hot split through the native library (ISSUE 40) -------------
+
+def _split_case(case, rng):
+    """(cols, vals, dim, the classes ``classify_columns`` gives them) of
+    one case: an ELL batch whose columns follow a power law, column 0 in
+    every row."""
+    from photon_ml_tpu.data.grr import classify_columns
+
+    n, k, dim, windows, hot_threshold = 600, 7, 900, 100, 64
+    if case == "many_row_blocks":
+        n = 5000
+    cols = (dim * rng.random((n, k)) ** 3.0).astype(np.int32)
+    cols[:, 0] = 0
+    vals = rng.normal(size=(n, k)).astype(np.float32)
+    if case == "no_tail":
+        windows = 1     # four slots hold any column; one table window
+    elif case == "explicit_zeros":
+        vals[rng.random((n, k)) < 0.3] = 0.0
+        vals[::7, 0] = -0.0
+    elif case == "ell_padding_rows":
+        cols[-40:], vals[-40:] = 0, 0.0
+        cols[5, 3:], vals[5, 3:] = 0, 0.0
+    elif case == "a_row_repeats_a_hot_column":
+        cols = np.maximum(cols, 1)
+        cols[:, 0] = cols[:, 1] = cols[:, 4] = 0
+        vals[:, 0], vals[:, 1], vals[:, 4] = 1e8, 1.0, -1e8
+    elif case == "zero_hot_columns":
+        hot_threshold = 10 * n
+    elif case == "every_column_hot":
+        dim, windows, hot_threshold = 6, 1, 0
+        cols = rng.integers(0, dim, (n, k)).astype(np.int32)
+    elif case == "an_empty_batch":
+        cols, vals = cols[:0], vals[:0]
+    elif case not in ("a_tail_and_the_compact_remap", "many_row_blocks"):
+        raise AssertionError(case)
+    counts = np.bincount(cols[vals != 0].reshape(-1), minlength=dim)
+    return cols, vals, dim, classify_columns(counts, windows,
+                                             hot_threshold, max_hot=128)
+
+
+SPLIT_CASES = ["no_tail", "a_tail_and_the_compact_remap", "explicit_zeros",
+               "ell_padding_rows", "a_row_repeats_a_hot_column",
+               "zero_hot_columns", "every_column_hot", "an_empty_batch",
+               "many_row_blocks"]
+
+
+def _split_leaves(split):
+    """The arrays of ``_split_classes``' result, in order."""
+    x_hot, (cols, vals, _width), tail = split
+    return [x_hot, cols, vals] + list(tail or ())
+
+
+def _both_splits(monkeypatch, cols, vals, dim, classes):
+    """((counts, split, threads) of the native library, the numpy
+    bodies' (counts, split))."""
+    import photon_ml_tpu.native as nat
+    from photon_ml_tpu.data import grr
+
+    counts, count_threads = grr._column_counts(cols, vals, dim)
+    split, split_threads = grr._split_classes_threads(
+        cols, vals, dim, len(cols), classes)
+    assert count_threads == split_threads >= 1
+    with monkeypatch.context() as patch:
+        patch.setattr(nat, "lib", lambda: None)
+        numpy_counts, none = grr._column_counts(cols, vals, dim)
+        numpy_split, no_threads = grr._split_classes_threads(
+            cols, vals, dim, len(cols), classes)
+    assert (none, no_threads) == (0, 0)
+    return (counts, split, split_threads), (numpy_counts, numpy_split)
+
+
+@pytest.fixture
+def assert_the_same_split(sha256_of):
+    """The counts and every array of two hot splits: equal in dtype,
+    shape and bytes, and by one sha256 over all of them; the planned
+    class's width and whether there is a tail alike."""
+    def check(got, want):
+        (got_counts, got_split), (want_counts, want_split) = got, want
+        assert got_split[1][2] == want_split[1][2]
+        assert (got_split[2] is None) == (want_split[2] is None)
+        got_leaves = [got_counts] + _split_leaves(got_split)
+        want_leaves = [want_counts] + _split_leaves(want_split)
+        assert len(got_leaves) == len(want_leaves)
+        for a, b in zip(got_leaves, want_leaves):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            assert a.tobytes() == b.tobytes()
+        assert sha256_of(got_leaves) == sha256_of(want_leaves)
+    return check
+
+
+@pytest.mark.parametrize("blocks", ["inline", "threaded"])
+@pytest.mark.parametrize("case", SPLIT_CASES)
+def test_native_hot_split_is_the_numpy_bodys_bytes(
+        rng, monkeypatch, assert_the_same_split, case, blocks):
+    """``column_counts_native`` against ``np.bincount`` and
+    ``split_classes_native`` against ``_split_classes``' numpy body:
+    the counts, ``x_hot``, the planned class's columns and values and
+    the tail's three arrays, in one block on the calling thread and cut
+    into blocks of 64 entries on five threads."""
+    import photon_ml_tpu.native as nat
+
+    cols, vals, dim, classes = _split_case(case, rng)
+    if blocks == "threaded":
+        monkeypatch.setattr(nat, "_SPLIT_BLOCK", 64)
+        monkeypatch.setattr(nat, "_usable_cores", lambda: 5)
+    (counts, split, threads), numpy = _both_splits(
+        monkeypatch, cols, vals, dim, classes)
+    assert_the_same_split((counts, split), numpy)
+    assert threads == (5 if blocks == "threaded" and cols.size else 1)
+    x_hot, (planned_cols, planned_vals, width), tail = split
+    assert counts.dtype == np.int64 and counts.shape == (dim,)
+    assert x_hot.shape == (len(cols), classes.hot.size)
+    if case in ("no_tail", "every_column_hot"):
+        assert classes.planned is None and tail is None
+        assert planned_cols is cols and width == dim
+    elif case != "an_empty_batch":
+        assert classes.tail.size and len(tail[0]) == classes.tail_nnz
+        assert width == classes.planned.size < dim
+        assert np.count_nonzero(planned_vals) == classes.planned_nnz
+    if case == "zero_hot_columns":
+        assert x_hot.shape[1] == 0
+    if case == "every_column_hot":
+        assert not planned_vals.any()
+    if case == "a_row_repeats_a_hot_column":
+        # (1e8 + 1) - 1e8 in float32, in entry order: the 1 is lost
+        assert classes.hot[0] == 0 and not x_hot[:, 0].any()
+    if case == "ell_padding_rows":
+        assert not x_hot[-40:].any() and not planned_vals[-40:].any()
+
+
+def test_native_hot_split_on_one_worker_is_the_bytes_of_many(
+        rng, monkeypatch, assert_the_same_split):
+    """The same row blocks taken by one thread, in order, and by seven
+    at once: the count's sums commute and every other output has its
+    place, so both are the serial bytes."""
+    import photon_ml_tpu.native as nat
+
+    cols, vals, dim, classes = _split_case("many_row_blocks", rng)
+    monkeypatch.setattr(nat, "_SPLIT_BLOCK", 256)
+    built = {}
+    for workers in (1, 7):
+        monkeypatch.setattr(nat, "_usable_cores", lambda: workers)
+        (counts, split, threads), numpy = _both_splits(
+            monkeypatch, cols, vals, dim, classes)
+        assert threads == workers
+        built[workers] = (counts, split)
+    assert_the_same_split(built[1], built[7])
+    assert_the_same_split(built[1], numpy)
+
+
+def test_native_hot_split_takes_its_threads_at_the_blocks_own_size(
+        rng, monkeypatch, assert_the_same_split):
+    """Nothing patched but the count of cores: a batch one entry under
+    two blocks of ``_SPLIT_BLOCK`` runs inline, one of three blocks on
+    three threads."""
+    import photon_ml_tpu.native as nat
+    from photon_ml_tpu.data.grr import classify_columns
+
+    monkeypatch.setattr(nat, "_usable_cores", lambda: 6)
+    k, dim = 8, 40000
+    for n, workers in ((2 * nat._SPLIT_BLOCK // k - 1, 1),
+                       (3 * nat._SPLIT_BLOCK // k, 3)):
+        cols = (dim * rng.random((n, k)) ** 2.2).astype(np.int32)
+        cols[:, 0] = 0
+        vals = rng.normal(size=(n, k)).astype(np.float32)
+        counts = np.bincount(cols.reshape(-1), minlength=dim)
+        classes = classify_columns(counts, 200, 500, max_hot=128)
+        assert classes.hot.size and classes.tail.size
+        (counts, split, threads), numpy = _both_splits(
+            monkeypatch, cols, vals, dim, classes)
+        assert threads == workers
+        assert_the_same_split((counts, split), numpy)
+
+
+def test_native_hot_split_callers_at_once_all_return_the_serial_bytes(
+        rng, monkeypatch, sha256_of):
+    """Four threads count and split one batch at once, as the plan
+    build's column chain counts beside the row part: they share nothing
+    but their inputs, none waits on another (joined under a time limit),
+    and each gets the bytes of a call made alone."""
+    import threading
+
+    import photon_ml_tpu.native as nat
+    from photon_ml_tpu.data import grr
+
+    cols, vals, dim, classes = _split_case("many_row_blocks", rng)
+    monkeypatch.setattr(nat, "_SPLIT_BLOCK", 512)
+    monkeypatch.setattr(nat, "_usable_cores", lambda: 4)
+
+    def once():
+        return sha256_of(
+            [grr._column_counts(cols, vals, dim)[0]]
+            + _split_leaves(grr._split_classes(cols, vals, dim, len(cols),
+                                               classes)))
+
+    want = once()
+    got = [None] * 4
+
+    def call(i):
+        got[i] = once()
+
+    threads = [threading.Thread(target=call, args=(i,), daemon=True)
+               for i in range(len(got))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    assert got == [want] * len(got)
+
+
+@pytest.mark.parametrize("bad", [-1, 900, 2 ** 31 - 1])
+@pytest.mark.parametrize("where", ["first_block", "last_block",
+                                   "a_padding_entry"])
+def test_native_hot_split_refuses_a_column_outside_the_table(
+        rng, monkeypatch, where, bad):
+    """A column id outside [0, dim) raises from either entry, in any
+    block, and is not written through: the count leaves padding entries
+    unread as ``np.bincount`` over the nonzeros does, the split reads
+    every entry's class as ``code[cols]`` does."""
+    import photon_ml_tpu.native as nat
+    from photon_ml_tpu.native import (
+        column_counts_native,
+        split_classes_native,
+    )
+
+    cols, vals, dim, classes = _split_case("many_row_blocks", rng)
+    monkeypatch.setattr(nat, "_SPLIT_BLOCK", 512)
+    monkeypatch.setattr(nat, "_usable_cores", lambda: 3)
+    row = {"first_block": 2, "last_block": len(cols) - 1,
+           "a_padding_entry": 700}[where]
+    cols[row, 3] = bad
+    code = np.zeros(dim, np.int32)
+    if where == "a_padding_entry":
+        vals[row, 3] = 0.0
+        counts, _workers = column_counts_native(cols, vals, dim)
+        assert counts.sum() == np.count_nonzero(vals)
+    else:
+        with pytest.raises(ValueError, match="column id out of range"):
+            column_counts_native(cols, vals, dim)
+    with pytest.raises(ValueError, match="column id out of range"):
+        split_classes_native(cols, vals, code, len(cols), n_hot=0,
+                             remap=True)
+    # a class table that names a hot rank the block does not have
+    cols[row, 3] = 1
+    code[1] = -3
+    vals[row, 3] = 1.0
+    with pytest.raises(ValueError, match="hot rank out of range"):
+        split_classes_native(cols, vals, code, len(cols), n_hot=2,
+                             remap=True)
+
+
+def test_native_column_counts_beyond_int32_raises_and_does_not_wrap():
+    from photon_ml_tpu.native import column_counts_native
+
+    cols = np.array([[0, 2 ** 32 + 1]], np.int64)
+    with pytest.raises(ValueError, match="exceeds int32"):
+        column_counts_native(cols, np.ones((1, 2), np.float32), 4)
+
+
+def test_native_hot_split_says_so_where_its_outputs_cannot_be_allocated(
+        rng, capfd):
+    """A table no machine holds: the wrapper says so on stderr and
+    returns None, and the caller's numpy body decides."""
+    from photon_ml_tpu.native import column_counts_native
+
+    cols, vals, _dim, _classes = _split_case("no_tail", rng)
+    assert column_counts_native(cols, vals, 10 ** 15) is None
+    assert "column_counts_native: no memory" in capfd.readouterr().err

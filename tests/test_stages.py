@@ -92,7 +92,8 @@ COUNTS = {
     "to_ell": {"rows", "dim", "k", "padded_rows"},
     "grr_plan_build": {"rows", "k", "dim", "nnz", "directions", "spill",
                        "cache_hit", "tail_len"} | CLASS_COUNTS,
-    "grr_hot_split": {"hot_columns"},
+    "grr_hot_split": {"hot_columns", "native", "workers", "entries",
+                      "count_s", "classify_s", "split_s"},
     "grr_plan_ranges": {"ranges"},
     "grr_row_part": {"parent", "lo", "hi", "cap", "spill"},
     "grr_mid_split": {"parent", "mid_columns", "spill"},
@@ -243,6 +244,31 @@ def test_traced_fit_counts_say_what_was_done(traced, tiny):
     (validation,) = _events(traced, "validation")
     assert counts[validation]["rows"] == valid.n
     assert len(_events(traced, "place_batch")) == 2   # the fence, the rest
+
+
+def test_traced_fit_says_who_ran_the_hot_split(traced):
+    """``grr_hot_split``: the ELL slots its two passes scanned, whether
+    both ran in the native library and on how many threads, and the
+    seconds of its three parts (ISSUE 40).  The tiny fit is under a row
+    window, so its mid split counts no column and names no counter
+    (``test_mid_split_says_who_counted_where_it_counts``)."""
+    from photon_ml_tpu import native
+
+    counts = traced["counts"]
+    (build,) = _events(traced, "grr_plan_build")
+    (hot,) = _events(traced, "grr_hot_split")
+    (mid,) = _events(traced, "grr_mid_split")
+    entries = counts[build]["rows"] * counts[build]["k"]
+    assert counts[hot]["entries"] == entries >= counts[build]["nnz"]
+    library = int(native.native_available())
+    assert counts[hot]["native"] == library
+    assert counts[hot]["workers"] == (
+        native._split_workers(entries) if library else 1)
+    parts = [counts[hot][part]
+             for part in ("count_s", "classify_s", "split_s")]
+    assert all(seconds >= 0 for seconds in parts)
+    assert sum(parts) <= hot[1] / 1e9 + 1e-3
+    assert counts[build]["rows"] < 16384 and "native" not in counts[mid]
 
 
 def test_traced_fit_scans_each_direction_inside_its_chain_stage(traced):
@@ -441,6 +467,21 @@ def routed_build(tmp_path_factory):
     spans = [e for e in read_run_log(str(out / "run_log.jsonl"))
              if e["event"] == "span"]
     return spans, routed
+
+
+def test_mid_split_says_who_counted_where_it_counts(routed_build):
+    """A batch of a row window or more: ``grr_mid_split`` counts every
+    column again and says the library did (``native`` 1), and
+    ``grr_hot_split`` ran its passes over two blocks, on two threads
+    where the process has them (ISSUE 40)."""
+    from photon_ml_tpu.native import _usable_cores
+
+    spans, _routed = routed_build
+    (hot,) = [s["args"] for s in spans if s["name"] == "grr_hot_split"]
+    (mid,) = [s["args"] for s in spans if s["name"] == "grr_mid_split"]
+    assert mid["native"] == 1
+    assert (hot["native"], hot["entries"]) == (1, 20000 * 8)
+    assert hot["workers"] == min(2, _usable_cores())
 
 
 def test_grr_routes_stage_only_for_calls_that_went_to_the_threads(
