@@ -108,6 +108,10 @@ class OptimizerSettings:
     # the run log's cd_coordinate events.  Costs two [max_iters+1]
     # arrays per solve.
     track_states: bool = False
+    # TRON's inner conjugate-gradient loop: at most ``cg_max_iters``
+    # Hessian-vector products an outer iteration (ignored by L-BFGS /
+    # OWL-QN).
+    cg_max_iters: int = 50
 
     def validate(self) -> None:
         # Coerce a raw-string variance_type to the enum ONCE, loudly
@@ -122,6 +126,10 @@ class OptimizerSettings:
             raise ValueError("max_iters must be positive")
         if self.tolerance <= 0:
             raise ValueError("tolerance must be positive")
+        if (isinstance(self.cg_max_iters, bool)
+                or not isinstance(self.cg_max_iters, int)
+                or self.cg_max_iters <= 0):
+            raise ValueError("cg_max_iters must be a positive integer")
         if self.reg_weight < 0:
             raise ValueError("reg_weight must be non-negative")
         if not 0.0 <= self.elastic_net_alpha <= 1.0:

@@ -120,6 +120,7 @@ def _optimizer_config(settings: OptimizerSettings) -> OptimizerConfig:
         max_iters=settings.max_iters,
         tolerance=settings.tolerance,
         track_states=settings.track_states,
+        cg_max_iters=settings.cg_max_iters,
     )
 
 

@@ -99,22 +99,37 @@ def _re_diag_reduce(diag):
     return conv, iters
 
 
+# TRON's counts (``optim.base.OptimizationResult``), where it made them
+_TRON_COUNTS = ("cg_steps", "hvp_passes", "forward_passes")
+
+
 def _solve_counts(diag) -> dict:
     """What one solve did, for the ``coord_train`` stage (a scalar
-    ``OptimizationResult`` only; the random effects hand over lists and
-    dicts): its iterations, the line-search trials they paid (the
-    solve's own count where a trial is a contraction, else the
-    tracker's plane, where states are tracked) and the forward
+    ``OptimizationResult``): its iterations, the line-search trials they
+    paid (the solve's own count where a trial is a contraction, else
+    the tracker's plane, where states are tracked) and the forward
     contractions X·v, where the solver counts them: ``iterations + 1``
-    along the margins, ``1 + ls_trials`` for an L1 coordinate
-    (``optim.base.OptimizationResult``).  One device→host copy for all
-    of them."""
+    along the margins, ``1 + ls_trials`` for an L1 coordinate; TRON's
+    CG steps and Hessian-vector products beside them.  A random
+    effect's per-bucket list of batched TRON results gives each of
+    TRON's counts summed over its lanes, as ``lane_<count>``: no stage
+    of a random effect carries a key that the fixed effect's readers
+    sum.  One device→host copy for all of them."""
+    if isinstance(diag, (list, tuple)) and diag \
+            and isinstance(diag[0], OptimizationResult) \
+            and diag[0].hvp_passes is not None:
+        lanes = jax.device_get([[getattr(r, key) for key in _TRON_COUNTS]
+                                for r in diag])
+        return {"lane_" + key: int(sum(np.sum(bucket[i], dtype=np.int64)
+                                       for bucket in lanes))
+                for i, key in enumerate(_TRON_COUNTS)}
     if not isinstance(diag, OptimizationResult) \
             or jnp.ndim(diag.iterations) != 0:
         return {}
-    iterations, passes, counted, tracked, trials = jax.device_get((
+    iterations, passes, counted, tracked, trials, cg, hvp = jax.device_get((
         diag.iterations, diag.forward_passes, diag.ls_trials,
-        diag.tracker.count, diag.tracker.ls_trials))
+        diag.tracker.count, diag.tracker.ls_trials, diag.cg_steps,
+        diag.hvp_passes))
     out = {"solver_iterations": int(iterations)}
     if counted is not None:              # the solve's own count, untracked
         out["ls_trials"] = int(counted)
@@ -122,6 +137,9 @@ def _solve_counts(diag) -> dict:
         out["ls_trials"] = int(np.nansum(trials))
     if passes is not None:
         out["forward_passes"] = int(passes)
+    if hvp is not None:
+        out["cg_steps"] = int(cg)
+        out["hvp_passes"] = int(hvp)
     return out
 
 
@@ -154,6 +172,9 @@ def _diag_fields(diag) -> dict:
         if getattr(diag, "ls_trials", None) is not None:
             out["ls_trials"] = int(diag.ls_trials)
             out["forward_passes"] = int(diag.forward_passes)
+        if getattr(diag, "hvp_passes", None) is not None:
+            out.update({key: int(getattr(diag, key))
+                        for key in _TRON_COUNTS})
         tracker = getattr(diag, "tracker", None)
         if tracker is not None and int(tracker.count) > 0:
             # Per-solver-iteration convergence trace (reference

@@ -158,13 +158,20 @@ class OptimizationResult:
     # its last trial kept); a solve that evaluates each trial from w (a
     # swept lane, a bare callable) 1 + ls_trials + iterations, the
     # accepted point of every search being evaluated once more.  None
-    # from a solver that counts none (TRON, the streamed solvers).
+    # from a solver that counts none (the streamed solvers).  TRON's: 1 for
+    # its start, 1 for each outer iteration's evaluation at w + p, and 2
+    # for each Hessian-vector product (X·w for its curvature, and X·v).
     forward_passes: Array | None = None
     # int32 line-search trials, where every trial is a forward
     # contraction (then a cost of its own); None along the margins, where
     # a trial is [rows]-vector work and the tracker's plane has them when
     # tracked.
     ls_trials: Array | None = None
+    # int32, TRON's alone: the inner conjugate-gradient steps over all
+    # outer iterations, and the Hessian-vector products they and the
+    # ratio's one an outer iteration made (cg_steps + iterations).
+    cg_steps: Array | None = None
+    hvp_passes: Array | None = None
 
 
 def grad_converged(g_norm: Array, g0_norm: Array, tolerance: float) -> Array:

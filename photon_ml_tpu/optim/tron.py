@@ -138,6 +138,10 @@ class _TronCarry:
     converged: Array
     g0_norm: Array
     tracker: StatesTracker
+    # what the solve paid, counted whether or not states are tracked
+    cg_steps: Array
+    hvp_passes: Array
+    forward_passes: Array
 
 
 def tron_solve(
@@ -166,6 +170,9 @@ def tron_solve(
         iteration=jnp.asarray(0, jnp.int32),
         done=already, converged=already,
         g0_norm=g0_norm, tracker=tracker,
+        cg_steps=jnp.asarray(0, jnp.int32),
+        hvp_passes=jnp.asarray(0, jnp.int32),
+        forward_passes=jnp.asarray(1, jnp.int32),
     )
 
     def cond(c: _TronCarry):
@@ -219,6 +226,11 @@ def tron_solve(
             if config.track_states else c.tracker
         )
 
+        hvps = cg_iters + 1   # one product a CG step, one for `predicted`
+        # Forward contractions, counted where they are made: the
+        # evaluation at w + p, and X·w and X·v in each product.  (XLA
+        # drops a product's X·w where the loss's d2 reads no margin, as
+        # the squared loss's does.)
         keep = lambda new, old: jnp.where(c.done, old, new)
         return _TronCarry(
             w=keep(w, c.w), f=keep(f, c.f), g=keep(g, c.g),
@@ -228,6 +240,10 @@ def tron_solve(
             converged=jnp.logical_or(c.converged, conv),
             g0_norm=c.g0_norm,
             tracker=jax.tree.map(keep, tracker, c.tracker),
+            cg_steps=keep(c.cg_steps + cg_iters, c.cg_steps),
+            hvp_passes=keep(c.hvp_passes + hvps, c.hvp_passes),
+            forward_passes=keep(c.forward_passes + 1 + 2 * hvps,
+                                c.forward_passes),
         )
 
     final = jax.lax.while_loop(cond, body, init)
@@ -238,4 +254,7 @@ def tron_solve(
         iterations=final.iteration,
         converged=final.converged,
         tracker=final.tracker,
+        forward_passes=final.forward_passes,
+        cg_steps=final.cg_steps,
+        hvp_passes=final.hvp_passes,
     )
