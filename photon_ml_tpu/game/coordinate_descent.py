@@ -109,8 +109,10 @@ def _solve_counts(diag) -> dict:
     paid (the solve's own count where a trial is a contraction, else
     the tracker's plane, where states are tracked) and the forward
     contractions X·v, where the solver counts them: ``iterations + 1``
-    along the margins, ``1 + ls_trials`` for an L1 coordinate; TRON's
-    CG steps and Hessian-vector products beside them.  A random
+    along the margins; for an L1 coordinate ``1 + ls_trials −
+    walked_trials`` and one X·d for each search that walked, with
+    ``walked_trials`` (the trials the orthant projection clipped nothing
+    of) beside them; TRON's CG steps and Hessian-vector products.  A random
     effect's per-bucket list of batched TRON results gives each of
     TRON's counts summed over its lanes, as ``lane_<count>``: no stage
     of a random effect carries a key that the fixed effect's readers
@@ -126,10 +128,11 @@ def _solve_counts(diag) -> dict:
     if not isinstance(diag, OptimizationResult) \
             or jnp.ndim(diag.iterations) != 0:
         return {}
-    iterations, passes, counted, tracked, trials, cg, hvp = jax.device_get((
-        diag.iterations, diag.forward_passes, diag.ls_trials,
-        diag.tracker.count, diag.tracker.ls_trials, diag.cg_steps,
-        diag.hvp_passes))
+    iterations, passes, counted, tracked, trials, cg, hvp, walked = \
+        jax.device_get((diag.iterations, diag.forward_passes,
+                        diag.ls_trials, diag.tracker.count,
+                        diag.tracker.ls_trials, diag.cg_steps,
+                        diag.hvp_passes, diag.walked_trials))
     out = {"solver_iterations": int(iterations)}
     if counted is not None:              # the solve's own count, untracked
         out["ls_trials"] = int(counted)
@@ -137,6 +140,8 @@ def _solve_counts(diag) -> dict:
         out["ls_trials"] = int(np.nansum(trials))
     if passes is not None:
         out["forward_passes"] = int(passes)
+    if walked is not None:
+        out["walked_trials"] = int(walked)
     if hvp is not None:
         out["cg_steps"] = int(cg)
         out["hvp_passes"] = int(hvp)
@@ -172,6 +177,8 @@ def _diag_fields(diag) -> dict:
         if getattr(diag, "ls_trials", None) is not None:
             out["ls_trials"] = int(diag.ls_trials)
             out["forward_passes"] = int(diag.forward_passes)
+        if getattr(diag, "walked_trials", None) is not None:
+            out["walked_trials"] = int(diag.walked_trials)
         if getattr(diag, "hvp_passes", None) is not None:
             out.update({key: int(getattr(diag, key))
                         for key in _TRON_COUNTS})

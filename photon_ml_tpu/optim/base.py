@@ -153,16 +153,19 @@ class OptimizationResult:
     tracker: StatesTracker
     # int32 forward contractions X·v the solve made, counted in its
     # carry: L-BFGS along the margins makes iterations + 1; OWL-QN
-    # through a ``MarginSplit`` 1 + ls_trials (a trial contracts its own
-    # point, and the accepted point's gradient is taken from the margins
-    # its last trial kept); a solve that evaluates each trial from w (a
+    # through a ``MarginSplit`` 1 + ls_trials − walked_trials + the
+    # searches that contracted an X·d (a trial the orthant projection
+    # clips contracts its own point, one it clips nothing is scored along
+    # m + a·X·d, and the accepted point's gradient is taken from the
+    # margins its last trial kept), and batched by vmap, where it walks
+    # nothing, 1 + ls_trials; a solve that evaluates each trial from w (a
     # swept lane, a bare callable) 1 + ls_trials + iterations, the
     # accepted point of every search being evaluated once more.  None
     # from a solver that counts none (the streamed solvers).  TRON's: 1 for
     # its start, 1 for each outer iteration's evaluation at w + p, and 2
     # for each Hessian-vector product (X·w for its curvature, and X·v).
     forward_passes: Array | None = None
-    # int32 line-search trials, where every trial is a forward
+    # int32 line-search trials, where a trial may be a forward
     # contraction (then a cost of its own); None along the margins, where
     # a trial is [rows]-vector work and the tracker's plane has them when
     # tracked.
@@ -172,6 +175,10 @@ class OptimizationResult:
     # ratio's one an outer iteration made (cg_steps + iterations).
     cg_steps: Array | None = None
     hvp_passes: Array | None = None
+    # int32, OWL-QN's through a ``MarginSplit`` and unbatched alone: the
+    # trials of ls_trials the orthant projection clipped nothing of,
+    # scored along m + a·X·d without a contraction of their own.
+    walked_trials: Array | None = None
 
 
 def grad_converged(g_norm: Array, g0_norm: Array, tolerance: float) -> Array:

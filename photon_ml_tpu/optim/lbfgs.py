@@ -30,13 +30,21 @@ TPU-native design notes:
   line-search trial is [rows]-vector work on ``m + α·X·d``: a solve makes
   ``iterations + 1`` forward contractions and as many transposed ones,
   where evaluating each trial from w pays one more forward contraction a
-  trial.  OWL-QN's orthant projection breaks ``w⁺ = w + α·d``, so with L1
-  each trial contracts ``X·w⁺`` anew; but the split still lets the search
-  keep the margins of the trial it scored last, and the accepted point's
-  gradient is taken from them: ``1 + ls_trials`` forward contractions.  A
-  bare ``value_and_grad`` callable shows no margins, so each trial is a
-  whole evaluation and so is the accepted point's gradient: ``1 +
-  ls_trials + iterations``.  Both counts ride in the carry.
+  trial.  OWL-QN's orthant projection bends ``w⁺ = w + α·d`` only where
+  it clips a coordinate: a trial it clips contracts ``X·w⁺`` anew, one
+  it clips nothing is scored along ``m + α·X·d`` like L-BFGS's, ``X·d``
+  contracted by the first such trial of a search and kept for the rest
+  (a search from w = 0 clips nothing).  The search keeps the margins of
+  the trial it ends on, and the accepted point's gradient is taken from
+  them: ``1 + ls_trials − walked_trials + xd_searches`` forward
+  contractions, the last the searches that contracted an ``X·d``.  Under
+  ``vmap`` a ``lax.cond`` whose predicate differs by lane runs both
+  branches, so a batched solve (the random effects' per-entity lanes)
+  does not walk: each of its trials contracts its own point, ``1 +
+  ls_trials``, the program it had before the walk.  A bare
+  ``value_and_grad`` callable shows no margins, so each trial is a whole
+  evaluation and so is the accepted point's gradient: ``1 + ls_trials +
+  iterations``.  The counts ride in the carry.
 """
 
 from __future__ import annotations
@@ -44,6 +52,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from flax import struct
+# the type of a value traced for vmap, which JAX names nowhere public
+from jax._src.interpreters.batching import BatchTracer
 
 from photon_ml_tpu.optim.base import (
     MarginSplit,
@@ -79,6 +89,9 @@ class _LbfgsCarry:
     margins: Array | None = None         # [n] X·w + o
     # counted in every mode:
     forward_passes: Array | None = None  # int32 — contractions X·v so far
+    # counted where OWL-QN walks the trials that clip nothing, else None:
+    ls_trials: Array | None = None       # int32 — line-search trials so far
+    walked_trials: Array | None = None   # int32 — of them along m + α·X·d
 
 
 def _pseudo_gradient(g: Array, w: Array, l1: Array) -> Array:
@@ -164,17 +177,18 @@ def _line_search(
     For OWL-QN (``xi`` given) trial points are projected onto the starting
     orthant and the slope uses the *actual* displacement x⁺ − x (which may
     differ from α·d where coordinates were clipped to zero).
-    ``value_fn(α, x⁺) → (f, kept)`` scores a trial: given α, a caller that
-    knows the objective along the step need not start from x⁺; ``kept`` is
-    whatever it wants back of the trial the search ended on (a pytree,
-    None for nothing).
+    ``value_fn(α, x⁺, kept) → (f, kept)`` scores a trial: given α, a
+    caller that knows the objective along the step need not start from
+    x⁺; ``kept`` is whatever it wants of the trial (a pytree, None for
+    nothing), handed to the next trial (None to the first) and returned
+    of the trial the search ended on.
     """
 
-    def trial(alpha):
+    def trial(alpha, kept):
         w_try = w + alpha * d
         if xi is not None:
             w_try = jnp.where(jnp.sign(w_try) == xi, w_try, 0.0)
-        return (w_try, *value_fn(alpha, w_try))
+        return (w_try, *value_fn(alpha, w_try, kept))
 
     def accepts(w_try, f_try):
         return f_try <= f0 + config.ls_c1 * jnp.vdot(pg, w_try - w)
@@ -187,13 +201,13 @@ def _line_search(
         )
 
     def body(state):
-        alpha, _, _, _, steps = state
+        alpha, _, _, kept, steps = state
         alpha = alpha * config.ls_shrink
-        return (alpha, *trial(alpha), steps + 1)
+        return (alpha, *trial(alpha, kept), steps + 1)
 
     alpha0 = jnp.asarray(1.0, w.dtype)
     alpha, w_new, f_new, kept, steps = jax.lax.while_loop(
-        cond, body, (alpha0, *trial(alpha0), jnp.asarray(0, jnp.int32))
+        cond, body, (alpha0, *trial(alpha0, None), jnp.asarray(0, jnp.int32))
     )
     ok = f_new < f0  # any strict decrease counts; stall otherwise
     return w_new, f_new, ok, alpha, steps + 1, kept
@@ -204,28 +218,32 @@ def _by_whole_evaluations(value_and_grad: ValueAndGrad, l1_vec):
     every trial, and the accepted point once more, from w.
     ``(start, open_search)``:
 
-    - ``start(w0) → (margins, forward_passes, f_smooth, g)``;
-    - ``open_search(c, d) → (trial, accept)``: ``trial(α, w_try) → (f,
-      kept)`` scores a point of the search and says what of it to keep,
-      ``accept(α, w_new, trials, kept) → (margins, forward_passes, g)``
-      takes the gradient where the search ended, after ``trials`` trials,
-      handed what its last trial kept.
+    - ``start(w0) → (margins, counts, f_smooth, g)``;
+    - ``open_search(c, d) → (trial, accept)``: ``trial(α, w_try, kept) →
+      (f, kept)`` scores a point of the search, handed what the trial
+      before it kept, and says what of it to keep; ``accept(α, w_new,
+      trials, kept) → (margins, counts, g)`` takes the gradient where the
+      search ended, after ``trials`` trials, handed what its last trial
+      kept.
 
-    This mode carries no margins and keeps nothing.  Each evaluation is
-    one forward contraction: the start, every trial, every accepted
+    ``counts`` are the carry's ``(forward_passes, ls_trials,
+    walked_trials)``, None where a mode does not count one.  This mode
+    carries no margins, keeps nothing and counts the contractions alone.
+    Each evaluation is one: the start, every trial, every accepted
     point, so its trials are ``forward_passes − 1 − iterations``."""
 
     def start(w0):
-        return (None, jnp.asarray(1, jnp.int32), *value_and_grad(w0))
+        return (None, (jnp.asarray(1, jnp.int32), None, None),
+                *value_and_grad(w0))
 
     def open_search(c, d):
-        def trial(alpha, w_try):
+        def trial(alpha, w_try, kept):
             f, _ = value_and_grad(w_try)
             return (f if l1_vec is None
                     else f + jnp.sum(l1_vec * jnp.abs(w_try))), None
 
         def accept(alpha, w_new, trials, kept):
-            return (None, c.forward_passes + trials + 1,
+            return (None, (c.forward_passes + trials + 1, None, None),
                     value_and_grad(w_new)[1])
 
         return trial, accept
@@ -238,7 +256,8 @@ def _start_at_margins(split: MarginSplit):
 
     def start(w0):
         m0 = split.margins(w0)
-        return (m0, jnp.asarray(1, jnp.int32), *split.value_and_grad(m0, w0))
+        return (m0, (jnp.asarray(1, jnp.int32), None, None),
+                *split.value_and_grad(m0, w0))
 
     return start
 
@@ -253,12 +272,13 @@ def _along_margins(split: MarginSplit):
     def open_search(c, d):
         xd, passes = split.margin_step(d), c.forward_passes + 1
 
-        def trial(alpha, w_try):
+        def trial(alpha, w_try, kept):
             return split.value(c.margins + alpha * xd, w_try), None
 
         def accept(alpha, w_new, trials, kept):
             m_new = c.margins + alpha * xd
-            return m_new, passes, split.value_and_grad(m_new, w_new)[1]
+            return (m_new, (passes, None, None),
+                    split.value_and_grad(m_new, w_new)[1])
 
         return trial, accept
 
@@ -266,26 +286,81 @@ def _along_margins(split: MarginSplit):
 
 
 def _keeping_margins(split: MarginSplit, l1_vec):
-    """And for a split objective whose step the orthant projection bends
-    (OWL-QN): a trial's margins must be contracted from its own point,
-    ``X·w_try + o``, but the search keeps those of the trial it ends on,
-    and the accepted point's gradient is taken from them.  One forward
-    contraction at the start and one a trial, none at accept: its trials
-    are ``forward_passes − 1``."""
+    """And for a split objective whose step the orthant projection may
+    bend (OWL-QN).  A trial that the projection clips is contracted from
+    its own point, ``X·w_try + o``; one that it clips nothing is the
+    straight step, scored along ``m + α·X·d`` with ``X·d`` contracted by
+    the first such trial of the search and handed on to the next.  The
+    search keeps the margins of the trial it ends on, and the accepted
+    point's gradient is taken from them: no contraction at accept.
+
+    Each contraction is counted where it is made, and so are the trials
+    and those walked (``ls_trials``, ``walked_trials``): ``forward_passes
+    = 1 + ls_trials − walked_trials + the searches that contracted X·d``.
+
+    A solve batched by ``vmap`` does not walk: there the clip test
+    differs by lane, ``lax.cond`` becomes a select that runs both
+    branches, and a walked trial would pay its contraction all the same.
+    Each of its trials contracts its own point, ``forward_passes = 1 +
+    ls_trials``, and it counts nothing else (the program it had before
+    the walk).  What the start's margins show decides: a batch tracer's
+    are batched.  A solve traced alone and batched afterwards (``vmap``
+    of a jitted solve) would walk both branches; nothing does that."""
+    start_at_margins = _start_at_margins(split)
+
+    def start(w0):
+        m0, (passes, _, _), f, g = start_at_margins(w0)
+        if isinstance(m0, BatchTracer):
+            return m0, (passes, None, None), f, g
+        zero = jnp.asarray(0, jnp.int32)
+        return m0, (passes, zero, zero), f, g
 
     def open_search(c, d):
-        def trial(alpha, w_try):
-            m_try = split.margins(w_try)
+        def scored(m_try, w_try):
             return (split.value(m_try, w_try)
-                    + jnp.sum(l1_vec * jnp.abs(w_try))), m_try
+                    + jnp.sum(l1_vec * jnp.abs(w_try)))
 
-        def accept(alpha, w_new, trials, m_new):
-            return (m_new, c.forward_passes + trials,
+        if c.walked_trials is None:      # batched: every trial contracts
+            def trial(alpha, w_try, kept):
+                m_try = split.margins(w_try)
+                return scored(m_try, w_try), m_try
+
+            def accept(alpha, w_new, trials, m_new):
+                return (m_new, (c.forward_passes + trials, None, None),
+                        split.value_and_grad(m_new, w_new)[1])
+
+            return trial, accept
+
+        def trial(alpha, w_try, kept):
+            # (margins, X·d, whether X·d is contracted, trials walked)
+            xd, known, walked = (
+                (jnp.zeros_like(c.margins), jnp.asarray(False),
+                 jnp.asarray(0, jnp.int32)) if kept is None else kept[1:])
+
+            def contract(xd, known):
+                return split.margins(w_try), xd, known, walked
+
+            def walk(xd, known):
+                xd = jax.lax.cond(known, lambda: xd,
+                                  lambda: split.margin_step(d))
+                return (c.margins + alpha * xd, xd, jnp.asarray(True),
+                        walked + 1)
+
+            clipped = jnp.any(w_try != c.w + alpha * d)
+            kept = jax.lax.cond(clipped, contract, walk, xd, known)
+            return scored(kept[0], w_try), kept
+
+        def accept(alpha, w_new, trials, kept):
+            m_new, _, known, walked = kept
+            return (m_new,
+                    (c.forward_passes + trials - walked
+                     + known.astype(jnp.int32),
+                     c.ls_trials + trials, c.walked_trials + walked),
                     split.value_and_grad(m_new, w_new)[1])
 
         return trial, accept
 
-    return _start_at_margins(split), open_search
+    return start, open_search
 
 
 def lbfgs_solve(
@@ -300,9 +375,10 @@ def lbfgs_solve(
       objective: smooth part — ``w → (f_smooth, ∇f_smooth)``, or a GLM
         objective split at its margins (``optim.base.MarginSplit``):
         without an L1 term the line search then walks the margins, with
-        one it keeps its last trial's for the accepted point's gradient
-        (module docstring).  The L1 term must NOT be folded in; pass it
-        via ``l1_weight``.
+        one it walks those of the trials the orthant projection clips
+        nothing of, unless batched, and keeps its last trial's for the
+        accepted point's gradient (module docstring).  The L1 term must
+        NOT be folded in; pass it via ``l1_weight``.
       w0: [dim] initial point.
       l1_weight: None (plain L-BFGS) or per-coordinate L1 weights [dim]
         (scalars broadcast), activating OWL-QN semantics.
@@ -322,12 +398,13 @@ def lbfgs_solve(
         trials_of = lambda c: c.forward_passes - 1 - c.iteration
     elif owlqn:
         start, open_search = _keeping_margins(objective, l1_vec)
-        trials_of = lambda c: c.forward_passes - 1
+        trials_of = lambda c: (c.forward_passes - 1 if c.ls_trials is None
+                               else c.ls_trials)
     else:
         start, open_search = _along_margins(objective)
         trials_of = lambda c: None
 
-    m0, passes0, f0_s, g0 = start(w0)
+    m0, (passes0, trials0, walked0), f0_s, g0 = start(w0)
     f0 = f0_s + jnp.sum(l1_vec * jnp.abs(w0)) if owlqn else f0_s
     pg0 = _pseudo_gradient(g0, w0, l1_vec) if owlqn else g0
     g0_norm = jnp.linalg.norm(pg0)
@@ -351,6 +428,8 @@ def lbfgs_solve(
         tracker=tracker,
         margins=m0,
         forward_passes=passes0,
+        ls_trials=trials0,
+        walked_trials=walked0,
     )
 
     def cond(c: _LbfgsCarry):
@@ -376,7 +455,8 @@ def lbfgs_solve(
         w_new, f_new, ls_ok, alpha, trials, kept = _line_search(
             trial, c.w, c.f, pg, d_dir, config, xi
         )
-        m_new, passes, g_new = accept(alpha, w_new, trials, kept)
+        m_new, (passes, ls_trials, walked), g_new = accept(
+            alpha, w_new, trials, kept)
 
         s = w_new - c.w
         y = g_new - c.g
@@ -444,6 +524,8 @@ def lbfgs_solve(
             # rejected search made its contraction all the same
             margins=jax.tree.map(moved, m_new, c.margins),
             forward_passes=jax.tree.map(keep, passes, c.forward_passes),
+            ls_trials=jax.tree.map(keep, ls_trials, c.ls_trials),
+            walked_trials=jax.tree.map(keep, walked, c.walked_trials),
         )
 
     final = jax.lax.while_loop(cond, body, init)
@@ -457,6 +539,7 @@ def lbfgs_solve(
         tracker=final.tracker,
         forward_passes=final.forward_passes,
         ls_trials=trials_of(final),
+        walked_trials=final.walked_trials,
     )
 
 

@@ -601,8 +601,13 @@ def test_the_stages_of_an_exposure_fit_say_so(rehearsed, spans_of):
     fixed = [args for args in by_name["coord_train"]
              if args["coordinate"] == "global"][0]
     assert fixed["nonzero_coefficients"] == np.count_nonzero(block[3])
-    assert fixed["forward_passes"] == 1 + fixed["ls_trials"] \
-        > fixed["solver_iterations"] > 0
+    # a contraction at the start and one a trial the orthant projection
+    # clipped; the trials it clipped nothing of walk the margins, after
+    # one X·d in their search (the first, from w = 0, walks them all)
+    walked = fixed["walked_trials"]
+    xd_searches = fixed["forward_passes"] - (1 + fixed["ls_trials"] - walked)
+    assert fixed["ls_trials"] > fixed["solver_iterations"] > 0
+    assert 1 <= xd_searches <= min(walked, fixed["solver_iterations"])
     assert all("nonzero_coefficients" not in args
                for args in by_name["coord_train"]
                if args["coordinate"] != "global")

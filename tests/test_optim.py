@@ -553,9 +553,19 @@ def test_search_under_vmap_each_lane_is_its_solo_solve(rng, loss, l1):
         solo = jax.jit(run)(jax.tree.map(lambda a: a[lane], batches),
                             w0s[lane])
         assert int(solo.iterations) == int(together.iterations[lane])
-        assert int(solo.forward_passes) == int(
-            together.forward_passes[lane]) == 1 + (
-                int(trials[lane]) if l1 else int(solo.iterations))
+        if l1:
+            # batched, a lane walks nothing and every trial contracts;
+            # alone, the trials the projection clips nothing of walk the
+            # margins, after one X·d in their search
+            assert together.walked_trials is None
+            assert int(together.forward_passes[lane]) == 1 + int(trials[lane])
+            walked = int(solo.walked_trials)
+            xd_searches = int(solo.forward_passes) - (
+                1 + int(trials[lane]) - walked)
+            assert 1 <= xd_searches <= min(walked, int(solo.iterations))
+        else:
+            assert int(solo.forward_passes) == int(
+                together.forward_passes[lane]) == 1 + int(solo.iterations)
         np.testing.assert_array_equal(solo.tracker.ls_trials,
                                       together.tracker.ls_trials[lane])
         np.testing.assert_allclose(together.w[lane], solo.w, rtol=0,
@@ -636,7 +646,8 @@ def test_an_l1_solve_through_the_split_is_the_solve_of_the_bare_callable(
         assert hashlib.sha256(text.encode()).hexdigest() \
             == PARENT_OWLQN_FROM_A_CALLABLE
     want = jax.jit(_owlqn_from_callable(cfg, whole_calls))(obj, batch, w0, l1)
-    # (ii) the split: a contraction at the start and one a trial
+    # (ii) the split: a contraction at the start and one a trial the
+    # projection clipped, one X·d for each search that walked a trial
     got = jax.jit(_owlqn_from_split(cfg, margins_calls, step_calls))(
         obj, batch, w0, l1)
     jax.effects_barrier()
@@ -644,9 +655,10 @@ def test_an_l1_solve_through_the_split_is_the_solve_of_the_bare_callable(
     assert iterations > 0 and int(want.ls_trials) > iterations
     assert int(want.forward_passes) \
         == 1 + int(want.ls_trials) + iterations == len(whole_calls)
-    assert int(got.forward_passes) == 1 + int(got.ls_trials) \
-        == len(margins_calls)
-    assert not step_calls
+    walked = int(got.walked_trials)
+    assert int(got.forward_passes) == len(margins_calls) + len(step_calls)
+    assert len(margins_calls) == 1 + int(got.ls_trials) - walked
+    assert 1 <= len(step_calls) <= min(walked, iterations)
     assert int(got.ls_trials) == int(
         np.nansum(np.asarray(got.tracker.ls_trials)))
 
@@ -729,4 +741,9 @@ def test_owlqn_search_that_exhausts_its_steps_stays_at_the_old_point(
                                       getattr(old, name))
     np.testing.assert_allclose(old.margins, obj.margins(old.w, batch),
                                rtol=1e-12, atol=1e-12)
-    assert int(rejected.forward_passes) == int(old.forward_passes) + 3
+    # its three trials were made all the same: a contraction each, or,
+    # where the projection clipped nothing, a walk after one X·d
+    walked = int(rejected.walked_trials) - int(old.walked_trials)
+    assert int(rejected.ls_trials) == int(old.ls_trials) + 3
+    assert int(rejected.forward_passes) \
+        == int(old.forward_passes) + 3 - walked + (walked > 0)

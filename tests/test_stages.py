@@ -293,8 +293,8 @@ def test_traced_fit_scans_each_direction_inside_its_chain_stage(traced):
 def test_coord_train_counts_of_one_solve():
     """What ``coord_train`` says of a solve: along the margins the
     trials only where the solver tracked its states; with an L1 term
-    (a contraction a trial) trials and forward passes from the carry,
-    tracked or not; nothing for a list of batched results."""
+    trials, those walked along the margins and forward passes from the
+    carry, tracked or not; nothing for a list of batched results."""
     import jax.numpy as jnp
 
     from photon_ml_tpu.data.batch import make_dense_batch
@@ -325,9 +325,13 @@ def test_coord_train_counts_of_one_solve():
         == {"solver_iterations": 6, "forward_passes": 7}
     whole = _solve_counts(solve(RegularizationContext.l1(0.1)))
     # through the split OWL-QN keeps its last trial's margins: nothing
-    # is contracted at the accepted point
-    assert whole["solver_iterations"] == 6 \
-        and whole["forward_passes"] == 1 + whole["ls_trials"] > 7
+    # is contracted at the accepted point; a trial the orthant projection
+    # clips nothing of walks the margins, after one X·d in its search
+    # (the first search, from w = 0, walks every trial)
+    walked = whole["walked_trials"]
+    xd_searches = whole["forward_passes"] - (1 + whole["ls_trials"] - walked)
+    assert whole["solver_iterations"] == 6 and walked >= 1 \
+        and 1 <= xd_searches <= min(walked, 6)
     assert _solve_counts(solve(RegularizationContext.l1(0.1),
                                track_states=False)) == whole
     assert _solve_counts([solve(RegularizationContext.l2(1.0))]) == {}

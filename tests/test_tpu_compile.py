@@ -358,10 +358,13 @@ def test_tailed_solve_contracts_the_tail_once_an_iteration(one_chip, on_tpu):
 def test_owlqn_poisson_solve_compiles_at_the_public_width(one_chip, on_tpu):
     """The fixed-effect solve of ``poisson-enet-kdd12`` (ISSUE 37): the
     Poisson loss under an elastic net, so OWL-QN: the tail's X.w in the
-    line search's loop (every trial contracts its own point) and, since
-    ISSUE 38, one X^T r an iteration from the margins the last trial
-    kept, with no X.w of the accepted point's own; an L1 vector of the
-    full width beside ``w``; and the whole solve fits the chip."""
+    line search's loop, in the branch of a trial the orthant projection
+    clipped (it contracts its own point), and X.d in the branch of one
+    it clipped nothing of, under the test of whether the search has it
+    yet; and one X^T r an iteration from the margins
+    the last trial kept, with no X.w of the accepted point's own; an L1
+    vector of the full width beside ``w``; and the whole solve fits the
+    chip."""
     from photon_ml_tpu.data.normalization import NormalizationContext
     from photon_ml_tpu.game.coordinates import _fixed_train_local_donating
     from photon_ml_tpu.ops import losses
@@ -386,7 +389,10 @@ def test_owlqn_poisson_solve_compiles_at_the_public_width(one_chip, on_tpu):
         objective, _tailed_batch(one_chip), leaf((KDD12_ROWS,)), None, None,
         leaf((KDD12_WIDTH,))).compile()
     text = compiled.as_text()
-    assert "/while/body/while/body/photon/fe_tail_dot/" in text
+    trial = "/while/body/while/body/cond/"
+    assert trial + "branch_1_fun/photon/fe_tail_dot/" in text
+    assert trial + "branch_0_fun/cond/branch_0_fun/photon/fe_tail_dot/" \
+        in text
     assert "/while/body/photon/fe_tail_tdot/" in text
     assert "/while/body/while/body/photon/fe_tail_tdot/" not in text
     assert "tpu_custom_call" in text
