@@ -233,7 +233,13 @@ class TrainingConfig:
     locked_coordinates: list[str] = dataclasses.field(default_factory=list)
     # Incremental training: regularize toward the warm-start model's
     # coefficients with strength prior_weight/σ² when it has variances
-    # (reference PriorDistribution semantics).
+    # (reference PriorDistribution semantics), added to the L2 term.
+    # It reaches every coordinate the warm model has variances for:
+    # the fixed effect (its variances), and each resident random
+    # effect entity by entity (its SIMPLE ``variance_blocks``, mapped
+    # onto this fit's entities and subspaces; an entity or a column the
+    # warm model lacks has no prior).  Streamed random effects refuse
+    # it; locked coordinates are not trained.
     use_warm_start_as_prior: bool = False
     prior_weight: float = 1.0
     checkpoint_dir: str | None = None      # per-CD-iteration checkpoints

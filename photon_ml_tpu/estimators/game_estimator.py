@@ -27,6 +27,7 @@ import dataclasses
 import itertools
 import logging
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -47,6 +48,7 @@ from photon_ml_tpu.estimators.game_transformer import GameTransformer
 from photon_ml_tpu.evaluation import evaluate, better_than
 from photon_ml_tpu.game.coordinates import (
     FixedEffectCoordinate,
+    RandomEffectCoordinate,
     build_random_effect_coordinate,
     build_random_effect_coordinate_sparse,
 )
@@ -64,7 +66,7 @@ from photon_ml_tpu.models.game import (
 )
 from photon_ml_tpu.models.glm import TaskType
 from photon_ml_tpu.ops.objective import GLMObjective
-from photon_ml_tpu.ops.prior import GaussianPrior
+from photon_ml_tpu.ops.prior import MIN_VARIANCE, EntityPriors, GaussianPrior
 from photon_ml_tpu.ops.regularization import (
     RegularizationContext,
     RegularizationType,
@@ -130,6 +132,105 @@ def _training_offsets(train: GameDataset):
     if train.offsets is None:
         return None
     return jnp.asarray(train.offset_array())
+
+
+def _join_random(comp: RandomEffectModel, coord, planes: list,
+                 fills: list) -> list:
+    """Each plane of a saved random effect (a list of per-bucket blocks
+    shaped as its ``coefficient_blocks``: the coefficients, the
+    variances) mapped onto ``coord``'s grouping and subspaces, as host
+    float32 blocks shaped as ``coord``'s coefficients; ``fills[i]``
+    where plane i has nothing (an entity or a column the saved model
+    does not have).
+
+    Fully vectorized (SURVEY §7 entity-ETL scale): one sorted join of
+    new vs saved entity ids, then per-(new bucket, old bucket) block
+    gathers — the bucket grid is O(log² max-count), each cell one
+    fancy-indexed copy of every plane; between two projected models
+    one sorted join on (entity, global column) keys a cell, every
+    plane's values carried through it together."""
+    out = [[np.full(shape, fill, np.float32)
+            for shape in coord.coefficient_shapes] for fill in fills]
+    g = coord.grouping
+    gs = comp.grouping
+    if g.n_total_entities == 0 or gs.n_total_entities == 0:
+        return out
+
+    # Sorted join on entity id (both sides are np.unique output =
+    # sorted; saved models preserve that order through I/O).
+    saved_pos = gs.join_ids(np.asarray(g.entity_ids))
+    found = saved_pos >= 0
+    pos_c = np.maximum(saved_pos, 0)
+    old_bucket = np.asarray(gs.entity_bucket)[pos_c]
+    old_slot = np.asarray(gs.entity_slot)[pos_c]
+    new_bucket = np.asarray(g.entity_bucket)
+    new_slot = np.asarray(g.entity_slot)
+
+    old_planes = [[np.asarray(blk) for blk in plane] for plane in planes]
+    for b in range(len(out[0])):
+        width = out[0][b].shape[1]
+        for ob in range(len(old_planes[0])):
+            sel = found & (new_bucket == b) & (old_bucket == ob)
+            if not sel.any():
+                continue
+            ns, os_ = new_slot[sel], old_slot[sel]
+            olds = [plane[ob][os_] for plane in old_planes]  # [m, p_old]
+            if coord.projection is None and comp.projection is None:
+                if olds[0].shape[1] != width:
+                    continue  # width mismatch: entity starts at 0
+                for new, old in zip(out, olds):
+                    new[b][ns] = old
+            elif coord.projection is None:
+                # Saved model projected, target dense: scatter each
+                # entity's local coefs to its global columns.
+                if comp.projection.global_dim != width:
+                    continue
+                fids = comp.projection.feature_ids[ob][os_]
+                rr, cc = np.nonzero(fids >= 0)
+                for new, old in zip(out, olds):
+                    new[b][ns[rr], fids[rr, cc]] = old[rr, cc]
+            elif comp.projection is None:
+                # Saved dense, target projected: gather the target's
+                # subspace columns out of the saved global rows.
+                fids = coord.projection.feature_ids[b][ns]  # [m, p]
+                valid = fids >= 0
+                valid &= fids < olds[0].shape[1]
+                rr, cc = np.nonzero(valid)
+                for new, old in zip(out, olds):
+                    new[b][ns[rr], cc] = old[rr, fids[rr, cc]]
+            else:
+                # Both projected: sparse merge-join on (entity,
+                # global col) keys.
+                from photon_ml_tpu.game.dataset import sorted_key_join
+
+                G = np.int64(comp.projection.global_dim)
+                f_old = comp.projection.feature_ids[ob][os_]
+                ro, co = np.nonzero(f_old >= 0)
+                if len(ro) == 0:
+                    continue
+                key_old = ro.astype(np.int64) * G + f_old[ro, co]
+                f_new = coord.projection.feature_ids[b][ns]
+                rn, cn = np.nonzero((f_new >= 0) & (f_new < G))
+                key_new = rn.astype(np.int64) * G + f_new[rn, cn]
+                # a subspace lists its columns ascending, so the keys
+                # come sorted and the join's argsort is not needed
+                at, hit = sorted_key_join(
+                    key_old, np.stack([old[ro, co] for old in olds], -1),
+                    key_new, presorted=bool(np.all(key_old[1:]
+                                                   > key_old[:-1])))
+                for i, new in enumerate(out):
+                    new[b][ns[rn[hit]], cn[hit]] = at[hit, i]
+    return out
+
+
+def _real_coefficients(coord) -> int:
+    """A random effect's coefficients that belong to a column its
+    entities saw: every local column of a projected one (padding
+    left out), entities x width of a dense one."""
+    if coord.projection is not None:
+        return int(sum((f >= 0).sum() for f in coord.projection.feature_ids))
+    return int(coord.grouping.n_total_entities) * int(
+        coord.coefficient_shapes[0][1] if coord.coefficient_shapes else 0)
 
 
 def _resolve_layout(requested: str, off_tpu: str) -> str:
@@ -379,73 +480,48 @@ class GameEstimator:
 
     def _import_random(self, comp: RandomEffectModel, coord):
         """Map a saved RandomEffectModel onto a (possibly different)
-        training-run grouping by entity id; unseen entities start at 0.
-
-        Fully vectorized (SURVEY §7 entity-ETL scale): one sorted join
-        of new vs saved entity ids, then per-(new bucket, old bucket)
-        block gathers — the bucket grid is O(log² max-count), each cell
-        one fancy-indexed copy."""
-        w0s = [np.zeros(shape, np.float32)
-               for shape in coord.coefficient_shapes]
-        g = coord.grouping
-        gs = comp.grouping
-        if g.n_total_entities == 0 or gs.n_total_entities == 0:
-            return [jnp.asarray(w) for w in w0s]
-
-        # Sorted join on entity id (both sides are np.unique output =
-        # sorted; saved models preserve that order through I/O).
-        saved_pos = gs.join_ids(np.asarray(g.entity_ids))
-        found = saved_pos >= 0
-        pos_c = np.maximum(saved_pos, 0)
-        old_bucket = np.asarray(gs.entity_bucket)[pos_c]
-        old_slot = np.asarray(gs.entity_slot)[pos_c]
-        new_bucket = np.asarray(g.entity_bucket)
-        new_slot = np.asarray(g.entity_slot)
-
-        old_blocks = [np.asarray(blk) for blk in comp.coefficient_blocks]
-        for b in range(len(w0s)):
-            for ob in range(len(old_blocks)):
-                sel = found & (new_bucket == b) & (old_bucket == ob)
-                if not sel.any():
-                    continue
-                ns, os_ = new_slot[sel], old_slot[sel]
-                blk_old = old_blocks[ob][os_]           # [m, p_old]
-                if coord.projection is None and comp.projection is None:
-                    if blk_old.shape[1] != w0s[b].shape[1]:
-                        continue  # width mismatch: entity starts at 0
-                    w0s[b][ns] = blk_old
-                elif coord.projection is None:
-                    # Saved model projected, target dense: scatter each
-                    # entity's local coefs to its global columns.
-                    if comp.projection.global_dim != w0s[b].shape[1]:
-                        continue
-                    fids = comp.projection.feature_ids[ob][os_]
-                    rr, cc = np.nonzero(fids >= 0)
-                    w0s[b][ns[rr], fids[rr, cc]] = blk_old[rr, cc]
-                elif comp.projection is None:
-                    # Saved dense, target projected: gather the target's
-                    # subspace columns out of the saved global rows.
-                    fids = coord.projection.feature_ids[b][ns]  # [m, p]
-                    valid = fids >= 0
-                    valid &= fids < blk_old.shape[1]
-                    rr, cc = np.nonzero(valid)
-                    w0s[b][ns[rr], cc] = blk_old[rr, fids[rr, cc]]
-                else:
-                    # Both projected: sparse merge-join on (entity,
-                    # global col) keys.
-                    from photon_ml_tpu.game.dataset import sorted_key_join
-
-                    G = np.int64(comp.projection.global_dim)
-                    f_old = comp.projection.feature_ids[ob][os_]
-                    ro, co = np.nonzero(f_old >= 0)
-                    key_old = ro.astype(np.int64) * G + f_old[ro, co]
-                    f_new = coord.projection.feature_ids[b][ns]
-                    rn, cn = np.nonzero((f_new >= 0) & (f_new < G))
-                    key_new = rn.astype(np.int64) * G + f_new[rn, cn]
-                    w_at, hit = sorted_key_join(key_old, blk_old[ro, co],
-                                                key_new)
-                    w0s[b][ns[rn[hit]], cn[hit]] = w_at[hit]
+        training-run grouping by entity id; unseen entities start at 0."""
+        (w0s,) = _join_random(comp, coord, [comp.coefficient_blocks], [0.0])
         return [jnp.asarray(w) for w in w0s]
+
+    def _random_prior(self, name: str, comp: RandomEffectModel, coord):
+        """The warm model's means and SIMPLE variances of a random
+        effect as each entity's Gaussian prior (``EntityPriors``), both
+        mapped onto this run's entities and subspaces by the one join
+        of ``_join_random``: a coefficient the saved model has keeps
+        its mean and gets the precision 1/σ²; an entity or a column it
+        has not gets precision 0.  None where the saved model has no
+        variances.  Returns (priors, entities that have one)."""
+        if comp.variance_blocks is None:
+            return None, 0
+        if not isinstance(coord, RandomEffectCoordinate):
+            raise ValueError(
+                f"use_warm_start_as_prior: random effect '{name}' is "
+                "streamed (re_chunk_entities), and a streamed random "
+                "effect takes no prior; fit it resident or set "
+                "use_warm_start_as_prior=false")
+        with telemetry.stage("re_prior_import", coordinate=name) as stage:
+            # a saved variance is never NaN: NaN marks "none saved"
+            means, variances = _join_random(
+                comp, coord, [comp.coefficient_blocks, comp.variance_blocks],
+                [0.0, np.nan])
+            known = [~np.isnan(v) for v in variances]
+            precisions = [
+                np.where(k, 1.0 / np.maximum(np.where(k, v, 1.0),
+                                             MIN_VARIANCE), 0.0)
+                .astype(np.float32)
+                for k, v in zip(known, variances)]
+            with_prior = int(sum(k.any(axis=1).sum() for k in known))
+            stage.set(entities=int(coord.grouping.n_total_entities),
+                      coefficients=_real_coefficients(coord),
+                      entities_with_prior=with_prior,
+                      coefficients_with_prior=int(sum(k.sum()
+                                                      for k in known)))
+            priors = EntityPriors(
+                means=[jnp.asarray(m) for m in means],
+                precisions=[jnp.asarray(p) for p in precisions],
+                weight=jnp.asarray(self.config.prior_weight, jnp.float32))
+        return priors, with_prior
 
     def _warm_coefficients(self, coords: dict, prep: dict) -> dict:
         """Per-coordinate starting coefficients from the warm model."""
@@ -458,6 +534,10 @@ class GameEstimator:
                 continue
             if by_name[name].kind == CoordinateKind.FIXED_EFFECT:
                 out[name], _ = self._import_fixed(comp, prep[name])
+            elif getattr(coords[name], "prior", None) is not None:
+                # the prior's means are the imported coefficients; the
+                # start is a copy, since training donates its buffers
+                out[name] = [jnp.copy(m) for m in coords[name].prior.means]
             else:
                 out[name] = self._import_random(comp, coords[name])
         return out
@@ -593,6 +673,18 @@ class GameEstimator:
                     # Coordinate was registered under entity_key by the
                     # builder; expose it under the coordinate name.
                     coords[coord_cfg.name].name = coord_cfg.name
+                    if (cfg.use_warm_start_as_prior
+                            and self._warm_model is not None
+                            and coord_cfg.name in self._warm_model.models
+                            and coord_cfg.name
+                            not in cfg.locked_coordinates):
+                        coord = coords[coord_cfg.name]
+                        prior, entities = self._random_prior(
+                            coord_cfg.name,
+                            self._warm_model.models[coord_cfg.name], coord)
+                        if prior is not None:
+                            coord.prior, coord.prior_entities = (prior,
+                                                                 entities)
             self._share_chunk_window(coords)
         return coords
 
@@ -702,8 +794,15 @@ class GameEstimator:
                         # Per-entity variances are SIMPLE by design (a
                         # FULL inverse per entity is neither needed nor
                         # tractable).
-                        models[name].variance_blocks = (
-                            coord.compute_variance_blocks(w, offsets))
+                        with telemetry.stage(
+                                "re_variances", coordinate=name,
+                                entities=int(
+                                    coord.grouping.n_total_entities),
+                                coefficients=_real_coefficients(coord)):
+                            models[name].variance_blocks = (
+                                jax.block_until_ready(
+                                    coord.compute_variance_blocks(
+                                        w, offsets)))
                     models[name].feature_shard = coord_cfg.feature_shard
                     models[name].entity_key = coord_cfg.entity_key
             stage.set(bytes_pulled=pulled, **sparsity)

@@ -267,36 +267,77 @@ def _bucket_chunks(n: int) -> tuple[int, int]:
     return n_chunks, -(-n // n_chunks)
 
 
-def _solve_bucket(run, batch: DenseBatch, w0: Array):
+def _solve_bucket(run, batch: DenseBatch, w0: Array, prior=None):
     """``vmap(run)`` over a bucket's entities; a bucket over
     ``_ONE_SHOT_ENTITIES`` in equal chunks of at most
     ``_CHUNK_ENTITIES``, one after the other (``lax.map``).  Entities
     are independent and every update of the solver is guarded per lane,
     so an entity's result does not depend on its neighbours; the lanes
-    that pad the last chunk hold no example (mask 0) and are cut off."""
+    that pad the last chunk hold no example (mask 0) and are cut off.
+
+    ``prior``, where given, is the bucket's (means, precisions) blocks,
+    [E_b, p_b] each, handed to ``run`` as its third and fourth
+    arguments, row for row with the entities.  A chunk gathers its rows
+    of them out of the resident blocks (no padded copy of either); a
+    lane that pads the last chunk gets precision 0."""
     n = w0.shape[0]
     n_chunks, lanes = _bucket_chunks(n)
     if n_chunks == 1:
-        return jax.vmap(run)(batch, w0)
+        return jax.vmap(run)(batch, w0, *(prior or ()))
 
     def chunked(a):
         pad = [(0, n_chunks * lanes - n)] + [(0, 0)] * (a.ndim - 1)
         return jnp.pad(a, pad).reshape(n_chunks, lanes, *a.shape[1:])
 
-    out = jax.lax.map(lambda args: jax.vmap(run)(*args),
-                      jax.tree.map(chunked, (batch, w0)))
+    if prior is None:
+        out = jax.lax.map(lambda args: jax.vmap(run)(*args),
+                          jax.tree.map(chunked, (batch, w0)))
+    else:
+        def chunk(args):
+            chunk_batch, chunk_w0, c = args
+            rows = c * lanes + jnp.arange(lanes)
+            at = jnp.minimum(rows, n - 1)
+            means, precisions = (jnp.take(a, at, axis=0) for a in prior)
+            precisions = jnp.where((rows < n)[:, None], precisions, 0.0)
+            return jax.vmap(run)(chunk_batch, chunk_w0, means, precisions)
+
+        out = jax.lax.map(chunk, (*jax.tree.map(chunked, (batch, w0)),
+                                  jnp.arange(n_chunks)))
     return jax.tree.map(
         lambda a: a.reshape(n_chunks * lanes, *a.shape[2:])[:n], out)
 
 
+def _toward(objective, prior, fn):
+    """``fn(objective, ...)`` as a per-entity function of
+    ``(..., means, precisions)``: the objective with that entity's
+    prior installed (``EntityPriors.entity``)."""
+    def entity(*args):
+        *rest, means, precisions = args
+        return fn(objective.replace(prior=prior.entity(means, precisions)),
+                  *rest)
+    return entity
+
+
 def _re_train_impl(optimizer, config, has_l1, objective, blocks,
-                   offsets: Array, w0s: list[Array]):
+                   offsets: Array, w0s: list[Array], prior=None):
+    """Every bucket's vmapped solve.  ``prior`` (``EntityPriors``, or
+    None for a random effect without one, whose program is then the one
+    it has always been) adds each entity's Gaussian prior to its
+    objective."""
     problem = OptimizationProblem(
         objective=objective, optimizer=optimizer, config=config
     )
-    run = partial(problem.run, has_l1=has_l1)
+    if prior is None:
+        run = partial(problem.run, has_l1=has_l1)
+        return [
+            _solve_bucket(run, _re_block_batch(blocks, b, offsets), w0s[b])
+            for b in range(len(blocks[0]))
+        ]
+    run = _toward(objective, prior, lambda obj, batch, w0: problem.replace(
+        objective=obj).run(batch, w0, has_l1=has_l1))
     return [
-        _solve_bucket(run, _re_block_batch(blocks, b, offsets), w0s[b])
+        _solve_bucket(run, _re_block_batch(blocks, b, offsets), w0s[b],
+                      (prior.means[b], prior.precisions[b]))
         for b in range(len(blocks[0]))
     ]
 
@@ -394,13 +435,24 @@ def _re_score(n_examples: int, x_blocks, ex_idx, row_idx, col_idx,
 
 
 @jax.jit
-def _re_variances(objective, blocks, coefficient_blocks, offsets: Array):
+def _re_variances(objective, blocks, coefficient_blocks, offsets: Array,
+                  prior=None):
+    """SIMPLE variances of every bucket's entities, 1 / diag H at their
+    coefficients; with ``prior`` (``EntityPriors``) its precisions are
+    part of that diagonal."""
     from photon_ml_tpu.optim.variance import simple_variances
 
+    if prior is None:
+        return [
+            jax.vmap(
+                lambda w, bb: simple_variances(objective, w, bb)
+            )(w_b, _re_block_batch(blocks, b, offsets))
+            for b, w_b in enumerate(coefficient_blocks)
+        ]
+    entity = _toward(objective, prior, simple_variances)
     return [
-        jax.vmap(
-            lambda w, bb: simple_variances(objective, w, bb)
-        )(w_b, _re_block_batch(blocks, b, offsets))
+        jax.vmap(entity)(w_b, _re_block_batch(blocks, b, offsets),
+                         prior.means[b], prior.precisions[b])
         for b, w_b in enumerate(coefficient_blocks)
     ]
 
@@ -751,6 +803,11 @@ class RandomEffectCoordinate(Coordinate):
     problem: OptimizationProblem
     # Set when features were subspace-projected (sparse global shard):
     projection: "SubspaceProjection | None" = None
+    # Each entity's Gaussian prior toward a previous model (incremental
+    # training, ops/prior.py), blocks shaped as the coefficient blocks;
+    # ``prior_entities`` of the entities have one.
+    prior: "EntityPriors | None" = None
+    prior_entities: int = 0
 
     def initial_coefficients(self) -> list[Array]:
         return [
@@ -770,15 +827,20 @@ class RandomEffectCoordinate(Coordinate):
             self.problem.optimizer, self.problem.config,
             self.problem.has_l1(), self.problem.objective,
             self._blocks(), offsets, w0s,
+            *(() if self.prior is None else (self.prior,)),
         )
         return [r.w for r in results], results
 
     def train_counts(self) -> dict:
         """``buckets``: the bucket programs a ``train`` runs;
-        ``chunks``: the ``_solve_bucket`` chunks they are solved in."""
-        return {"buckets": len(self.x_blocks),
-                "chunks": sum(_bucket_chunks(blk.shape[0])[0]
-                              for blk in self.x_blocks)}
+        ``chunks``: the ``_solve_bucket`` chunks they are solved in;
+        with a prior, ``prior_entities``: the entities that have one."""
+        counts = {"buckets": len(self.x_blocks),
+                  "chunks": sum(_bucket_chunks(blk.shape[0])[0]
+                                for blk in self.x_blocks)}
+        if self.prior is not None:
+            counts["prior_entities"] = self.prior_entities
+        return counts
 
     def score(self, coefficient_blocks: list[Array]) -> Array:
         """Block-space scoring: x·w per entity block, gathered back to
@@ -797,10 +859,12 @@ class RandomEffectCoordinate(Coordinate):
     def compute_variance_blocks(
         self, coefficient_blocks: list[Array], offsets: Array
     ) -> list[Array]:
-        """SIMPLE per-entity variances (1/diag H), vmapped per bucket —
-        the per-entity arm of the reference's variance pipeline."""
+        """SIMPLE per-entity variances (1/diag H, the prior's precision
+        included), vmapped per bucket — the per-entity arm of the
+        reference's variance pipeline."""
         return _re_variances(self.problem.objective, self._blocks(),
-                             coefficient_blocks, offsets)
+                             coefficient_blocks, offsets,
+                             *(() if self.prior is None else (self.prior,)))
 
     @property
     def coefficient_shapes(self) -> list[tuple[int, int]]:

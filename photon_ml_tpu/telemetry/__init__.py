@@ -118,9 +118,11 @@ STAGES = (
     "grr_col_build", "grr_plan_scan", "grr_overflow_level", "grr_routes",
     "plan_cache_save",
     "place_batch", "build_coordinates",
-    "group_entities", "re_project", "place_re", "cd_initial_scores",
+    "group_entities", "re_project", "place_re", "re_prior_import",
+    "cd_initial_scores",
     "cd_coordinate", "coord_train", "coord_score", "cd_validation",
-    "export_model", "validation", "transform", "score_coordinate",
+    "export_model", "re_variances", "validation", "transform",
+    "score_coordinate",
 )
 _STAGE_SET = frozenset(STAGES)
 STAGE_PREFIX = "photon/"
@@ -1027,6 +1029,9 @@ def start(mode: str, telemetry_dir: str | None = None, run_logger=None,
     if mode == "off":
         raise ValueError("start() needs an active mode; gate 'off' at "
                          "the caller (see maybe_session)")
+    # the session's ``jax.compiles`` come from the compile path's
+    # listener, whether or not a stage has been entered yet
+    listen_to_compiles()
     owns = False
     if run_logger is None:
         from photon_ml_tpu.utils.run_log import RunLogger

@@ -77,6 +77,11 @@ PROJECT_STAGE = "re_project"
 # numpy) runs this one, on the thread that builds the direction and so
 # directly inside that thread's chain stage (ISSUE 39).
 SCAN_STAGE = "grr_plan_scan"
+# Only a fit whose random effects take a prior from a warm-start model
+# runs the first, inside ``build_coordinates``; only a random effect
+# that exports variances the second, inside ``export_model``
+# (tests/test_refresh_prior.py has such fits); the tiny fit does neither.
+PRIOR_STAGES = ("re_prior_import", "re_variances")
 LEVEL_COUNTS = {"depth", "entries", "supertiles", "native", "kept"}
 SCAN_COUNTS = {"entries", "supertiles", "native"}
 # What a stage that was charged anything by the compile path's listener
@@ -115,7 +120,7 @@ COUNTS = {
 def test_stage_table_is_the_whole_of_stages():
     assert set(PARENT) | set(CACHE_STAGES) \
         | {ROUTE_STAGE, TAIL_STAGE, LEVEL_STAGE, PROJECT_STAGE, SCAN_STAGE} \
-        == set(telemetry.STAGES)
+        | set(PRIOR_STAGES) == set(telemetry.STAGES)
     assert len(set(telemetry.STAGES)) == len(telemetry.STAGES)
 
 

@@ -449,6 +449,38 @@ def test_random_effect_bucket_solve_compiles(one_chip, bucket):
         assert "bf16" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("program", ["solve", "variances"])
+def test_random_effect_bucket_toward_a_prior_compiles(one_chip, program):
+    """A daily refresh's per-user bucket (``glmix-kdd12``'s of
+    capacity 64): every entity's Gaussian prior toward yesterday's
+    posterior, [E_b, p_b] blocks of means and precisions beside its
+    coefficients, in the vmapped solve and in its SIMPLE variances."""
+    from photon_ml_tpu.game.coordinates import (
+        _re_train_donating,
+        _re_variances,
+    )
+    from photon_ml_tpu.ops.prior import EntityPriors
+    from photon_ml_tpu.optim.base import OptimizerConfig, OptimizerType
+
+    leaf = _abstract(one_chip)
+    e, c, p = PROJECTED_BUCKET
+    blocks = ([leaf((e, c, p))], [leaf((e, c))], [leaf((e, c))],
+              [leaf((e, c))], *[[leaf((e * c,), jnp.int32)]] * 3)
+    objective = _objective(one_chip, dim=1, intercept_index=None)
+    prior = EntityPriors(means=[leaf((e, p))], precisions=[leaf((e, p))],
+                         weight=leaf(()))
+    if program == "solve":
+        lowered = _re_train_donating.lower(
+            OptimizerType.LBFGS, OptimizerConfig(max_iters=10), False,
+            objective, blocks, leaf((N_TRAIN,)), [leaf((e, p))], prior)
+    else:
+        lowered = _re_variances.lower(objective, blocks, [leaf((e, p))],
+                                      leaf((N_TRAIN,)), prior)
+    compiled = lowered.compile()
+    _assert_fits(compiled)
+    assert "bf16" not in compiled.as_text()
+
+
 def test_sharded_grr_step_compiles_on_four_chips(four_chips, on_tpu):
     """The shard_mapped value+gradient over example-sharded GRR plans:
     the kernel under shard_map, partials met by one all-reduce."""
