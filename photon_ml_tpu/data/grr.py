@@ -610,7 +610,8 @@ def _spill_overflow(s_idx, s_seg, s_val, m_real, table_len, n_segments,
         kept = "vals" in plan
         stage.set(supertiles=_laid_out_supertiles(
             plan["n_st"], plan["n_gw"], plan["n_ow"])[1],
-            native=int(plan["native"]), kept=int(kept))
+            native=int(plan["native"]), kept=int(kept),
+            **_fill_counts(plan))
         if not kept:
             return None, s_idx, s_seg, s_val
         lvl2 = _finish_direction(
@@ -646,7 +647,8 @@ def _native_direction(cols, vals_masked, direction, table_len, n_segments,
         plan = grr_plan_native(cols, vals_masked, direction, table_len,
                                n_segments, cap, idx_range=idx_range)
         scan.set(supertiles=0 if plan is None else int(plan["n_st"]),
-                 native=int(plan is not None))
+                 native=int(plan is not None),
+                 **_fill_counts(plan))
     if plan is None:
         return None
     if idx_range is not None:
@@ -654,6 +656,18 @@ def _native_direction(cols, vals_masked, direction, table_len, n_segments,
     return _finish_direction(plan, table_len, n_segments, validate,
                              overflow_threshold, device=device,
                              dense_grid=dense_grid)
+
+
+def _fill_counts(plan: "dict | None") -> dict:
+    """What a builder's ``plan`` says of its own work, for the stage
+    around it: ``workers``, the threads that filled its arrays (the
+    caller's included; 1 inline or in the numpy body, 0 where nothing
+    was filled or no plan came), and the seconds of its two parts,
+    ``count_s`` (passes A-B and the block list) and ``fill_s`` (passes
+    C-D and the spill)."""
+    if plan is None:
+        return dict(workers=0, count_s=0.0, fill_s=0.0)
+    return {key: plan[key] for key in ("workers", "count_s", "fill_s")}
 
 
 def _finish_direction(plan, table_len, n_segments, validate,
@@ -751,7 +765,8 @@ def build_grr_direction(
     """
     with telemetry.stage("grr_plan_scan", entries=int(np.size(idx))) as scan:
         plan = _plan_coo(idx, seg, val, table_len, n_segments, cap)
-        scan.set(supertiles=int(plan["n_st"]), native=int(plan["native"]))
+        scan.set(supertiles=int(plan["n_st"]), native=int(plan["native"]),
+                 **_fill_counts(plan))
     return _finish_direction(plan, table_len, n_segments, validate,
                              overflow_threshold, device=device,
                              dense_grid=dense_grid,
@@ -826,6 +841,7 @@ def _plan_coo_numpy(idx, seg, val, n_gw, n_ow, cap, max_st=None) -> dict:
     a compiler, and the reference the C++ passes are held to, byte for
     byte (tests/test_grr.py).  No fit with the native library runs it.
     Entries validated, zeros dropped (``_plan_coo``)."""
+    t0 = time.perf_counter()
     idx, seg = idx.astype(np.int64), seg.astype(np.int64)
     segwin = WIN // cap
     group = TILE // cap
@@ -866,9 +882,11 @@ def _plan_coo_numpy(idx, seg, val, n_gw, n_ow, cap, max_st=None) -> dict:
     missing_ow = np.setdiff1d(np.arange(n_ow, dtype=np.int64), present_ow)
     blocks = np.sort(np.r_[blocks, missing_ow * n_gw])
     n_st = blocks.size
-    sizes = {"cap": cap, "n_gw": n_gw, "n_ow": n_ow, "n_st": n_st}
+    t1 = time.perf_counter()
+    sizes = {"cap": cap, "n_gw": n_gw, "n_ow": n_ow, "n_st": n_st,
+             "count_s": round(t1 - t0, 4)}
     if max_st is not None and n_st > max_st:
-        return sizes
+        return dict(sizes, workers=0, fill_s=0.0)
     st_of = np.searchsorted(blocks, bkk)
 
     gw_of_st = (blocks % n_gw).astype(np.int32)
@@ -917,7 +935,8 @@ def _plan_coo_numpy(idx, seg, val, n_gw, n_ow, cap, max_st=None) -> dict:
         dst=dst.reshape(n_st, TILE, TILE), gw_of_st=gw_of_st,
         ow_of_st=ow_of_st, first_of_ow=first_of_ow,
         spill_idx=np.pad(s_idx, pad), spill_seg=np.pad(s_seg, pad),
-        spill_val=np.pad(s_val, pad))
+        spill_val=np.pad(s_val, pad), workers=1,
+        fill_s=round(time.perf_counter() - t1, 4))
 
 
 def _validate_routes(G2, G3) -> None:
@@ -1620,16 +1639,17 @@ def _build_pair_cold(cols, vals, dim, cap, hot_threshold, max_hot,
     # range (or the single row plan) plus the (mid split → tail col)
     # chain — runs through ONE shared thread pool.  The C++ builder and
     # numpy release the GIL, so a multi-core TPU host builds all tasks
-    # concurrently; each task is one thread but for its route
-    # colouring, which ``grr_routes_native`` spreads over every core on
-    # native threads of its own (never this pool: its workers would
-    # wait on tasks queued behind themselves).  The wall is the column
-    # chain's.  Each task device_puts its OWN finished plan immediately
-    # (PJRT copies asynchronously in the background), so host→HBM
-    # transfers overlap the remaining host builds — the mid plan's
-    # transfer starts before the tail col build finishes, and early row
-    # ranges transfer under late ones.  The final fence is the caller's
-    # (``place_batch``).
+    # concurrently; each task is one thread but for its plans' fills
+    # and route colouring, which ``grr_plan_native`` /
+    # ``grr_plan_native_coo`` and ``grr_routes_native`` spread over
+    # every core on native threads of their own (never this pool: its
+    # workers would wait on tasks queued behind themselves).  The wall
+    # is the longer chain's.  Each task device_puts its OWN finished
+    # plan immediately (PJRT copies asynchronously in the background),
+    # so host→HBM transfers overlap the remaining host builds — the mid
+    # plan's transfer starts before the tail col build finishes, and
+    # early row ranges transfer under late ones.  The final fence is
+    # the caller's (``place_batch``).
     from concurrent.futures import ThreadPoolExecutor
 
     # Range planning is a sampled scan (fast) — run it up front so the

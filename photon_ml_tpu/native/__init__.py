@@ -9,8 +9,8 @@ effects' subspace projection) live in ``fast_etl.cpp`` and are bound
 here.
 
 Build model: ``g++ -O3 -shared -fPIC -pthread`` on first use (seconds,
-once; the GRR router and the projection run their blocks on
-``std::thread``s) into
+once; the GRR plans' fill, their router, the planner's hot split and
+the projection run their blocks on ``std::thread``s) into
 a .so next to the source whose name carries a hash of the source and the
 build command, so "is the binary stale" is decided from content, not
 from mtimes a copy does not keep.  The build is portable (no
@@ -31,6 +31,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 
@@ -165,8 +166,9 @@ def lib() -> "ctypes.CDLL | None":
             ctypes.POINTER(ctypes.c_int32),
             ctypes.POINTER(ctypes.c_int32),
         ]
+        dll.pml_grr_plan_fill.restype = ctypes.c_int32
         dll.pml_grr_plan_fill.argtypes = [ctypes.c_void_p] + [
-            ctypes.c_void_p] * 9
+            ctypes.c_void_p] * 9 + [ctypes.c_int32]
         dll.pml_grr_plan_free.argtypes = [ctypes.c_void_p]
         dll.pml_re_project_widths.restype = ctypes.c_int32
         dll.pml_re_project_widths.argtypes = [
@@ -379,12 +381,40 @@ def _check_cap(cap: int) -> None:
         raise ValueError(f"cap must be a power of two ≤ 128, got {cap}")
 
 
-def _read_grr_plan(dll, handle):
-    """The plan behind ``handle`` as a dict, and the handle freed: the
-    plan arrays (hi/vals/dst per supertile, block maps, spill COO) with
-    ``cap``, ``n_gw``, ``n_ow`` and ``n_st``; the four sizes alone where
-    the plan was over its caller's bound; None where the C++ declined
-    (size overflow, a shape it does not take)."""
+def _plan_workers(n_ow: int) -> int:
+    """Threads a plan's fill runs on, the caller's included: one output
+    window a block, so ``min(_usable_cores(), n_ow)``, and 1 (inline)
+    under two windows.
+
+    Not a setting: one window is the block because a block costs
+    nothing of its own but a counter step and the occupancy bitmaps of
+    its supertiles, and a window's supertiles (one a table window with
+    entries, ``n_gw`` at most) are already 147 KB of slots each, so the
+    smallest block balances the threads' last round best."""
+    return min(_usable_cores(), max(1, int(n_ow)))
+
+
+def _read_grr_plan(dll, start):
+    """The plan of the head call ``start()`` (``pml_grr_plan`` or
+    ``pml_grr_plan_coo``, which returns a handle) as a dict, and the
+    handle freed.
+
+    The head counts: pass A, the cap, pass B and the block list, on
+    this thread.  The slot arrays are then allocated here, uninitialised,
+    and ``pml_grr_plan_fill`` writes every element of them and of the
+    spill's arrays in place, on ``workers`` threads (``_plan_workers``:
+    native threads that live for the call, one output window a block;
+    the bytes are those of one serial pass), so their pages are first
+    touched by the threads that fill them.
+
+    The dict: the plan arrays (hi/vals/dst per supertile, block maps,
+    spill COO) with ``cap``, ``n_gw``, ``n_ow``, ``n_st``, ``workers``
+    and the seconds of the two calls, ``count_s`` and ``fill_s``; the
+    sizes and ``count_s`` alone (``workers`` 0, ``fill_s`` 0) where the
+    plan was over its caller's bound; None where the C++ declined (size
+    overflow, a shape it does not take, no memory)."""
+    t0 = time.perf_counter()
+    handle = start()
     if not handle:
         raise MemoryError("pml_grr_plan allocation failed")
     try:
@@ -399,36 +429,37 @@ def _read_grr_plan(dll, handle):
             ctypes.byref(cap_out), ctypes.byref(n_gw), ctypes.byref(n_ow),
             ctypes.byref(error),
         )
+        t1 = time.perf_counter()
         if error.value == 1:
             raise ValueError("idx or seg out of range in GRR plan build")
         st = int(n_st.value)
         sizes = {"cap": int(cap_out.value), "n_gw": int(n_gw.value),
                  "n_ow": int(n_ow.value), "n_st": st}
         if error.value == 4:
-            return sizes
+            return dict(sizes, workers=0, count_s=round(t1 - t0, 4),
+                        fill_s=0.0)
         if error.value:
             return None  # the numpy path decides
         m = int(n_spill.value)
-        hi = np.empty((st, 128, 128), np.int8)
-        v_out = np.empty((st, 128, 128), np.float32)
-        dst = np.empty((st, 128, 128), np.int32)
-        gw_of_st = np.empty(st, np.int32)
-        ow_of_st = np.empty(st, np.int32)
-        first_of_ow = np.empty(st, np.int32)
-        spill_idx = np.zeros(m, np.int32)
-        spill_seg = np.zeros(m, np.int32)
-        spill_val = np.zeros(m, np.float32)
-        dll.pml_grr_plan_fill(
-            handle, _ptr(hi), _ptr(v_out), _ptr(dst), _ptr(gw_of_st),
-            _ptr(ow_of_st), _ptr(first_of_ow), _ptr(spill_idx),
-            _ptr(spill_seg), _ptr(spill_val),
-        )
+        workers = _plan_workers(sizes["n_ow"])
+        out = dict(
+            hi=np.empty((st, 128, 128), np.int8),
+            vals=np.empty((st, 128, 128), np.float32),
+            dst=np.empty((st, 128, 128), np.int32),
+            gw_of_st=np.empty(st, np.int32), ow_of_st=np.empty(st, np.int32),
+            first_of_ow=np.empty(st, np.int32),
+            spill_idx=np.empty(m, np.int32), spill_seg=np.empty(m, np.int32),
+            spill_val=np.empty(m, np.float32))
+        rc = dll.pml_grr_plan_fill(handle, *map(_ptr, out.values()), workers)
     finally:
         dll.pml_grr_plan_free(handle)
-    return dict(sizes, hi=hi, vals=v_out, dst=dst, gw_of_st=gw_of_st,
-                ow_of_st=ow_of_st, first_of_ow=first_of_ow,
-                spill_idx=spill_idx, spill_seg=spill_seg,
-                spill_val=spill_val)
+    if rc == -2:
+        return _no_room("pml_grr_plan_fill")
+    if rc != 0:
+        raise RuntimeError("pml_grr_plan_fill: the spill disagrees with "
+                           "the head's count")
+    return dict(sizes, **out, workers=workers, count_s=round(t1 - t0, 4),
+                fill_s=round(time.perf_counter() - t1, 4))
 
 
 def grr_plan_native(
@@ -453,7 +484,9 @@ def grr_plan_native(
     returned plan's table axis is [0, hi-lo); ``lo`` must be
     window-aligned (a multiple of 16384).  Indices outside
     [0, table_len) are still an error.
-    Returns ``_read_grr_plan``'s dict with the chosen cap; route
+    Returns ``_read_grr_plan``'s dict with the chosen cap: the C++
+    head counts on this thread, the fill writes the plan's arrays on
+    every core the process may use from two output windows on.  Route
     coloring is the caller's next step (``grr_routes_native``).
     """
     dll = lib()
@@ -466,7 +499,7 @@ def grr_plan_native(
     if cap is not None:
         _check_cap(cap)
     lo, hi = idx_range if idx_range is not None else (0, int(table_len))
-    return _read_grr_plan(dll, dll.pml_grr_plan(
+    return _read_grr_plan(dll, lambda: dll.pml_grr_plan(
         _ptr(cols), _ptr(vals), n, k, int(direction), int(table_len),
         int(n_segments), 0 if cap is None else int(cap), int(lo), int(hi),
     ))
@@ -488,7 +521,7 @@ def grr_plan_native_coo(
     the numpy body writes.  Entries with value 0 are dropped; an idx or
     seg out of range raises.  Past ``max_supertiles`` (None: no bound)
     the C++ stops once the supertiles are counted and the dict holds
-    the sizes alone."""
+    the sizes alone.  Head and fill as ``grr_plan_native``'s."""
     dll = lib()
     if dll is None:
         return None
@@ -498,7 +531,7 @@ def grr_plan_native_coo(
     val = np.ascontiguousarray(val, np.float32)
     if not idx.shape == seg.shape == val.shape or idx.ndim != 1:
         raise ValueError("idx, seg and val must be 1-D and of one length")
-    return _read_grr_plan(dll, dll.pml_grr_plan_coo(
+    return _read_grr_plan(dll, lambda: dll.pml_grr_plan_coo(
         _ptr(idx), _ptr(seg), _ptr(val), idx.size, int(table_len),
         int(n_segments), int(cap),
         -1 if max_supertiles is None else int(max_supertiles),

@@ -462,30 +462,32 @@ int32_t pml_grr_routes_blocks(const int32_t* dst, const int8_t* hi,
 // heuristic a sample of segments; pml_grr_plan_coo is always given the
 // cap.
 //
-// Protocol: pml_grr_plan(...) or pml_grr_plan_coo(...) -> handle;
-// pml_grr_plan_sizes(handle,..); pml_grr_plan_fill(handle, ...);
-// pml_grr_plan_free(handle).
+// Protocol, split at the sizes so that the slot arrays are the caller's:
+//   pml_grr_plan(...) or pml_grr_plan_coo(...) -> handle: the serial
+//     head (grr_plan_head): pass A, the cap, pass B's counts and the
+//     block list, and no slot array;
+//   pml_grr_plan_sizes(handle, ...): the sizes, or why there is no plan;
+//   pml_grr_plan_fill(handle, ..., n_threads): passes C and D straight
+//     into the caller's arrays (grr_plan_fill), then the spill;
+//   pml_grr_plan_free(handle).
+// The handle keeps pointers to the entries: they must outlive the fill.
 // Route coloring stays in pml_grr_routes (shared with the Python path).
+//
+// The fill takes one output window a block, on for_each_block's
+// threads.  Window ow holds the segments [ow * segwin, (ow + 1) *
+// segwin): the rank counters its entries touch (q per (segment, table
+// window), the start-lane rank per (block, sub-tile row)) and its
+// supertiles (a run of them: the block list is ow-major) are no other
+// window's, and its entries keep their scan order, so every rank, slot
+// and spill decision is the serial pass's.  The spill is written last,
+// in scan order over every entry, from a byte mask the windows mark:
+// on chunks of cells, each from the count of the chunks before it.
 
 namespace {
 
 constexpr int64_t GRR_WIN = 16384;
 constexpr int32_t GRR_TILE = 128;
 constexpr int64_t GRR_SLOTS = GRR_WIN;  // 128*128 slots per supertile
-
-struct GrrPlan {
-  // 1 = idx/seg out of range, 2 = size overflow, 3 = bad argument,
-  // 4 = more supertiles than the caller's bound (sizes only, no arrays)
-  int32_t error = 0;
-  int32_t cap = 0, n_gw = 0, n_ow = 0;
-  int64_t n_st = 0, n_spill = 0;  // n_spill already padded to 8
-  std::vector<int8_t> hi;
-  std::vector<float> vals;
-  std::vector<int32_t> dst;
-  std::vector<int32_t> gw_of_st, ow_of_st, first_of_ow;
-  std::vector<int32_t> spill_idx, spill_seg;
-  std::vector<float> spill_val;
-};
 
 inline int32_t grr_next_pow2(int64_t x) {
   // Callers clamp the result to <= 64; clamp the input too so an
@@ -496,8 +498,11 @@ inline int32_t grr_next_pow2(int64_t x) {
   return p;
 }
 
-// The two entry sources of grr_plan_body: `size` cells, some of value
-// zero, cell e holding the entry (idx, seg) = at(e).
+// The two entry sources of a plan: `size` cells, some of value zero,
+// cell e holding the entry (idx, seg) = at(e).  first_cell(seg): where
+// a source holds its entries in segment order, one run of cells a
+// segment (an ELL array whose rows are the segments), the first cell
+// of segment seg's run; -1 where it does not.
 struct EllEntries {  // cell (r, j) of [n, k] cols/vals: row r, column c
   const int32_t* cols;
   const float* vals;
@@ -510,6 +515,7 @@ struct EllEntries {  // cell (r, j) of [n, k] cols/vals: row r, column c
     *idx = direction ? r : c;
     *seg = direction ? c : r;
   }
+  int64_t first_cell(int64_t seg) const { return direction ? -1 : seg * k; }
 };
 
 struct CooEntries {
@@ -522,11 +528,34 @@ struct CooEntries {
     *idx = idx_of[e];
     *seg = seg_of[e];
   }
+  int64_t first_cell(int64_t) const { return -1; }
 };
 
-// Body behind an exception firewall: std::bad_alloc must not unwind
-// through the extern "C"/ctypes boundary (that would terminate the
-// process instead of letting the caller fall back to numpy).
+struct GrrPlan {
+  // 1 = idx/seg out of range, 2 = size overflow, 3 = bad argument,
+  // 4 = more supertiles than the caller's bound (sizes only, no arrays)
+  int32_t error = 0;
+  int32_t cap = 0, n_gw = 0, n_ow = 0;
+  int64_t n_st = 0, n_spill = 0;  // n_spill already padded to 8
+  std::vector<int32_t> gw_of_st, ow_of_st, first_of_ow;
+  // What the fill takes from the head: the entries (ell, or coo where
+  // is_coo), the table range, whether the (seg, window) keys arrive
+  // sorted, the spilled entries' count (m_spill, before padding), the
+  // supertile of every block, the first supertile of every output
+  // window ([n_ow + 1]) and the counter tables, which each window of
+  // the fill zeroes for itself.
+  EllEntries ell{};
+  CooEntries coo{};
+  bool is_coo = false, sorted = true;
+  int64_t n_segments = 0, idx_lo = 0, idx_hi = 0, m_spill = 0;
+  std::vector<int32_t> st_of_bk, st_of_ow;
+  std::vector<uint8_t> qcnt;
+  std::vector<uint16_t> r2cnt;
+};
+
+// The head behind an exception firewall: std::bad_alloc must not
+// unwind through the extern "C"/ctypes boundary (that would terminate
+// the process instead of letting the caller fall back to numpy).
 //
 // [idx_lo, idx_hi) restricts the plan to a contiguous sub-range of the
 // table axis (the column-range split of data/grr.py): entries outside
@@ -539,10 +568,9 @@ struct CooEntries {
 //
 // max_st >= 0 bounds the supertiles the caller will pay for (the
 // overflow chain's economy test, data/grr.py _spill_overflow): a plan
-// with more stops once they are counted, before any array is made,
-// with error 4 and its sizes.
+// with more stops once they are counted, with error 4 and its sizes.
 template <class Entries>
-void grr_plan_body(GrrPlan* plan, const Entries& in, int64_t table_len,
+void grr_plan_head(GrrPlan* plan, const Entries& in, int64_t table_len,
                    int64_t n_segments, int32_t cap_in, int64_t idx_lo,
                    int64_t idx_hi, int64_t max_st) {
   // Same cap validation as the numpy path (data/grr.py): a non-power-
@@ -625,7 +653,6 @@ void grr_plan_body(GrrPlan* plan, const Entries& in, int64_t table_len,
   }
   plan->cap = cap;
   const int64_t segwin = GRR_WIN / cap;
-  const int32_t group = GRR_TILE / cap;
   const int64_t n_ow = n_segments > 0 ? (n_segments + segwin - 1) / segwin : 1;
   plan->n_ow = static_cast<int32_t>(n_ow);
   const int64_t n_bk = n_ow * n_gw;
@@ -636,8 +663,8 @@ void grr_plan_body(GrrPlan* plan, const Entries& in, int64_t table_len,
 
   // Rank counters.  q: per (seg, window) among all entries (uint8,
   // cap <= 64 < 255 so saturate at 255 = spilled anyway).  rank2: per
-  // (block, lane residue) among cap-kept entries.
-  std::vector<uint8_t> qcnt;
+  // (block, sub-tile row) among cap-kept entries.
+  std::vector<uint8_t>& qcnt = plan->qcnt;
   if (!sorted) {
     if (n_segments * n_gw > kMaxCounterBytes) {
       plan->error = 2;
@@ -645,10 +672,12 @@ void grr_plan_body(GrrPlan* plan, const Entries& in, int64_t table_len,
     }
     qcnt.assign(static_cast<size_t>(n_segments * n_gw), 0);
   }
-  std::vector<uint16_t> r2cnt(static_cast<size_t>(n_bk) * GRR_TILE, 0);
+  std::vector<uint16_t>& r2cnt = plan->r2cnt;
+  r2cnt.assign(static_cast<size_t>(n_bk) * GRR_TILE, 0);
   std::vector<int64_t> cnt_bk(static_cast<size_t>(n_bk), 0);
 
-  // Pass B: count kept entries per block (q + rank2 logic, no fills).
+  // Pass B: count kept entries per block, ranked as pass C ranks them
+  // (no fills), so the spill's length is known before any slot is.
   {
     int64_t run_key = -1, run_q = 0;
     for (int64_t e = 0; e < m_ell; ++e) {
@@ -671,13 +700,9 @@ void grr_plan_body(GrrPlan* plan, const Entries& in, int64_t table_len,
       }
       if (q >= cap) continue;  // spill1
       const int64_t bk = (seg / segwin) * n_gw + gw;
-      // This pass keys the start-lane counter by the lane residue
-      // idx % 128 where pass C keys it by the sub-tile row
-      // (idx % WIN) / 128, so cnt_bk is not pass C's kept count.  It is
-      // only read as "the block has an entry", and a block's first
-      // cap-kept entry is counted under either key (a block holds at
-      // most segwin * cap = 16384 of them, so no counter wraps).
-      uint16_t& r2 = r2cnt[bk * GRR_TILE + (idx % GRR_TILE)];
+      // A block holds at most segwin * cap = 16384 cap-kept entries,
+      // so no counter wraps.
+      uint16_t& r2 = r2cnt[bk * GRR_TILE + (idx % GRR_WIN) / GRR_TILE];
       if (r2 >= GRR_TILE) { ++r2; continue; }  // spill2 (sat. anyway)
       ++r2;
       ++cnt_bk[bk];
@@ -685,7 +710,10 @@ void grr_plan_body(GrrPlan* plan, const Entries& in, int64_t table_len,
   }
 
   // Block list: non-empty blocks ascending + a dummy per empty ow.
-  std::vector<int32_t> st_of_bk(static_cast<size_t>(n_bk), -1);
+  int64_t m_kept = 0;
+  for (int64_t b = 0; b < n_bk; ++b) m_kept += cnt_bk[b];
+  plan->st_of_bk.assign(static_cast<size_t>(n_bk), -1);
+  plan->st_of_ow.resize(static_cast<size_t>(n_ow + 1));
   {
     std::vector<uint8_t> ow_present(static_cast<size_t>(n_ow), 0);
     for (int64_t b = 0; b < n_bk; ++b)
@@ -704,123 +732,229 @@ void grr_plan_body(GrrPlan* plan, const Entries& in, int64_t table_len,
       plan->error = 4;
       return;
     }
-    plan->hi.assign(static_cast<size_t>(n_st) * GRR_SLOTS, 0);
-    plan->vals.assign(static_cast<size_t>(n_st) * GRR_SLOTS, 0.0f);
-    plan->dst.assign(static_cast<size_t>(n_st) * GRR_SLOTS, 0);
     plan->gw_of_st.resize(static_cast<size_t>(n_st));
     plan->ow_of_st.resize(static_cast<size_t>(n_st));
     plan->first_of_ow.resize(static_cast<size_t>(n_st));
     int32_t st = 0;
-    int64_t prev_ow = -1;
     for (int64_t ow = 0; ow < n_ow; ++ow) {
+      plan->st_of_ow[ow] = st;
       if (ow_present[ow]) {
         for (int64_t g = 0; g < n_gw; ++g) {
           const int64_t b = ow * n_gw + g;
           if (cnt_bk[b] <= 0) continue;
-          st_of_bk[b] = st;
+          plan->st_of_bk[b] = st;
           plan->gw_of_st[st] = static_cast<int32_t>(g);
           plan->ow_of_st[st] = static_cast<int32_t>(ow);
-          plan->first_of_ow[st] = (ow != prev_ow) ? 1 : 0;
-          prev_ow = ow;
+          plan->first_of_ow[st] = st == plan->st_of_ow[ow] ? 1 : 0;
           ++st;
         }
       } else {
         plan->gw_of_st[st] = 0;
         plan->ow_of_st[st] = static_cast<int32_t>(ow);
         plan->first_of_ow[st] = 1;
-        prev_ow = ow;
         ++st;
       }
     }
+    plan->st_of_ow[n_ow] = st;
   }
+  plan->sorted = sorted;
+  plan->n_segments = n_segments;
+  plan->idx_lo = idx_lo;
+  plan->idx_hi = idx_hi;
+  plan->m_spill = m_nz - m_kept;
+  plan->n_spill = plan->m_spill ? ((plan->m_spill + 7) / 8) * 8 : 0;
+}
 
-  // Pass C: fill HI/VALS/DST + occupancy bitmaps + spill COO.
-  const int64_t n_st = plan->n_st;
-  std::vector<uint64_t> occ_s(static_cast<size_t>(n_st) * (GRR_SLOTS / 64), 0);
-  std::vector<uint64_t> occ_f(static_cast<size_t>(n_st) * (GRR_SLOTS / 64), 0);
-  {
-    std::fill(r2cnt.begin(), r2cnt.end(), 0);
-    if (!sorted) std::fill(qcnt.begin(), qcnt.end(), 0);
-    int64_t run_key = -1, run_q = 0;
-    for (int64_t e = 0; e < m_ell; ++e) {
-      const float v = in.val(e);
-      if (v == 0.0f) continue;
+// Passes C and D into the caller's arrays (hi, vals, dst: [n_st, 16384];
+// the spill's three: [n_spill]), one output window a block on
+// `n_threads` threads at most, then the spill.  Every element of every
+// output is written.  Returns 0; -1 where the spill disagrees with the
+// head's count (the outputs are then unspecified); -2: out of memory.
+template <class Entries>
+int32_t grr_plan_fill(GrrPlan* p, const Entries& in, int8_t* hi,
+                      float* vals, int32_t* dst, int32_t* spill_idx,
+                      int32_t* spill_seg, float* spill_val,
+                      int32_t n_threads) {
+  const int64_t n_gw = p->n_gw, n_ow = p->n_ow, cap = p->cap;
+  const int64_t segwin = GRR_WIN / cap;
+  const int64_t group = GRR_TILE / cap;
+  const int64_t m_ell = in.size;
+  // The entry (idx rebased, seg) of cell e, or false where the cell
+  // holds none of this plan's (a zero, or an idx outside the range).
+  auto entry = [&](int64_t e, int64_t* idx, int64_t* seg) -> bool {
+    if (in.val(e) == 0.0f) return false;
+    in.at(e, idx, seg);
+    if (*idx < p->idx_lo || *idx >= p->idx_hi) return false;
+    *idx -= p->idx_lo;
+    return true;
+  };
+
+  // The passes over every cell run on chunks of cells, a few a thread.
+  const int64_t n_chunks = 4 * std::max<int64_t>(n_threads, 1);
+  const int64_t chunk = (m_ell + n_chunks - 1) / n_chunks;
+  auto over_chunks = [&](auto body) {  // body(c, e0, e1)
+    for_each_block(n_chunks, n_threads, [&](int64_t c) {
+      body(c, std::min(m_ell, c * chunk), std::min(m_ell, (c + 1) * chunk));
+      return 0;
+    });
+  };
+
+  // Window ow's cells are [first[ow], first[ow + 1]): of the entries
+  // themselves where the source keeps its segments in order (or there
+  // is one window), else of `order`, the cells sorted by window: a
+  // counting sort over the chunks, which keeps the scan order within
+  // each window.
+  std::vector<int64_t> first(static_cast<size_t>(n_ow + 1)), order;
+  const bool runs = n_ow == 1 || in.first_cell(0) >= 0;
+  if (runs) {
+    first[0] = 0;
+    for (int64_t ow = 1; ow < n_ow; ++ow)
+      first[ow] = std::min(in.first_cell(ow * segwin), m_ell);
+    first[n_ow] = m_ell;
+  } else {
+    // place[c * n_ow + ow]: chunk c's entries of window ow; then where
+    // the next of them goes in `order`.
+    std::vector<int64_t> place(static_cast<size_t>(n_chunks * n_ow), 0);
+    over_chunks([&](int64_t c, int64_t e0, int64_t e1) {
+      int64_t* mine = place.data() + c * n_ow;
       int64_t idx, seg;
-      in.at(e, &idx, &seg);
-      if (idx < idx_lo || idx >= idx_hi) continue;
-      idx -= idx_lo;
+      for (int64_t e = e0; e < e1; ++e)
+        if (entry(e, &idx, &seg)) ++mine[seg / segwin];
+    });
+    int64_t t = 0;
+    for (int64_t ow = 0; ow < n_ow; ++ow) {
+      first[ow] = t;
+      for (int64_t c = 0; c < n_chunks; ++c) {
+        const int64_t n = place[c * n_ow + ow];
+        place[c * n_ow + ow] = t;
+        t += n;
+      }
+    }
+    first[n_ow] = t;
+    order.resize(static_cast<size_t>(t));
+    over_chunks([&](int64_t c, int64_t e0, int64_t e1) {
+      int64_t* next = place.data() + c * n_ow;
+      int64_t idx, seg;
+      for (int64_t e = e0; e < e1; ++e)
+        if (entry(e, &idx, &seg)) order[next[seg / segwin]++] = e;
+    });
+  }
+  const int64_t* by_window = runs ? nullptr : order.data();
+  std::vector<uint8_t> spilled(static_cast<size_t>(m_ell), 0);
+
+  const int32_t rc = for_each_block(n_ow, n_threads, [&](int64_t ow) {
+    const int64_t st0 = p->st_of_ow[ow], n_mine = p->st_of_ow[ow + 1] - st0;
+    const int64_t seg0 = ow * segwin;
+    const int64_t seg1 = std::min(seg0 + segwin, p->n_segments);
+    // The window's slots and counters, zeroed here: their pages are
+    // first touched by the thread that fills them.
+    std::memset(hi + st0 * GRR_SLOTS, 0, n_mine * GRR_SLOTS);
+    std::memset(vals + st0 * GRR_SLOTS, 0, n_mine * GRR_SLOTS * sizeof(float));
+    if (!p->sorted && seg1 > seg0)
+      std::memset(p->qcnt.data() + seg0 * n_gw, 0, (seg1 - seg0) * n_gw);
+    std::memset(p->r2cnt.data() + ow * n_gw * GRR_TILE, 0,
+                n_gw * GRR_TILE * sizeof(uint16_t));
+    std::vector<uint64_t> occ_s, occ_f;  // the window's occupancy bitmaps
+    try {
+      occ_s.assign(static_cast<size_t>(n_mine) * (GRR_SLOTS / 64), 0);
+      occ_f.assign(static_cast<size_t>(n_mine) * (GRR_SLOTS / 64), 0);
+    } catch (const std::bad_alloc&) {
+      return -2;
+    }
+
+    // Pass C: fill HI/VALS/DST + occupancy bitmaps; mark the spill.
+    int64_t run_key = -1, run_q = 0;
+    for (int64_t j = first[ow]; j < first[ow + 1]; ++j) {
+      const int64_t e = by_window ? by_window[j] : j;
+      int64_t idx, seg;
+      if (!entry(e, &idx, &seg)) continue;
       const int64_t gw = idx / GRR_WIN;
       int64_t q;
-      if (sorted) {
+      if (p->sorted) {
         const int64_t key = seg * n_gw + gw;
         if (key != run_key) { run_key = key; run_q = 0; }
         q = run_q++;
       } else {
-        uint8_t& qc = qcnt[seg * n_gw + gw];
+        uint8_t& qc = p->qcnt[seg * n_gw + gw];
         q = qc;
         if (qc < 255) ++qc;
       }
-      bool spilled = q >= cap;
+      bool spill = q >= cap;
       int64_t l_s = 0;
-      const int64_t bk = (seg / segwin) * n_gw + gw;
+      const int64_t bk = ow * n_gw + gw;
       // Start ROW = the entry's window sub-tile (idx%WIN)/128, so the
       // kernel gathers from the UNtransposed table window: row s of the
       // window holds table[gw*WIN + s*128 .. +127] and the gather plane
-      // carries the lane residue idx%128.  (Previously rows were keyed
-      // by residue, which required transposing every window per step —
-      // two ~100 us XLA transpose fusions per objective pass at bench
-      // shape.)
+      // carries the lane residue idx%128.
       const int64_t hrow = (idx % GRR_WIN) / GRR_TILE;
-      if (!spilled) {
-        uint16_t& r2 = r2cnt[bk * GRR_TILE + hrow];
+      if (!spill) {
+        uint16_t& r2 = p->r2cnt[bk * GRR_TILE + hrow];
         l_s = r2;
         ++r2;
-        spilled = l_s >= GRR_TILE;
+        spill = l_s >= GRR_TILE;
       }
-      if (spilled) {
-        plan->spill_idx.push_back(static_cast<int32_t>(idx));
-        plan->spill_seg.push_back(static_cast<int32_t>(seg));
-        plan->spill_val.push_back(v);
+      if (spill) {
+        spilled[e] = 1;
         continue;
       }
-      const int64_t st = st_of_bk[bk];
+      const int64_t st = p->st_of_bk[bk];
       const int64_t b = seg % segwin;
       const int64_t s_start = hrow * GRR_TILE + l_s;
       const int64_t s_final =
           (q * group + b / GRR_TILE) * GRR_TILE + (b % GRR_TILE);
-      const int64_t base = st * GRR_SLOTS;
-      plan->hi[base + s_start] = static_cast<int8_t>(idx % GRR_TILE);
-      plan->vals[base + s_final] = v;
-      plan->dst[base + s_start] = static_cast<int32_t>(s_final);
-      occ_s[(base + s_start) >> 6] |= (uint64_t{1} << (s_start & 63));
-      occ_f[(base + s_final) >> 6] |= (uint64_t{1} << (s_final & 63));
+      const int64_t base = st * GRR_SLOTS, mine = (st - st0) * GRR_SLOTS;
+      hi[base + s_start] = static_cast<int8_t>(idx % GRR_TILE);
+      vals[base + s_final] = in.val(e);
+      dst[base + s_start] = static_cast<int32_t>(s_final);
+      occ_s[(mine + s_start) >> 6] |= (uint64_t{1} << (s_start & 63));
+      occ_f[(mine + s_final) >> 6] |= (uint64_t{1} << (s_final & 63));
     }
-  }
 
-  // Pass D: padding bijection — pair free starts with free finals in
-  // order (same construction as the Python path).
-  for (int64_t st = 0; st < n_st; ++st) {
-    const int64_t base = st * GRR_SLOTS;
-    int64_t f = 0;  // next candidate free final
-    for (int64_t s = 0; s < GRR_SLOTS; ++s) {
-      if (occ_s[(base + s) >> 6] & (uint64_t{1} << (s & 63))) continue;
-      while (f < GRR_SLOTS &&
-             (occ_f[(base + f) >> 6] & (uint64_t{1} << (f & 63))))
+    // Pass D: padding bijection — pair free starts with free finals in
+    // order (same construction as the Python path).
+    for (int64_t t = 0; t < n_mine; ++t) {
+      const int64_t base = (st0 + t) * GRR_SLOTS, mine = t * GRR_SLOTS;
+      int64_t f = 0;  // next candidate free final
+      for (int64_t s = 0; s < GRR_SLOTS; ++s) {
+        if (occ_s[(mine + s) >> 6] & (uint64_t{1} << (s & 63))) continue;
+        while (f < GRR_SLOTS &&
+               (occ_f[(mine + f) >> 6] & (uint64_t{1} << (f & 63))))
+          ++f;
+        dst[base + s] = static_cast<int32_t>(f);
         ++f;
-      plan->dst[base + s] = static_cast<int32_t>(f);
-      ++f;
+      }
     }
-  }
+    return 0;
+  });
+  if (rc != 0) return rc;
 
-  // Spill padding to a multiple of 8.
-  {
-    const int64_t m = static_cast<int64_t>(plan->spill_idx.size());
-    const int64_t m_pad = m ? ((m + 7) / 8) * 8 : 0;
-    plan->spill_idx.resize(static_cast<size_t>(m_pad), 0);
-    plan->spill_seg.resize(static_cast<size_t>(m_pad), 0);
-    plan->spill_val.resize(static_cast<size_t>(m_pad), 0.0f);
-    plan->n_spill = m_pad;
+  // The spill COO in scan order, padded to a multiple of 8: each chunk
+  // counts its marked cells, then writes them from where the chunks
+  // before it end.
+  std::vector<int64_t> spill_at(static_cast<size_t>(n_chunks + 1), 0);
+  over_chunks([&](int64_t c, int64_t e0, int64_t e1) {
+    int64_t n = 0;
+    for (int64_t e = e0; e < e1; ++e) n += spilled[e];
+    spill_at[c + 1] = n;
+  });
+  for (int64_t c = 0; c < n_chunks; ++c) spill_at[c + 1] += spill_at[c];
+  if (spill_at[n_chunks] != p->m_spill) return -1;
+  over_chunks([&](int64_t c, int64_t e0, int64_t e1) {
+    int64_t t = spill_at[c], idx = 0, seg = 0;
+    for (int64_t e = e0; e < e1; ++e) {
+      if (!spilled[e]) continue;
+      entry(e, &idx, &seg);
+      spill_idx[t] = static_cast<int32_t>(idx);
+      spill_seg[t] = static_cast<int32_t>(seg);
+      spill_val[t] = in.val(e);
+      ++t;
+    }
+  });
+  for (int64_t t = p->m_spill; t < p->n_spill; ++t) {
+    spill_idx[t] = spill_seg[t] = 0;
+    spill_val[t] = 0.0f;
   }
+  return 0;
 }
 
 }  // namespace
@@ -833,9 +967,10 @@ void* pml_grr_plan(const int32_t* cols, const float* vals, int64_t n,
                    int64_t idx_hi) {
   auto* plan = new (std::nothrow) GrrPlan();
   if (!plan) return nullptr;
+  plan->ell = EllEntries{cols, vals, k, n * k, direction};
   try {
-    grr_plan_body(plan, EllEntries{cols, vals, k, n * k, direction},
-                  table_len, n_segments, cap_in, idx_lo, idx_hi, -1);
+    grr_plan_head(plan, plan->ell, table_len, n_segments, cap_in, idx_lo,
+                  idx_hi, -1);
   } catch (const std::bad_alloc&) {
     plan->error = 2;  // caller falls back to the numpy path
   }
@@ -845,8 +980,8 @@ void* pml_grr_plan(const int32_t* cols, const float* vals, int64_t n,
 // The same plan from m COO entries (idx[e], seg[e], val[e]) in the
 // order given, over the whole table axis.  `cap` is required (the
 // caller resolves it, data/grr.py); max_st < 0: no bound (see
-// grr_plan_body).  Touches nothing shared: any number of threads may
-// call it at once.
+// grr_plan_head).  Touches nothing shared: any number of threads may
+// call it, and the fill, at once.
 void* pml_grr_plan_coo(const int32_t* idx, const int32_t* seg,
                        const float* val, int64_t m, int64_t table_len,
                        int64_t n_segments, int32_t cap, int64_t max_st) {
@@ -856,9 +991,11 @@ void* pml_grr_plan_coo(const int32_t* idx, const int32_t* seg,
     plan->error = 3;
     return plan;
   }
+  plan->is_coo = true;
+  plan->coo = CooEntries{idx, seg, val, m};
   try {
-    grr_plan_body(plan, CooEntries{idx, seg, val, m}, table_len,
-                  n_segments, cap, 0, 0, max_st);
+    grr_plan_head(plan, plan->coo, table_len, n_segments, cap, 0, 0,
+                  max_st);
   } catch (const std::bad_alloc&) {
     plan->error = 2;  // caller falls back to the numpy path
   }
@@ -877,27 +1014,32 @@ void pml_grr_plan_sizes(void* handle, int64_t* n_st, int64_t* n_spill,
   *error = p->error;
 }
 
-void pml_grr_plan_fill(void* handle, int8_t* hi, float* vals, int32_t* dst,
-                       int32_t* gw_of_st, int32_t* ow_of_st,
-                       int32_t* first_of_ow, int32_t* spill_idx,
-                       int32_t* spill_seg, float* spill_val) {
+// The plan of a handle whose sizes said no error, into the caller's
+// arrays: hi int8, vals float32, dst int32, each [n_st, 128, 128] and
+// needing no initial value; the block maps [n_st]; the spill's three
+// [n_spill].  On `n_threads` threads at most, the caller's included
+// (grr_plan_fill).  Returns 0; -1: a handle with an error, or an
+// internal disagreement; -2: out of memory (the caller's numpy body
+// decides).
+int32_t pml_grr_plan_fill(void* handle, int8_t* hi, float* vals,
+                          int32_t* dst, int32_t* gw_of_st, int32_t* ow_of_st,
+                          int32_t* first_of_ow, int32_t* spill_idx,
+                          int32_t* spill_seg, float* spill_val,
+                          int32_t n_threads) {
   auto* p = static_cast<GrrPlan*>(handle);
-  std::memcpy(hi, p->hi.data(), p->hi.size());
-  std::memcpy(vals, p->vals.data(), p->vals.size() * sizeof(float));
-  std::memcpy(dst, p->dst.data(), p->dst.size() * sizeof(int32_t));
-  std::memcpy(gw_of_st, p->gw_of_st.data(),
-              p->gw_of_st.size() * sizeof(int32_t));
-  std::memcpy(ow_of_st, p->ow_of_st.data(),
-              p->ow_of_st.size() * sizeof(int32_t));
-  std::memcpy(first_of_ow, p->first_of_ow.data(),
-              p->first_of_ow.size() * sizeof(int32_t));
-  if (p->n_spill) {
-    std::memcpy(spill_idx, p->spill_idx.data(),
-                p->spill_idx.size() * sizeof(int32_t));
-    std::memcpy(spill_seg, p->spill_seg.data(),
-                p->spill_seg.size() * sizeof(int32_t));
-    std::memcpy(spill_val, p->spill_val.data(),
-                p->spill_val.size() * sizeof(float));
+  if (p->error != 0) return -1;
+  const size_t n_st = static_cast<size_t>(p->n_st);
+  std::memcpy(gw_of_st, p->gw_of_st.data(), n_st * sizeof(int32_t));
+  std::memcpy(ow_of_st, p->ow_of_st.data(), n_st * sizeof(int32_t));
+  std::memcpy(first_of_ow, p->first_of_ow.data(), n_st * sizeof(int32_t));
+  try {
+    return p->is_coo
+               ? grr_plan_fill(p, p->coo, hi, vals, dst, spill_idx,
+                               spill_seg, spill_val, n_threads)
+               : grr_plan_fill(p, p->ell, hi, vals, dst, spill_idx,
+                               spill_seg, spill_val, n_threads);
+  } catch (const std::bad_alloc&) {
+    return -2;
   }
 }
 

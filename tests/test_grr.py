@@ -1018,6 +1018,61 @@ def test_pair_leaves_equal_native_hot_split_or_numpy_body(
     hot_split.same_bytes(native, numpy)
 
 
+def test_pair_leaves_equal_at_one_fill_thread_or_many_or_numpy_body(
+        rng, monkeypatch, hot_split, spans_of, sha256_of):
+    """A whole pair with all three column classes, a mid split and kept
+    overflow levels: through the native library with the
+    plans' fills on one thread and on up to seven, and with
+    ``PHOTON_ML_TPU_NATIVE=0``: one sha256 over the pair.  Every plan
+    filled says so on its ``grr_plan_scan`` or ``grr_overflow_level``:
+    ``workers`` min(cores, its output windows), 1 inline; a refused
+    level 0."""
+    import jax
+
+    import photon_ml_tpu.native as native_lib
+    from photon_ml_tpu.data import grr
+
+    # a tail at this height, and overflow levels dense enough to keep
+    monkeypatch.setattr(grr, "ECONOMY_SLOTS_PER_ENTRY", 7)
+    n, k, dim = 17000, 5, 30000
+    cols, vals = _powerlaw_ell(rng, n, k, dim, x0=300.0)
+    cols[:, 0] = 0
+    vals[rng.random((n, k)) < 0.05] = 0.0
+
+    def build(cores):
+        monkeypatch.setattr(native_lib, "_usable_cores", lambda: cores)
+        pair, spans = spans_of(build_grr_pair, cols, vals, dim, cap=4,
+                               overflow_threshold=2000)
+        said = [s["args"] for s in spans
+                if s["name"] in ("grr_plan_scan", "grr_overflow_level")]
+        assert all(a["kept"] or a["workers"] == 0 for a in said
+                   if "kept" in a)
+        return pair, said
+
+    digests = set()
+    for cores in (1, 7):
+        pair, said = build(cores)
+        digests.add(sha256_of(
+            [np.asarray(x) for x in jax.tree_util.tree_leaves(pair)]))
+        assert all(a["native"] == 1 for a in said)
+        plans = [d for top in (pair.col_dir, pair.col_mid)
+                 + tuple(getattr(pair.row_dir, "parts", (pair.row_dir,)))
+                 for d in _levels(top)]
+        assert sorted(a["workers"] for a in said if a["workers"]) \
+            == sorted(min(cores, d.n_ow) for d in plans)
+    assert pair.tail.nnz and pair.mid_ids.size
+    assert max(len(_levels(pair.col_dir)), len(_levels(pair.col_mid)),
+               len(_levels(pair.row_dir))) >= 3
+    assert {min(7, d.n_ow) for d in plans} >= {1, 5}     # inline, threads
+    hot_split.without("the_library")
+    numpy, said = build(7)
+    assert all(a["native"] == 0 for a in said)
+    assert {a["workers"] for a in said} <= {0, 1}
+    hot_split.same_bytes(pair, numpy)
+    assert digests == {sha256_of(
+        [np.asarray(x) for x in jax.tree_util.tree_leaves(numpy)])}
+
+
 def test_col_range_split_reduces_spill(rng):
     """On power-law columns the per-range capacities must hold in the
     level-1 kernel what the single global cap pushed to overflow/COO

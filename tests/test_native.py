@@ -199,10 +199,10 @@ def test_grr_plan_coo_callers_at_once_all_return_the_serial_bytes(rng):
     seg = (n_segments * rng.random(m) ** 3.0).astype(np.int64)
     val = rng.normal(size=m).astype(np.float32)
 
-    def plan():
+    def plan():  # its bytes, and no clock's reading
         out = grr_plan_native_coo(idx, seg, val, table_len, n_segments, 8)
         return {name: a.tobytes() if isinstance(a, np.ndarray) else a
-                for name, a in out.items()}
+                for name, a in out.items() if not name.endswith("_s")}
 
     want = plan()
     assert want["n_st"] > 10 and len(want["spill_val"]) > 0
@@ -680,3 +680,125 @@ def test_native_hot_split_says_so_where_its_outputs_cannot_be_allocated(
     cols, vals, _dim, _classes = _split_case("no_tail", rng)
     assert column_counts_native(cols, vals, 10 ** 15) is None
     assert "column_counts_native: no memory" in capfd.readouterr().err
+
+
+# -- the GRR plan's fill on native threads ------------------------------------
+
+PLAN_SIZES = ("cap", "n_gw", "n_ow", "n_st")
+PLAN_LEAVES = ("hi", "vals", "dst", "gw_of_st", "ow_of_st", "first_of_ow",
+               "spill_idx", "spill_seg", "spill_val")
+
+
+def _skewed_ell(rng, n, k, dim):
+    """``n`` rows of ``k`` columns, most of them in the first table
+    window (cells of value zero among them), so small caps spill."""
+    cols = np.minimum(300 * np.exp(rng.uniform(size=(n, k))
+                                   * np.log((dim + 300) / 300)) - 300,
+                      dim - 1).astype(np.int32)
+    vals = rng.normal(size=(n, k)).astype(np.float32)
+    vals[rng.random((n, k)) < 0.1] = 0.0
+    return cols, vals
+
+
+def _plan_case(case, rng):
+    """(the native builder's call, its entries as COO in scan order,
+    table_len, n_segments, cap, the bound on supertiles): the ELL cells
+    scan row by row, so direction 0's segments arrive in order and
+    direction 1's do not."""
+    from photon_ml_tpu.native import grr_plan_native, grr_plan_native_coo
+
+    bound = None
+    if case.startswith("ell"):
+        direction = int(case.startswith("ell_columns"))
+        n, k, dim = (30000, 6, 40000) if direction == 0 else (20000, 6, 30000)
+        cols, vals = _skewed_ell(rng, n, k, dim)
+        cap = None if case.endswith("chosen_cap") else 4
+        rows = np.repeat(np.arange(n), k)
+        idx, seg = ((cols.ravel(), rows) if direction == 0
+                    else (rows, cols.ravel()))
+        table_len, n_segments = (dim, n) if direction == 0 else (n, dim)
+        part = (16384, 2 * 16384) if case == "ell_rows_an_idx_range" \
+            else None
+        lo, hi = part or (0, table_len)
+        keep = (vals.ravel() != 0) & (idx >= lo) & (idx < hi)
+        coo = (idx[keep] - lo, seg[keep], vals.ravel()[keep])
+
+        def build():
+            return grr_plan_native(cols, vals, direction, table_len,
+                                   n_segments, cap, idx_range=part)
+        return build, coo, hi - lo, n_segments, cap, bound
+    table_len, n_segments, cap = 5 * 16384, 30000, 8
+    m = 150000
+    idx = rng.integers(0, table_len, m)
+    seg = (n_segments * rng.random(m) ** 3.0).astype(np.int64)
+    if case == "coo_sorted":
+        order = np.lexsort((idx // 16384, seg))
+        idx, seg = idx[order], seg[order]
+    elif case == "coo_fewer_windows_than_workers":
+        n_segments = 3 * (16384 // cap) - 5        # three output windows
+        seg = seg % n_segments
+    elif case == "coo_over_the_bound":
+        bound = 10
+    elif case != "coo_unsorted":
+        raise AssertionError(case)
+    val = rng.normal(size=m).astype(np.float32)
+    val[rng.random(m) < 0.05] = 0.0
+
+    def build():
+        return grr_plan_native_coo(idx, seg, val, table_len, n_segments, cap,
+                                   max_supertiles=bound)
+    keep = val != 0
+    return build, (idx[keep], seg[keep], val[keep]), table_len, n_segments, \
+        cap, bound
+
+
+PLAN_CASES = ["ell_rows_as_segments", "ell_rows_an_idx_range",
+              "ell_rows_chosen_cap", "ell_columns_as_segments",
+              "ell_columns_chosen_cap", "coo_sorted", "coo_unsorted",
+              "coo_fewer_windows_than_workers", "coo_over_the_bound"]
+
+
+@pytest.mark.parametrize("case", PLAN_CASES)
+def test_grr_plan_fill_on_any_thread_count_is_the_numpy_bodys_bytes(
+        rng, monkeypatch, sha256_of, case):
+    """The plan's fill on one output window a block: at 1, 2, 7 and 33
+    usable cores (more threads than this host's cores where the plan has
+    the windows for them) every size and leaf, the spill's in scan order
+    among them, is the same, one sha256 over all, and the numpy body's
+    at the cap the C++ took; the fill ran on min(cores, windows)
+    threads, inline under two; a plan over its bound is its sizes
+    alone, never filled."""
+    import photon_ml_tpu.native as nat
+    from photon_ml_tpu.data import grr
+
+    build, (idx, seg, val), table_len, n_segments, cap, bound = \
+        _plan_case(case, rng)
+    digests = set()
+    for cores in (1, 2, 7, 33):
+        monkeypatch.setattr(nat, "_usable_cores", lambda: cores)
+        plan = build()
+        n_ow = plan["n_ow"]
+        if bound is not None:
+            assert plan["n_st"] > bound and "vals" not in plan
+            assert (plan["workers"], plan["fill_s"]) == (0, 0.0)
+        else:
+            assert plan["workers"] == min(cores, n_ow)
+            digests.add(sha256_of([plan[name] for name in PLAN_LEAVES]))
+        sizes = [plan[name] for name in PLAN_SIZES]
+    if case == "coo_fewer_windows_than_workers":
+        assert n_ow == 3
+    elif bound is None:
+        assert n_ow >= 4 and plan["workers"] == min(33, n_ow) >= 7
+    if case.endswith("chosen_cap"):
+        assert cap is None and plan["cap"] in (4, 8, 16, 32, 64)
+    n_gw = -(-table_len // 16384)
+    numpy = grr._plan_coo_numpy(idx, seg, val, n_gw, n_ow, sizes[0], bound)
+    assert [numpy[name] for name in PLAN_SIZES] == sizes
+    if bound is not None:
+        return
+    assert len(digests) == 1 and np.count_nonzero(plan["spill_val"])
+    for name in PLAN_LEAVES:
+        assert plan[name].dtype == numpy[name].dtype
+        assert plan[name].shape == numpy[name].shape
+        assert plan[name].tobytes() == numpy[name].tobytes(), name
+    assert digests == {sha256_of([numpy[name] for name in PLAN_LEAVES])}

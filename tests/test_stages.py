@@ -82,8 +82,12 @@ SCAN_STAGE = "grr_plan_scan"
 # that exports variances the second, inside ``export_model``
 # (tests/test_refresh_prior.py has such fits); the tiny fit does neither.
 PRIOR_STAGES = ("re_prior_import", "re_variances")
-LEVEL_COUNTS = {"depth", "entries", "supertiles", "native", "kept"}
-SCAN_COUNTS = {"entries", "supertiles", "native"}
+# What a plan's builder says of its fill: its threads and the seconds of
+# its two parts.
+FILL_COUNTS = {"workers", "count_s", "fill_s"}
+LEVEL_COUNTS = {"depth", "entries", "supertiles", "native",
+                "kept"} | FILL_COUNTS
+SCAN_COUNTS = {"entries", "supertiles", "native"} | FILL_COUNTS
 # What a stage that was charged anything by the compile path's listener
 # sets when it closes, and ``estimator_fit`` always (ISSUE 39).
 COMPILE_COUNTS = {"programs", "trace_s", "lower_s", "cache_load_s",
